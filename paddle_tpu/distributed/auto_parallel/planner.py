@@ -60,14 +60,12 @@ __all__ = ["plan_model", "shard", "Plan", "CostReport"]
 
 # v5e-class constants; only RATIOS matter for the argmin
 _PEAK_FLOPS = 197e12          # bf16 MXU
-# Achieved-rate derate, calibrated against the measured flagship
-# (BENCH_r04/r05: BERT-base trains at ~0.51-0.55 MFU incl. remat
+# Achieved-rate derate, set from the flagship's pre-round figure on a
+# runtime that no longer exists (BERT-base at ~0.51-0.55 MFU incl. remat
 # recompute and the attention/loss ops this layer-level model does not
-# enumerate).  Applied to BOTH compute and ICI so every strategy RATIO —
-# and therefore the argmin the golden tests pin — is unchanged, while
-# absolute step-time predictions are calibrated: validated in
-# tests/test_auto_parallel_planner.py, the predicted flagship step time
-# must stay within ~30% of the driver-measured BENCH number.
+# enumerate) — history, to be re-set from PERF_LEDGER.jsonl once the
+# benchmark exists.  Applied to BOTH compute and ICI so every strategy
+# RATIO — and therefore the argmin the golden tests pin — is unchanged.
 _EFF = 0.55
 _EFF_FLOPS = _PEAK_FLOPS * _EFF
 _ICI_BW = 4.5e10 * _EFF       # achieved bytes/s per link

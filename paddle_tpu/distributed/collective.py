@@ -175,15 +175,8 @@ def _eager_collective(fn, group: Group, x, out_specs=None, extra=()):
     mesh = group.mesh()
     in_specs = (P(ax),) + tuple(P() for _ in extra)
     out_specs = P(ax) if out_specs is None else out_specs
-    try:
-        shmapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):
-        # older jax: shard_map still experimental / check_rep spelling
-        from jax.experimental.shard_map import shard_map as _sm
-        shmapped = _sm(fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
-    return shmapped(x, *extra)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(x, *extra)
 
 
 # ---------------------------------------------------------------------------

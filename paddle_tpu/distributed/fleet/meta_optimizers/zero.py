@@ -29,26 +29,9 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = ["add_sharding_axis", "shard_tree", "zero_state_shardings"]
-
-
-def _supported_memory_kind(mesh: Mesh, kind: Optional[str]
-                           ) -> Optional[str]:
-    """``kind`` if the mesh's devices can address it, else None.  TPU
-    devices expose ``pinned_host`` for offload; the CPU backend only
-    has ``unpinned_host`` (it IS host memory), where offload is a
-    placement no-op rather than an error."""
-    if not kind:
-        return None
-    try:
-        dev = next(iter(mesh.devices.flat))
-        if any(m.kind == kind for m in dev.addressable_memories()):
-            return kind
-    except Exception:       # noqa: BLE001 — older jax: trust the caller
-        return kind
-    return None
 
 
 def add_sharding_axis(ns: NamedSharding, shape, axis: str = "sharding",
@@ -58,7 +41,11 @@ def add_sharding_axis(ns: NamedSharding, shape, axis: str = "sharding",
     (the reference shards flattened params by rank; here we keep array
     structure and pick a dimension)."""
     mesh = ns.mesh
-    memory_kind = _supported_memory_kind(mesh, memory_kind)
+    if memory_kind == "pinned_host":
+        # offload: raises on a TPU without the space, None (placement
+        # no-op) on a host backend without it
+        from ....core.place import pinned_host_kind
+        memory_kind = pinned_host_kind(next(iter(mesh.devices.flat)))
     n = mesh.shape.get(axis, 1)
     spec = list(ns.spec) + [None] * (len(shape) - len(ns.spec))
     if any(axis == p or (isinstance(p, tuple) and axis in p)

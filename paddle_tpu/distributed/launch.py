@@ -10,7 +10,11 @@ chips through one PJRT client), identified to ``jax.distributed`` via
 coordinator address + process id; ``--nproc`` > 1 on a single machine is
 the CPU-simulation path, where each process gets an
 ``xla_force_host_platform_device_count`` virtual mesh for test parity
-(reference TestDistBase's localhost multi-process cluster).
+(reference TestDistBase's localhost multi-process cluster).  A chip
+belongs to one process: ``--nproc`` > 1 whose children are not pinned to
+the CPU (``--devices_per_proc`` or ``JAX_PLATFORMS=cpu``) is refused —
+each child would open every local chip.  The launcher itself never
+touches JAX, so it holds no chip its children need.
 
 Supervisor mode (``--supervise``, TorchElastic-style): the launcher
 heartbeats workers through the elastic ``Store`` (workers put step
@@ -237,6 +241,15 @@ def _parse_args(argv=None):
         p.error("--supervise --elastic needs --np MIN:MAX: elastic "
                 "supervise relaunches at the surviving world size "
                 "within those bounds")
+    most = max(args.nproc, _parse_np(args.np)[1] if args.np else 0)
+    if most > 1 and args.devices_per_proc <= 0 \
+            and os.environ.get("JAX_PLATFORMS") != "cpu":
+        p.error(f"{most} processes on one host would each open every "
+                "local accelerator chip, and a chip belongs to one "
+                "process: run one process per host (--nproc 1 drives "
+                "all local chips), or pin the children to the CPU "
+                "simulation with --devices_per_proc N or "
+                "JAX_PLATFORMS=cpu")
     if args.evict_stragglers and not (args.supervise and args.np):
         p.error("--evict_stragglers requires --supervise --np MIN:MAX "
                 "(eviction re-forms the gang one host smaller, which "

@@ -195,7 +195,13 @@ class DataParallel(Layer):
         apply_param_shardings(layers, mesh)
 
     def forward(self, *inputs, **kwargs):
-        return self._layers(*inputs, **kwargs)
+        # ops with a Mosaic kernel (attention) must know the mesh: GSPMD
+        # cannot partition the kernel, so it runs per shard — batch over
+        # the dp axis, heads over mp where TP layers shard them
+        from ..ops import pallas
+        with pallas.kernel_mesh(self.mesh, batch_axes=(self._dp_axis,),
+                                head_axes=("mp",)):
+            return self._layers(*inputs, **kwargs)
 
     def shard_inputs(self, arrays):
         return shard_batch(arrays, self.mesh, self._dp_axis)

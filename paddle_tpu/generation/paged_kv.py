@@ -197,29 +197,15 @@ def paged_view(cache: PagedKV) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return k, v
 
 
-_HOST_SHARDING_PROBED = False
-_HOST_SHARDING = None
-
-
 def _host_sharding():
-    """Sharding that places an array in **pinned host memory** when
-    the backend exposes the ``pinned_host`` memory kind (TPU offload —
-    the same probe seam as the zero-offload optimizer's
-    ``_supported_memory_kind``); None on backends where host memory IS
-    the default (CPU CI), where the caller falls back to plain numpy
-    arrays.  Probed once per process."""
-    global _HOST_SHARDING_PROBED, _HOST_SHARDING
-    if not _HOST_SHARDING_PROBED:
-        _HOST_SHARDING_PROBED = True
-        try:
-            dev = jax.devices()[0]
-            if any(m.kind == "pinned_host"
-                   for m in dev.addressable_memories()):
-                _HOST_SHARDING = jax.sharding.SingleDeviceSharding(
-                    dev, memory_kind="pinned_host")
-        except Exception:   # noqa: BLE001 — older jax: numpy fallback
-            _HOST_SHARDING = None
-    return _HOST_SHARDING
+    """Sharding that places an array in **pinned host memory** (TPU
+    offload — ``core.place.pinned_host_kind``, which raises on a TPU
+    that lacks the space); None on a host backend without it, where the
+    caller keeps plain numpy arrays."""
+    dev = jax.devices()[0]
+    from ..core.place import pinned_host_kind
+    kind = pinned_host_kind(dev)
+    return kind and jax.sharding.SingleDeviceSharding(dev, memory_kind=kind)
 
 
 class BlockPoolExhausted(RuntimeError):
@@ -653,8 +639,8 @@ class PagedGenerationSession(GenerationSession):
         field — k/v and, when quantized, the scale planes) to HOST
         memory so the engine can free the device blocks for
         higher-priority work.  Pinned host memory (``pinned_host``
-        memory kind) when the backend exposes it; plain numpy arrays
-        on CPU CI.  Blocked until the copies land — the caller decrefs
+        memory kind); plain numpy arrays on a host backend without
+        that space.  Blocked until the copies land — the caller decrefs
         the blocks immediately after, so the gather must not race
         their reuse.  Returns an opaque per-layer payload for
         :meth:`swap_in_blocks`."""

@@ -9,7 +9,8 @@ GIL discipline, and buffer marshalling live in ``pd_capi.cc``; the
 public header is ``pd_inference_api.h``.
 
 ``build()`` compiles ``libpaddle_tpu_capi.so`` on demand with the same
-in-repo g++ convention as ``paddle_tpu.native``.  C programs link it
+in-repo g++ convention as ``paddle_tpu.native`` (keyed by source hash
+and the CPython it embeds, ``native/_build.py``).  C programs link it
 directly (see ``demo_main.c``); Go programs use the cgo wrapper in
 ``paddle_tpu/inference/goapi`` over the same ABI.
 """
@@ -17,8 +18,11 @@ from __future__ import annotations
 
 import os
 import subprocess
+import sys
 import sysconfig
 import threading
+
+from ...native._build import build_shared, failure_text
 
 __all__ = ["build", "lib_path", "header_path", "available"]
 
@@ -49,23 +53,18 @@ def python_link_args() -> list:
             "-Wl,-rpath," + libdir]
 
 
-def build(force: bool = False) -> bool:
-    """Compile libpaddle_tpu_capi.so in-tree; True on success (cached by
-    mtime like paddle_tpu.native)."""
+def build() -> bool:
+    """Compile libpaddle_tpu_capi.so in-tree unless it is already built
+    from these sources for this CPython; True on success.  A failure
+    prints the compiler's words to stderr."""
     with _lock:
         try:
-            src_mtime = max(os.path.getmtime(_SRC), os.path.getmtime(_HDR))
-            if (not force and os.path.exists(_SO)
-                    and os.path.getmtime(_SO) >= src_mtime):
-                return True
-            cmd = (["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-                    "-fvisibility=hidden", _SRC, "-o", _SO + ".tmp"]
-                   + python_link_args())
-            subprocess.run(cmd, check=True, capture_output=True,
-                           timeout=240)
-            os.replace(_SO + ".tmp", _SO)
+            build_shared(_SO, [_SRC, _HDR],
+                         ["-fvisibility=hidden", *python_link_args()])
             return True
-        except Exception:
+        except (OSError, subprocess.SubprocessError) as e:
+            print("paddle_tpu.inference.capi: build failed "
+                  f"({failure_text(e)})", file=sys.stderr)
             return False
 
 

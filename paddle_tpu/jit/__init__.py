@@ -177,26 +177,22 @@ def avals_for_export(shapes_dtypes):
         for shape, dt in shapes_dtypes]
     if not any(s in (None, -1) for shape, _ in shapes_dtypes for s in shape):
         return None, concrete
-    try:
-        scope = jax_export.SymbolicScope()
-        symbolic, k = [], 0
-        for shape, dt in shapes_dtypes:
-            if any(s in (None, -1) for s in shape):
-                parts = []
-                for s in shape:
-                    if s in (None, -1):
-                        parts.append(f"dyn{k}")
-                        k += 1
-                    else:
-                        parts.append(str(int(s)))
-                shp = jax_export.symbolic_shape(", ".join(parts),
-                                                scope=scope)
-            else:
-                shp = tuple(int(s) for s in shape)
-            symbolic.append(jax.ShapeDtypeStruct(tuple(shp), dt))
-        return symbolic, concrete
-    except Exception:  # pragma: no cover - old jax without symbolic dims
-        return None, concrete
+    scope = jax_export.SymbolicScope()
+    symbolic, k = [], 0
+    for shape, dt in shapes_dtypes:
+        if any(s in (None, -1) for s in shape):
+            parts = []
+            for s in shape:
+                if s in (None, -1):
+                    parts.append(f"dyn{k}")
+                    k += 1
+                else:
+                    parts.append(str(int(s)))
+            shp = jax_export.symbolic_shape(", ".join(parts), scope=scope)
+        else:
+            shp = tuple(int(s) for s in shape)
+        symbolic.append(jax.ShapeDtypeStruct(tuple(shp), dt))
+    return symbolic, concrete
 
 
 def export_with_dynamic_dims(jitted, shapes_dtypes, *leading_args):

@@ -31,52 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .gpt import GPTConfig
 
 __all__ = ["init_gpt_params", "gpt_param_shardings",
-           "build_spmd_train_step", "HAS_MANUAL_PIPELINE"]
-
-# The pp/sp schedules need partial-manual shard_map (manual pipeline
-# axis, dp/mp left to GSPMD).  ``jax.shard_map`` with ``axis_names=``
-# landed post-0.4.x; the 0.4.x experimental ``auto=`` spelling exists
-# but this XLA hard-CHECKs partitioning the resulting mixed-manual
-# HLO, so old-jax builds take a GSPMD scan fallback instead (same
-# numerics, no microbatch overlap).
-HAS_MANUAL_PIPELINE = hasattr(jax, "shard_map")
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-               check_vma=False):
-    """``jax.shard_map`` with the modern ``axis_names``/``check_vma``
-    spelling, falling back to ``jax.experimental.shard_map`` (0.4.x:
-    ``auto``/``check_rep``) — same partial-manual semantics: axes not
-    in ``axis_names`` stay with GSPMD."""
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        manual = frozenset(axis_names) if axis_names is not None \
-            else frozenset(mesh.axis_names)
-        auto = frozenset(mesh.axis_names) - manual
-        return _sm(f, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=bool(check_vma),
-                   auto=auto)
-
-
-def _barrier_with_grad():
-    """``lax.optimization_barrier`` if this jax can differentiate
-    through it, else identity.  The barrier is a pure perf hint
-    (materialize per-layer weight slices so XLA doesn't pick the
-    half-rate batch-in-sublanes emitter — see trunk()); on jax builds
-    without its autodiff rule the train step must still build."""
-    try:
-        jax.eval_shape(jax.grad(lambda x: lax.optimization_barrier(x)),
-                       jax.ShapeDtypeStruct((), jnp.float32))
-        return lax.optimization_barrier
-    except Exception:       # noqa: BLE001 — NotImplementedError et al.
-        return lambda x: x
-
-
-_opt_barrier = _barrier_with_grad()
+           "build_spmd_train_step"]
 
 
 def _glorot(key, shape):
@@ -93,8 +48,11 @@ def init_gpt_params(cfg: GPTConfig, key) -> Dict:
     ks = jax.random.split(key, 8)
     blocks = {
         "ln1_g": jnp.ones((L, D)), "ln1_b": jnp.zeros((L, D)),
-        "qkv_w": _glorot(ks[0], (L, D, 3 * D)),
-        "qkv_b": jnp.zeros((L, 3 * D)),
+        # q/k/v sections on their own axis: sharding the last (head-major)
+        # axis over mp gives every shard whole heads of all three — a
+        # contiguous split of a packed 3*D axis would not
+        "qkv_w": _glorot(ks[0], (L, D, 3 * D)).reshape(L, D, 3, D),
+        "qkv_b": jnp.zeros((L, 3, D)),
         "out_w": _glorot(ks[1], (L, D, D)), "out_b": jnp.zeros((L, D)),
         "ln2_g": jnp.ones((L, D)), "ln2_b": jnp.zeros((L, D)),
         "up_w": _glorot(ks[2], (L, D, H)), "up_b": jnp.zeros((L, H)),
@@ -118,7 +76,8 @@ def gpt_param_shardings(mesh: Mesh, cfg: GPTConfig) -> Dict:
 
     blocks = {
         "ln1_g": ns("pp", None), "ln1_b": ns("pp", None),
-        "qkv_w": ns("pp", None, "mp"), "qkv_b": ns("pp", "mp"),
+        "qkv_w": ns("pp", None, None, "mp"),
+        "qkv_b": ns("pp", None, "mp"),
         "out_w": ns("pp", "mp", None), "out_b": ns("pp", None),
         "ln2_g": ns("pp", None), "ln2_b": ns("pp", None),
         "up_w": ns("pp", None, "mp"), "up_b": ns("pp", "mp"),
@@ -138,9 +97,12 @@ def _layernorm(x, g, b, eps=1e-5):
     return (x - mu) * lax.rsqrt(var + eps) * g + b
 
 
-def make_block_fn(cfg: GPTConfig, sp_axis: Optional[str] = None):
+def make_block_fn(cfg: GPTConfig, sp_axis: Optional[str] = None,
+                  mesh: Optional[Mesh] = None):
     """One transformer block; with sp_axis set, attention runs as ring
-    attention over that manual mesh axis (sequence/context parallel)."""
+    attention over that manual mesh axis (sequence/context parallel).
+    Under a ``mesh`` the flash kernels run per shard: batch over
+    dp/sharding, heads over mp."""
     h, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
 
     def block_fn(p, x):
@@ -148,17 +110,20 @@ def make_block_fn(cfg: GPTConfig, sp_axis: Optional[str] = None):
         # x: (mb, T_local, D)
         B, T, D = x.shape
         y = _layernorm(x, p["ln1_g"], p["ln1_b"])
-        qkv = y @ p["qkv_w"] + p["qkv_b"]
+        qkv = jnp.einsum("btd,dse->btse", y, p["qkv_w"]) + p["qkv_b"]
         if sp_axis is not None:
             from ..distributed.fleet.meta_parallel.sequence_parallel \
                 import ring_attention
-            q, k, v = jnp.split(qkv.reshape(B, T, 3 * h, hd), 3, axis=2)
+            q, k, v = (qkv[:, :, i].reshape(B, T, h, hd)
+                       for i in range(3))
             ctx = ring_attention(q, k, v, sp_axis, causal=True)
             ctx = ctx.reshape(B, T, D)
         else:
             # packed path: attention straight off the projection output,
             # no head-split / transpose copies in HBM
-            ctx = flash_attention_qkv(qkv, h, causal=True)  # (B, T, D)
+            ctx = flash_attention_qkv(
+                qkv, h, causal=True, mesh=mesh,
+                batch_axes=("dp", "sharding"), head_axes=("mp",))
         ctx = checkpoint_name(ctx, "attn_ctx")
         x = x + ctx @ p["out_w"] + p["out_b"]
         y = _layernorm(x, p["ln2_g"], p["ln2_b"])
@@ -202,20 +167,13 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
     sp = mesh.shape.get("sp", 1)
     sharding_n = mesh.shape.get("sharding", 1)
     use_pp, use_sp = pp > 1, sp > 1
-    if (use_pp or use_sp) and not HAS_MANUAL_PIPELINE:
-        import warnings
-        warnings.warn(
-            "build_spmd_train_step: this jax has no partial-manual "
-            "jax.shard_map — pp/sp run the GSPMD scan fallback "
-            "(identical numerics, no pipeline/ring overlap)")
-        use_pp = use_sp = False
     use_zero = sharding_n > 1
     # only axes actually present in the mesh shard the batch (a pp-only
     # mesh has no dp axis at all; size-1 axes are no-ops)
     batch_axes = tuple(a for a in ("dp", "sharding")
                        if mesh.shape.get(a, 1) > 1) or None
     sp_axis = "sp" if use_sp else None
-    block_fn = make_block_fn(cfg, sp_axis=sp_axis)
+    block_fn = make_block_fn(cfg, sp_axis=sp_axis, mesh=mesh)
 
     # remat policy (reference recompute_optimizer checkpoints attr):
     #   full — recompute everything in backward (min HBM, +1/3 flops)
@@ -280,7 +238,7 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
             # batch-in-sublanes emitter (profiled r5: the down-proj+LN
             # fusion ran 3.43 ms vs 1.81 with materialized weights —
             # the copies themselves are ~0.1 ms/layer)
-            p_i = _opt_barrier(p_i)
+            p_i = lax.optimization_barrier(p_i)
             x = maybe_remat(block_fn)(p_i, x)
         return _layernorm(x, params["ln_f_g"], params["ln_f_b"])
 
@@ -311,7 +269,7 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
                                      axis="pp", num_stages=pp,
                                      num_microbatches=M)
 
-            xm = _shard_map(
+            xm = jax.shard_map(
                 piped, mesh=mesh, in_specs=(P("pp"), x_spec),
                 out_specs=x_spec, axis_names={"pp"} | ({"sp"} if use_sp
                                                        else set()),
@@ -325,7 +283,7 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
                     return maybe_remat(block_fn)(p, h), None
                 h, _ = lax.scan(body, xi, bp)
                 return h
-            x = _shard_map(
+            x = jax.shard_map(
                 seq_par, mesh=mesh, in_specs=(P(None), P(None, "sp")),
                 out_specs=P(None, "sp"), axis_names={"sp"},
                 check_vma=False)(params["blocks"], x)
@@ -392,16 +350,20 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
         x = trunk(params, ids)
         head_w = params["head_w"].astype(x.dtype)
         B, T, D = x.shape
-        if jax.default_backend() == "tpu" and mesh.size == 1:
+        from ..ops import pallas
+        fused = pallas.enabled() and mesh.size == 1
+        interpret = pallas.note("softmax_xent", fused)
+        if fused:
             # fused pallas head (softmax_xent.py): no (N, V) logits in
             # the forward at all — the kernel streams W tiles through
             # VMEM with online stats (the chunked path below writes +
             # re-reads 500 MB of f32 logits per chunk; measured r5:
             # fused fwd 23.5 ms vs 28.5, and the saved-lse backward
-            # skips the stat recompute)
+            # skips the stat recompute).  One device only: the kernel
+            # has no cross-shard lse combine for a vocab-sharded head.
             from ..ops.pallas.softmax_xent import softmax_xent_loss
             return softmax_xent_loss(x.reshape(B * T, D), head_w,
-                                     labels.reshape(B * T))
+                                     labels.reshape(B * T), interpret)
         return chunked_ce(x, head_w, labels)
 
     def adamw_update(params, grads, opt_state):
@@ -475,7 +437,7 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
             return loss, dbp, dxi, dhp
 
         lab_spec = P(None, None, "sp") if use_sp else P(None)
-        loss, dblocks, dx, dhead = _shard_map(
+        loss, dblocks, dx, dhead = jax.shard_map(
             run, mesh=mesh,
             in_specs=(P("pp"), x_spec, lab_spec, P()),
             out_specs=(P(), P("pp"), x_spec, P()),

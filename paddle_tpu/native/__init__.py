@@ -5,17 +5,21 @@ Reference parity map (see src/native.cc header): blocking_queue.h,
 auto_growth_best_fit_allocator.h:30, platform/profiler.h:216,
 platform/monitor.h:77.
 
-The library is compiled in-repo on first use (g++ -O2 -shared) and bound
-via ctypes — the image has no pybind11, and a C ABI keeps the binding
-layer trivial.  Every consumer has a pure-Python fallback so the
-framework still works if no toolchain is present.
+The library is compiled in-repo on first use (g++ -O2 -shared, keyed by
+source hash — ``_build.py``) and bound via ctypes — the image has no
+pybind11, and a C ABI keeps the binding layer trivial.  Every consumer
+has a pure-Python path, so the framework still works if no toolchain is
+present; a failed build says so once on stderr.
 """
 from __future__ import annotations
 
 import ctypes
 import os
 import subprocess
+import sys
 import threading
+
+from ._build import build_shared, failure_text
 
 __all__ = ["available", "lib", "BlockingQueue", "Arena", "Profiler",
            "stat_add", "stat_get", "stat_reset"]
@@ -28,20 +32,6 @@ _lib = None
 _lock = threading.Lock()
 
 
-def _build() -> bool:
-    try:
-        src_mtime = os.path.getmtime(_SRC)
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= src_mtime:
-            return True
-        cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-               _SRC, "-o", _SO + ".tmp"]
-        subprocess.run(cmd, check=True, capture_output=True, timeout=240)
-        os.replace(_SO + ".tmp", _SO)
-        return True
-    except Exception:
-        return False
-
-
 def lib():
     """Load (building if needed) the native library; None if unavailable."""
     global _lib
@@ -50,12 +40,13 @@ def lib():
     with _lock:
         if _lib is not None:
             return _lib if _lib is not False else None
-        if not _build():
-            _lib = False
-            return None
         try:
+            build_shared(_SO, [_SRC], ["-pthread"])
             L = ctypes.CDLL(_SO)
-        except OSError:
+        except (OSError, subprocess.SubprocessError) as e:
+            print(f"paddle_tpu.native: {_SO} could not be built or "
+                  f"loaded ({failure_text(e)}); using the pure-Python "
+                  "queue/arena/profiler paths", file=sys.stderr)
             _lib = False
             return None
         # signatures

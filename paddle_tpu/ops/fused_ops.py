@@ -3,8 +3,8 @@
 Reference parity: ``operators/fused/fused_dropout_helper.h`` (the
 LayernormResidualDropoutBias functor family) — the epilogue the reference
 fuses into its fused_attention / fused_feedforward CUDA ops.  Here the op
-is one pallas kernel on TPU (ops/pallas/fused_ln.py) with an XLA fallback
-that produces bit-identical results (shared counter-based hash RNG), so
+is one pallas kernel on TPU (ops/pallas/fused_ln.py); the XLA math
+produces bit-identical results (shared counter-based hash RNG), so
 ``FLAGS_use_pallas`` flips the implementation without changing numerics.
 
 Backward recomputes the dropout mask from (seed, index) — no stored mask
@@ -46,9 +46,10 @@ def _fused_math(x, residual, bias, gamma, beta, seed, *, p, eps):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
 def _fused(x, residual, bias, gamma, beta, seed, p, eps, use_pallas):
+    from .pallas import note
+    interpret = note("fused_ln", use_pallas)
     if use_pallas:
         from .pallas.fused_ln import fused_ln_pallas
-        interpret = jax.default_backend() == "cpu"
         return fused_ln_pallas(x, residual, bias, gamma, beta, seed,
                                p=p, eps=eps, interpret=interpret)
     return _fused_math(x, residual, bias, gamma, beta, seed, p=p, eps=eps)

@@ -140,8 +140,10 @@ def bilinear(x1, x2, weight, bias=None, name=None):
 
 
 def _sdpa_xla(q, k, v, *rest, causal=False, scale=None, dropout_p=0.0,
-              dropout_key=None, has_mask=False):
-    """Reference attention math (XLA fused).  q/k/v: (B, S, H, D)."""
+              dropout_key=None, has_mask=False, mesh_spec=None):
+    """Reference attention math (XLA fused).  q/k/v: (B, S, H, D).
+    ``mesh_spec`` is for the pallas registration: GSPMD partitions this
+    math by itself."""
     d = q.shape[-1]
     s = scale if scale is not None else 1.0 / np.sqrt(d)
     qh = jnp.swapaxes(q, 1, 2)  # B,H,S,D
@@ -166,15 +168,24 @@ register_kernel("scaled_dot_product_attention", "xla")(_sdpa_xla)
 
 
 def _sdpa_pallas(q, k, v, *rest, causal=False, scale=None, dropout_p=0.0,
-                 dropout_key=None, has_mask=False):
+                 dropout_key=None, has_mask=False, mesh_spec=None):
     """Flash-attention pallas kernel (ops/pallas/flash_attention.py);
-    mask/dropout variants fall back to the XLA math."""
+    the kernels take no mask and no dropout, so those variants run the
+    XLA math (counted as ``flash_attention.xla``)."""
     if has_mask or dropout_p > 0.0:
+        from .pallas import note
+        note("flash_attention", False)
         return _sdpa_xla(q, k, v, *rest, causal=causal, scale=scale,
                          dropout_p=dropout_p, dropout_key=dropout_key,
                          has_mask=has_mask)
     from .pallas.flash_attention import flash_attention
-    return flash_attention(q, k, v, causal=causal, scale=scale)
+    # under a mesh the kernels run per shard (``pallas.kernel_mesh``,
+    # entered by DataParallel.forward); sharded operands with no mesh
+    # given fail at lowering — "Mosaic kernels cannot be automatically
+    # partitioned" — rather than quietly taking other math
+    mesh, batch_axes, head_axes = mesh_spec or (None, (), ())
+    return flash_attention(q, k, v, causal=causal, scale=scale, mesh=mesh,
+                           batch_axes=batch_axes, head_axes=head_axes)
 
 
 register_kernel("scaled_dot_product_attention", "pallas")(_sdpa_pallas)
@@ -194,10 +205,12 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     # kwargs — dispatch itself swaps in the pallas registration when
     # preferred_backend() says so (core/dispatch.py)
     impl = get_kernel("scaled_dot_product_attention", "xla")
+    from .pallas import current_kernel_mesh
     return dispatch("scaled_dot_product_attention", impl, tensors,
                     dict(causal=is_causal, scale=scale,
                          dropout_p=dropout_p if training else 0.0,
-                         dropout_key=dkey, has_mask=has_mask))
+                         dropout_key=dkey, has_mask=has_mask,
+                         mesh_spec=current_kernel_mesh()))
 
 
 def sparse_attention(query, key, value, sparse_csr_offset, sparse_csr_columns,

@@ -22,11 +22,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 ships the params class as TPUCompilerParams (same fields);
-# the modern name is CompilerParams — resolve whichever this jax has
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 __all__ = ["fused_ln_pallas", "hash_uniform"]
 
 
@@ -49,7 +44,10 @@ def hash_uniform(seed, shape, offset=0):
     h = h ^ (h >> 13)
     h = h * jnp.uint32(0xC2B2AE35)
     h = h ^ (h >> 16)
-    return (h >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+    # 24 random bits: the value is < 2**24, so the route through int32
+    # is exact (Mosaic has no uint32 -> float32 cast)
+    return (h >> 8).astype(jnp.int32).astype(jnp.float32) \
+        * (1.0 / (1 << 24))
 
 
 def _kernel(x_ref, res_ref, bias_ref, gamma_ref, beta_ref, seed_ref,
@@ -74,20 +72,29 @@ def fused_ln_pallas(x, residual, bias, gamma, beta, seed, *, p: float,
                     eps: float, interpret: bool = False):
     """x/residual: (N, D); bias/gamma/beta: (D,); seed: uint32 scalar."""
     N, D = x.shape
-    block_rows = next(b for b in (256, 128, 64, 32, 16, 8, 4, 2, 1)
-                      if N % b == 0)
-    grid = (N // block_rows,)
+    # row blocks tile in sublanes (8 rows of 32 bits, 16 of bf16): pad
+    # a ragged row count up (the hash indexes elements by row * D + col,
+    # so real rows keep their bits) and slice the pad rows off the result
+    sub = 32 // x.dtype.itemsize
+    Np = -(-N // sub) * sub
+    if Np != N:
+        x, residual = (jnp.pad(a, ((0, Np - N), (0, 0)))
+                       for a in (x, residual))
+    block_rows = next(b for b in (256, 128, 64, 32, 16, 8)
+                      if Np % b == 0 and b >= sub)
+    grid = (Np // block_rows,)
     row_spec = pl.BlockSpec((block_rows, D), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((1, D), lambda i: (0, 0))
     one_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_kernel, p=p, eps=eps, block_rows=block_rows, D=D),
         grid=grid,
         in_specs=[row_spec, row_spec, vec_spec, vec_spec, vec_spec, one_spec],
         out_specs=row_spec,
-        out_shape=jax.ShapeDtypeStruct((N, D), x.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((Np, D), x.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, residual, bias.reshape(1, D), gamma.reshape(1, D),
       beta.reshape(1, D), jnp.asarray(seed, jnp.uint32).reshape(1, 1))
+    return out[:N] if Np != N else out

@@ -35,11 +35,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 ships the params class as TPUCompilerParams (same fields);
-# the modern name is CompilerParams — resolve whichever this jax has
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 __all__ = ["softmax_xent_loss", "softmax_xent_fwd"]
 
 NEG_INF = -1e30
@@ -122,7 +117,7 @@ def softmax_xent_fwd(x, w, labels, block_rows: int = 1024,
             pltpu.VMEM((block_rows, 1), jnp.float32),
             pltpu.VMEM((block_rows, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, w, lab2)
@@ -173,7 +168,7 @@ def softmax_xent_dlogits(x, w, labels, lse, gscale,
         out_specs=pl.BlockSpec((block_rows, block_v),
                                lambda c, v: (c, v)),
         out_shape=jax.ShapeDtypeStruct((N, Vp), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, w, lab2, lse2, g2)

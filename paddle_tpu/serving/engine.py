@@ -828,25 +828,19 @@ class InferenceEngine:
 
         def compile_fn():
             jit_fn = predictor._compiled_call()
-            try:
-                avals = [jax.ShapeDtypeStruct(a.shape, a.dtype)
-                         for a in arrays]
-                lead_avals = [jax.tree_util.tree_map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
-                    for t in leading]
-                # AOT artifact store: an engine relaunch loads the
-                # persisted executable instead of re-compiling the
-                # bucket (utils/artifact_store.py; armed with
-                # FLAGS_compile_cache_dir)
-                from ..utils.artifact_store import aot_compile
-                return aot_compile(
-                    jit_fn.lower(*lead_avals, *avals),
-                    label=f"{self.config.name}.bucket")
-            except Exception:
-                # AOT lowering unsupported for this export: fall back to
-                # the shared jit wrapper (its shape-keyed cache makes the
-                # first call the compile, still once per bucket key)
-                return jit_fn
+            avals = [jax.ShapeDtypeStruct(a.shape, a.dtype)
+                     for a in arrays]
+            lead_avals = [jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), t)
+                for t in leading]
+            # AOT artifact store: an engine relaunch loads the
+            # persisted executable instead of re-compiling the bucket
+            # (utils/artifact_store.py; armed with
+            # FLAGS_compile_cache_dir)
+            from ..utils.artifact_store import aot_compile
+            return aot_compile(
+                jit_fn.lower(*lead_avals, *avals),
+                label=f"{self.config.name}.bucket")
         exe = self._cache.get_or_compile(key, compile_fn)
         out = exe(*leading, *arrays)
         return predictor._finalize_outputs(out)

@@ -21,8 +21,8 @@ from . import compile_cache  # noqa: F401
 from . import artifact_store  # noqa: F401
 from . import cpp_extension  # noqa: F401
 
-# backend init: arm the persistent XLA compilation cache when
-# FLAGS_compile_cache_dir is set (env or earlier define); supervised
-# relaunches then skip recompiles entirely.  The AOT artifact store
-# (artifact_store.py) arms off the same flag at its own import.
+# place JAX's persistent compilation cache before anything compiles
+# (JAX_COMPILATION_CACHE_DIR, else <repo>/.jax_cache); the AOT artifact
+# store (artifact_store.py) arms off FLAGS_compile_cache_dir at its own
+# import.
 compile_cache.configure()

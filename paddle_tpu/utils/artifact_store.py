@@ -21,13 +21,14 @@ in the key AND re-checked from the entry header on load).
 Entry layout (``<root>/objects/<fp[:2]>/<fp>.bin``)::
 
     PTAOT1\\n
-    {json header: payload sha256+size, jax/jaxlib/backend, label}\\n
+    {json header: payload sha256+size, jax/jaxlib/backend, label,
+                  ids of the devices the executable runs on}\\n
     <pickled (serialized_executable, in_tree, out_tree)>
 
 Every load re-hashes the payload against the header (the PR 3 manifest
 pattern): truncated, bit-flipped, or version-mismatched entries **miss
-cleanly** — counted, quarantine-deleted, recompiled — never crash and
-never serve wrong code.  ``<root>/index.json`` tracks per-entry size and
+cleanly** — counted, quarantine-deleted, recompiled — and never serve
+wrong code; an intact entry that then fails to load raises.  ``<root>/index.json`` tracks per-entry size and
 last-use for the LRU size-cap GC (``FLAGS_aot_store_max_mb``); the
 blobs are self-verifying, so a lost or stale index only costs GC
 bookkeeping, not correctness.
@@ -36,8 +37,9 @@ Metrics (PR 1 registry): ``aot_store.hit`` / ``miss`` / ``store`` /
 ``corrupt`` / ``evicted`` / ``bypass``.
 
 The module-level store arms from ``FLAGS_compile_cache_dir`` (root =
-``<dir>/artifacts``) at import and on every ``set_flags`` — the same
-switch that arms jax's persistent cache, so one flag warms both layers.
+``<dir>/artifacts``) at import and on every ``set_flags``, and from
+nothing else: jax's persistent cache is placed independently
+(``utils/compile_cache.py``) and does not arm the store.
 """
 from __future__ import annotations
 
@@ -48,6 +50,8 @@ import pickle
 import time
 from typing import Optional, Tuple
 
+import jax.monitoring
+
 from . import concurrency as _conc
 from . import flags as _flags
 
@@ -56,6 +60,18 @@ __all__ = ["ArtifactStore", "active", "configure", "aot_compile",
 
 _MAGIC = b"PTAOT1\n"
 _METRIC_PREFIX = "aot_store"
+
+# compiles that JAX's own persistent cache served (utils/compile_cache.py)
+_jax_cache_hits = 0
+
+
+def _on_jax_event(event, **_):
+    global _jax_cache_hits
+    if event == "/jax/compilation_cache/cache_hits":
+        _jax_cache_hits += 1
+
+
+jax.monitoring.register_event_listener(_on_jax_event)
 
 
 def _m(name: str):
@@ -165,7 +181,7 @@ class ArtifactStore:
     def get(self, fp: str):
         """Deserialize-and-load the entry for ``fp``; None on miss.
         Corrupt/mismatched entries are deleted and counted, never
-        served."""
+        served; an intact entry that fails to load raises."""
         path = self._obj_path(fp)
         try:
             with open(path, "rb") as f:
@@ -190,9 +206,10 @@ class ArtifactStore:
                     f"{header.get('jaxlib')}/{header.get('backend')} vs "
                     f"running {jax_v}/{jaxlib_v}/{backend})")
             serialized, in_tree, out_tree = pickle.loads(payload)
-            from jax.experimental import serialize_executable as _se
-            exe = _se.deserialize_and_load(serialized, in_tree, out_tree)
-        except Exception:       # noqa: BLE001 — any defect = clean miss
+            devices = [int(i) for i in header["devices"]]
+        except (ValueError, KeyError, TypeError, EOFError,
+                pickle.UnpicklingError):
+            # a damaged or foreign entry is a clean miss
             _m("corrupt").inc()
             try:
                 os.unlink(path)
@@ -203,6 +220,15 @@ class ArtifactStore:
                 if idx.pop(fp, None) is not None:
                     self._write_index(idx)
             return None
+        from jax.experimental import serialize_executable as _se
+        # load onto the devices the executable was compiled for — the
+        # default (every device of the backend) makes a one-device
+        # program expect one shard per visible device.  An intact entry
+        # that will not load is a defect of the store, not a miss: raise.
+        by_id = {d.id: d for d in jax.devices()}
+        exe = _se.deserialize_and_load(
+            serialized, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in devices])
         with self._lock:        # LRU bookkeeping (best-effort)
             idx = self._load_index()
             ent = idx.get(fp) or {"size": len(blob)}
@@ -226,6 +252,8 @@ class ArtifactStore:
             "sha256": hashlib.sha256(payload).hexdigest(),
             "size": len(payload), "jax": jax_v, "jaxlib": jaxlib_v,
             "backend": backend, "label": label, "fingerprint": fp,
+            "devices": [d.id for d in
+                        compiled.runtime_executable().local_devices()],
         }, sort_keys=True).encode()
         blob = _MAGIC + header + b"\n" + payload
         path = self._obj_path(fp)
@@ -306,12 +334,20 @@ class ArtifactStore:
                     provenance="store-hit", cause="cached")
             return exe
         _m("miss").inc()
+        hits0 = _jax_cache_hits
         compiled = lowered.compile()
         if _memscope.active:
             _memscope.compile_record(
                 label or "aot", fp, time.perf_counter() - t0,
                 provenance="store-miss")
-        self.put(fp, compiled, label=label)
+        if _jax_cache_hits != hits0:
+            # JAX's persistent cache served this compile: it is already
+            # persistent there, and XLA:CPU cannot re-serialize an
+            # executable it loaded from that cache (the blob would fail
+            # at run time with NOT_FOUND)
+            _m("bypass").inc()
+        else:
+            self.put(fp, compiled, label=label)
         return compiled
 
 

@@ -1,90 +1,56 @@
-"""Persistent XLA compilation cache wiring.
+"""Where JAX's persistent compilation cache lives.
 
 Reference parity: the reference caches compiled programs in-process per
 ``ProgramDesc``; on TPU the expensive artifact is the XLA executable, and
 jax ships a content-addressed on-disk compilation cache for exactly the
-relaunch/restart case (supervised restarts from PR 3 re-trace and
-re-compile every jitted step otherwise — tens of seconds of cold start
-for the BERT-base config).
+relaunch/restart case (a supervised restart otherwise re-compiles every
+jitted step — tens of seconds for the BERT-base config).
 
-``configure()`` runs once at backend init (package import) and again on
-every ``set_flags`` via the flags observer, so
-``FLAGS_compile_cache_dir`` can be armed either from the environment
-(``FLAGS_compile_cache_dir=/path python train.py``) or at runtime before
-the first compile.  The thresholds jax gates persistence on (min compile
-seconds / min entry bytes) are zeroed so every executable lands in the
-cache — a restarted trainer wants ALL of its programs back, not just the
-slow ones.
+The cache is placed from outside.  ``JAX_COMPILATION_CACHE_DIR`` in the
+environment is read by JAX itself and this module then sets no directory
+at all.  Without it the cache goes to one fixed path inside the checkout,
+``<repo>/.jax_cache`` — the path is part of the cache key, so it is
+derived from the package location and never from a temp dir, a pid or
+the clock.  ``configure()`` runs once at package import, before the first
+compile.  The thresholds jax gates persistence on (min compile seconds /
+min entry bytes) are zeroed so every executable lands in the cache — a
+restarted trainer wants ALL of its programs back, not just the slow ones.
 """
 from __future__ import annotations
 
 import os
 from typing import Optional
 
-from . import flags as _flags
+__all__ = ["configure", "cache_dir", "entry_count", "DEFAULT_DIR"]
 
-__all__ = ["configure", "cache_dir", "entry_count"]
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-_state = {"dir": None}
 
-
-def configure() -> Optional[str]:
-    """Point jax's persistent compilation cache at
-    ``FLAGS_compile_cache_dir`` (no-op when the flag is empty or the
-    value is unchanged).  Returns the active cache dir or None."""
-    d = _flags.get_flag("FLAGS_compile_cache_dir") or ""
-    if d:
-        d = os.path.abspath(d)   # compare canonical: the observer runs
-        # on EVERY set_flags and must no-op when the dir is unchanged
-    if d == (_state["dir"] or ""):
-        return _state["dir"]
-    if not d:
-        # jax has no supported "unset" once armed; leave the existing
-        # cache live for this process and stop tracking it
-        _state["dir"] = None
-        return None
+def configure() -> None:
     import jax
-    os.makedirs(d, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", d)
-    except Exception:
-        return None          # ancient jax without the knob: degrade
     # persistence thresholds: cache everything, not just slow compiles
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:
-            pass
-    # jax latches its cache state at the first compile; a dir armed at
-    # runtime (set_flags after training started) is silently ignored
-    # unless the latch is cleared
-    try:
-        from jax._src import compilation_cache as _jcc
-        _jcc.reset_cache()
-    except Exception:
-        pass
-    _state["dir"] = d
-    return d
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
 
 
 def cache_dir() -> Optional[str]:
-    """The directory configure() armed, or None."""
-    return _state["dir"]
+    """The directory JAX's persistent cache is using (None: disabled)."""
+    import jax
+    return jax.config.jax_compilation_cache_dir
 
 
 def entry_count(d: Optional[str] = None) -> int:
-    """Number of cached executables on disk (0 when no cache is
-    configured).  bench.py diffs this across a run to report cold-start
-    vs steady-state compile counts."""
-    d = d or _state["dir"]
+    """Number of cached executables on disk (0 when the directory does
+    not exist yet).  A second run that compiles nothing new leaves this
+    unchanged."""
+    d = d or cache_dir()
     if not d or not os.path.isdir(d):
         return 0
     n = 0
     for _root, _dirs, files in os.walk(d):
-        n += sum(1 for f in files if not f.startswith("."))
+        n += sum(1 for f in files if f.endswith("-cache"))
     return n
-
-
-# re-wire whenever flags change (set_flags({"FLAGS_compile_cache_dir": ...}))
-_flags.on_change(configure)

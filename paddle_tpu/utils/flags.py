@@ -172,12 +172,13 @@ define_flag("FLAGS_anomaly_action", "",
             "continue), 'rollback' (restore the newest intact "
             "checkpoint when fit(checkpointer=...) is set, else skip)")
 define_flag("FLAGS_compile_cache_dir", "",
-            "persistent XLA compilation cache directory (jax "
-            "compilation cache): relaunches and supervised restarts "
-            "(launch --supervise) reuse compiled executables instead "
-            "of re-tracing + re-compiling every program; empty "
-            "disables.  Wired at backend init "
-            "(utils/compile_cache.py) and re-wired on set_flags")
+            "root of the AOT artifact store "
+            "(<dir>/artifacts, utils/artifact_store.py): relaunches "
+            "deserialize persisted executables instead of compiling; "
+            "empty leaves the store off.  JAX's own persistent "
+            "compilation cache is NOT placed by this flag: it follows "
+            "JAX_COMPILATION_CACHE_DIR, else <repo>/.jax_cache "
+            "(utils/compile_cache.py)")
 define_flag("FLAGS_lock_san", 0,
             "runtime lock sanitizer level for the framework's named "
             "locks (utils/concurrency.py): 0 = off (factories return "

@@ -9,6 +9,11 @@ import sys
 
 # Must happen before jax backend init.
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+# Hermetic tests: no compile is served from, or written to, a persistent
+# cache left by an earlier run (the package would otherwise place one at
+# <repo>/.jax_cache).  Inherited by the processes the tests spawn; the
+# placement itself is pinned by tests/test_placement.py.
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if repo_root not in sys.path:
     sys.path.insert(0, repo_root)

@@ -373,22 +373,13 @@ def test_checkpointer_want_save_interval(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# persistent compilation cache flag
+# persistent compilation cache accounting (placement: test_placement.py)
 # ---------------------------------------------------------------------------
 
-def test_compile_cache_flag_wires_jax_config(tmp_path):
-    import jax
+def test_compile_cache_entry_count(tmp_path):
     d = str(tmp_path / "xla_cache")
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        flags.set_flags({"FLAGS_compile_cache_dir": d})
-        assert compile_cache.cache_dir() == os.path.abspath(d)
-        assert jax.config.jax_compilation_cache_dir == os.path.abspath(d)
-        assert os.path.isdir(d)
-        assert compile_cache.entry_count() == 0
-        open(os.path.join(d, "entry_a"), "w").close()
-        assert compile_cache.entry_count() == 1
-    finally:
-        flags.set_flags({"FLAGS_compile_cache_dir": ""})
-        jax.config.update("jax_compilation_cache_dir", prev)
-    assert compile_cache.cache_dir() is None
+    assert compile_cache.entry_count(d) == 0      # not created yet
+    os.makedirs(d)
+    open(os.path.join(d, "jit_f-0123-cache"), "w").close()
+    open(os.path.join(d, "jit_f-0123-atime"), "w").close()
+    assert compile_cache.entry_count(d) == 1

@@ -214,31 +214,3 @@ def test_cost_report_uses_real_axis_sizes(gpt):
     t8 = pl._allreduce_time(plan8.report.mp_comm_bytes, 8)
     assert abs((plan8.report.total_s - plan8.report.compute_s) - t8) \
         < 1e-9
-
-
-def test_flagship_prediction_within_30pct_of_measured_bench():
-    """Cost-model validation against reality (the in-tree check the r4
-    verdict said was missing): the planner's predicted single-chip step
-    time for the flagship bench config must be within ~30% of the
-    driver-measured BENCH throughput."""
-    import json
-    import os
-    bench_path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "BENCH_r04.json")
-    if not os.path.exists(bench_path):
-        pytest.skip("no driver BENCH artifact in tree")
-    with open(bench_path) as f:
-        bench = json.load(f)
-    seq_per_s = float(bench["parsed"]["value"])
-    measured_step_s = 128.0 / seq_per_s       # B=128 (bench.py config)
-    paddle.seed(0)
-    cfg = GPTConfig(vocab_size=30528, hidden_size=768, num_layers=12,
-                    num_heads=12, max_seq_len=512)
-    g = GPT(cfg)
-    ids = paddle.to_tensor(np.zeros((2, 8), np.int32))
-    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1), ("dp", "mp"))
-    plan = fleet.auto.plan_model(g, mesh, tokens=128 * 512,
-                                 sample_input=ids)
-    pred = plan.report.total_s
-    assert 0.7 * measured_step_s < pred < 1.3 * measured_step_s, \
-        (pred, measured_step_s)

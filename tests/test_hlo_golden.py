@@ -68,16 +68,19 @@ def test_pp_produces_collective_permute():
 
 def test_1f1b_has_reverse_permutes():
     """1F1B adds the cotangent hops: the backward ppermute uses the
-    reverse permutation (pairs {1,0},{2,1},... alongside the forward's
-    {0,1},{1,2},...)."""
+    reverse permutation.  In the (dp, pp, mp) = (2, 2, 2) mesh the pp
+    neighbours sit at device stride 2, so the forward hop is {0,2} and
+    the reverse {2,0}; no mp-neighbour ({0,1}) permute may appear — the
+    per-section qkv layout leaves nothing to reshard between mp ranks."""
     txt = _hlo(build_mesh({"dp": 2, "pp": 2, "mp": 2}),
                num_microbatches=2, schedule_mode="1F1B")
-    perms = re.findall(r"collective-permute[^\n]*source_target_pairs=\{([^}]*)\}",
-                       txt)
+    perms = re.findall(
+        r"collective-permute[^\n]*source_target_pairs=\{((?:\{[^}]*\},?)*)\}",
+        txt)
     assert perms, "no collective-permutes in 1F1B program"
     joined = ";".join(perms)
-    assert "{0,1}" in joined or "0,1" in joined
-    assert "{1,0}" in joined or "1,0" in joined
+    assert "0,2" in joined and "2,0" in joined
+    assert "0,1" not in joined and "1,0" not in joined
 
 
 def test_single_device_has_no_collectives():

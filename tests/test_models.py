@@ -228,9 +228,8 @@ def test_graft_entry_multichip_dryrun():
     doc = ge.dryrun_multichip(8)
     # the MULTICHIP doc carries a MEASURED schedule per mesh, not just
     # a parity bit: every record has warm step wall time and tokens/s,
-    # and every pp>1 mesh lands in the pipeline.measured list with an
-    # honest schedule label (1F1B when manual shard_map pipelining is
-    # available, pp-scan-fallback otherwise)
+    # and every pp>1 mesh lands in the pipeline.measured list labelled
+    # with the schedule it ran (1F1B)
     assert doc["devices"] == 8 and doc["meshes"]
     for m in doc["meshes"]:
         assert m["step_time_s"] > 0 and m["tokens_per_s"] > 0
@@ -238,6 +237,4 @@ def test_graft_entry_multichip_dryrun():
     pp_meshes = [m for m in doc["meshes"] if m["dims"]["pp"] > 1]
     assert pp_meshes, "no pp>1 mesh in the 8-device dryrun"
     assert doc["pipeline"]["measured"] == pp_meshes
-    from paddle_tpu.models import gpt_spmd
-    want = "1F1B" if gpt_spmd.HAS_MANUAL_PIPELINE else "pp-scan-fallback"
-    assert all(m["schedule"] == want for m in pp_meshes)
+    assert all(m["schedule"] == "1F1B" for m in pp_meshes)

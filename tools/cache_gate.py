@@ -30,6 +30,7 @@ Usage: python tools/cache_gate.py          (parent: orchestrates)
 import hashlib
 import json
 import os
+import shutil
 import subprocess
 import sys
 import tempfile
@@ -125,8 +126,11 @@ def child(workdir: str):
 
 
 def run_child(workdir: str, cache_dir: str) -> dict:
+    # the store is gated alone: with JAX's own persistent cache on, a
+    # compile it serves is (rightly) not stored a second time
     env = dict(os.environ,
                JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="false",
                FLAGS_compile_cache_dir=cache_dir,
                FLAGS_prefetch_to_device="2",
                PYTHONPATH=REPO + os.pathsep
@@ -150,8 +154,11 @@ def main():
     if len(sys.argv) > 1 and sys.argv[1] == "--child":
         child(sys.argv[2])
         return
-    base = tempfile.mkdtemp(prefix="paddle_cache_gate_")
-    cache_dir = os.path.join(base, "compile_cache")
+    base = tempfile.mkdtemp(prefix="paddle_cache_gate_")   # model files
+    # the artifact store under test: a fixed path, emptied so that run 1
+    # starts cold
+    cache_dir = os.path.join(REPO, ".jax_cache", "cache_gate_store")
+    shutil.rmtree(cache_dir, ignore_errors=True)
     r1 = run_child(base, cache_dir)
     r2 = run_child(base, cache_dir)
     print(f"[cache_gate] run1 aot={r1['aot']} "

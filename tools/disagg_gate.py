@@ -157,7 +157,6 @@ def main():
     import paddle_tpu as paddle
 
     work = tempfile.mkdtemp(prefix="disagg_gate_")
-    cache = os.path.join(work, "compile_cache")
     kv = KVServer().start()
     spec = f"tcp://{kv.endpoint}"
 
@@ -166,7 +165,8 @@ def main():
         f.write(WORKER.format(repo=REPO, job=JOB, max_new=MAX_NEW,
                               bs=BS))
     env = dict(os.environ)
-    env["FLAGS_compile_cache_dir"] = cache   # replicas share AOT blobs
+    # the replicas share compiled programs through JAX's persistent
+    # cache at its fixed path (utils/compile_cache.py)
     env_chaos = dict(env, FLAGS_chaos_spec="kv.transfer:fail@1")
     procs = [
         subprocess.Popen([sys.executable, script, spec, "pre",
@@ -177,7 +177,6 @@ def main():
                           "decode"], env=env_chaos),
     ]
 
-    paddle.set_flags({"FLAGS_compile_cache_dir": cache})
     router = fleet.FleetRouter(spec, JOB, refresh_interval=0.1,
                                probe_interval=0.25,
                                manage_swaps=False).start()

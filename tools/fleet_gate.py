@@ -145,7 +145,6 @@ def main():
 
     work = tempfile.mkdtemp(prefix="fleet_gate_")
     wdir = os.path.join(work, "ckpts")
-    cache = os.path.join(work, "compile_cache")
     os.makedirs(wdir)
 
     # step 1: the fleet's boot weights (seed 0)
@@ -160,7 +159,8 @@ def main():
     with open(script, "w") as f:
         f.write(WORKER.format(repo=REPO, job=JOB, max_new=MAX_NEW))
     env = dict(os.environ)
-    env["FLAGS_compile_cache_dir"] = cache   # replicas share AOT blobs
+    # the replicas share compiled programs through JAX's persistent
+    # cache at its fixed path (utils/compile_cache.py)
     procs = [subprocess.Popen([sys.executable, script, spec,
                                f"g{i}", wdir], env=env)
              for i in (1, 2)]

@@ -218,9 +218,8 @@ def run_burst(work, tag):
     out = os.path.join(work, f"burst.{tag}.json")
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
                PADDLE_THREAD_CANARY="0")
-    # share the AOT cache between the two runs so run 2 is fast
-    env.setdefault("FLAGS_compile_cache_dir",
-                   os.path.join(work, "aot"))
+    # run 2 is fast: both runs share JAX's persistent cache at its
+    # fixed path (utils/compile_cache.py)
     r = subprocess.run([sys.executable, script, out], env=env,
                        cwd=REPO, capture_output=True, text=True,
                        timeout=900)
