@@ -23,10 +23,12 @@ nothing but its own set-up (compile seconds per phase, to show the
 persistent cache serving a second run) and prints no rate, utilization or
 peak.  It never sets JAX_PLATFORMS or a cache directory.
 
-The last line of stdout is one JSON object:
-    {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N},
-     "phases": {...}, "claim": null}
-and the same object is written to chiprun_out/chip_smoke.json.
+The last line of stdout is the result, one JSON object with exactly
+these keys, the device as JAX reports it:
+    {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}
+The full report ({"ok", "device", "phases": {...}, "compile_cache",
+"wall_s", "claim": null}) is the "[report]" line before it and the file
+chiprun_out/chip_smoke.json.
 
 The phases are importable functions taking sizes; tests/test_chip_smoke.py
 rehearses them at toy sizes on the CPU (main() has no CPU mode).
@@ -582,7 +584,10 @@ def main():
     os.makedirs("chiprun_out", exist_ok=True)
     with open(os.path.join("chiprun_out", "chip_smoke.json"), "w") as f:
         json.dump(report, f, indent=1)
-    print(json.dumps(report), flush=True)
+    say("report", json.dumps(report))
+    # the result: these keys and no other — whoever runs the script
+    # parses this line alone; everything more is in the report above
+    print(json.dumps({"ok": True, "device": device}), flush=True)
 
 
 if __name__ == "__main__":

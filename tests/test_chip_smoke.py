@@ -47,6 +47,32 @@ def test_device_phase_reports_what_jax_reports():
         chip_smoke.phase_device(require="tpu")
 
 
+def test_main_ends_with_the_result_line(monkeypatch, tmp_path, capsys):
+    """The last line of stdout is exactly {"ok", "device": {"platform",
+    "kind", "count"}} — whoever runs the script parses that line and
+    accepts no other key.  The report (phases, cache, "claim": null) is
+    the line before it and the file.  Phases are stubbed: this rehearses
+    main()'s own output, not the work."""
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    monkeypatch.setattr(chip_smoke, "phase_device", lambda: dict(device))
+    for name in ("kernels", "train", "fit", "serve"):
+        monkeypatch.setattr(chip_smoke, f"phase_{name}", lambda: {"n": 1})
+    monkeypatch.setattr(chip_smoke, "phase_multichip",
+                        lambda: {"skipped": "1 device"})
+    monkeypatch.chdir(tmp_path)
+    assert not chip_smoke.main()
+    lines = capsys.readouterr().out.strip().split("\n")
+    assert json.loads(lines[-1]) == {"ok": True, "device": device}
+    assert lines[-2].startswith("[report] ")
+    report = json.loads(lines[-2][len("[report] "):])
+    assert report["ok"] is True and report["claim"] is None
+    assert list(report)[-1] == "claim"
+    assert set(report["phases"]) == {"kernels", "train", "fit", "serve",
+                                     "multichip"}
+    with open(tmp_path / "chiprun_out" / "chip_smoke.json") as f:
+        assert json.load(f) == report
+
+
 @pytest.mark.slow
 def test_kernels_phase_toy(force_pallas):
     out = chip_smoke.phase_kernels(
