@@ -109,8 +109,13 @@ def make_block_fn(cfg: GPTConfig, sp_axis: Optional[str] = None,
         from ..ops.pallas.flash_attention import flash_attention_qkv
         # x: (mb, T_local, D)
         B, T, D = x.shape
-        y = _layernorm(x, p["ln1_g"], p["ln1_b"])
-        qkv = jnp.einsum("btd,dse->btse", y, p["qkv_w"]) + p["qkv_b"]
+        with jax.named_scope("attn_qkv"):
+            y = _layernorm(x, p["ln1_g"], p["ln1_b"])
+            qkv = jnp.einsum("btd,dse->btse", y, p["qkv_w"]) + p["qkv_b"]
+        # The attention call and its checkpoint name stay OUTSIDE every
+        # scope: the TPU compiler names a Mosaic custom call after the
+        # last name-stack component in front of pallas_call, and the
+        # benchmark's roofline metrics find the kernels by those names.
         if sp_axis is not None:
             from ..distributed.fleet.meta_parallel.sequence_parallel \
                 import ring_attention
@@ -125,11 +130,13 @@ def make_block_fn(cfg: GPTConfig, sp_axis: Optional[str] = None,
                 qkv, h, causal=True, mesh=mesh,
                 batch_axes=("dp", "sharding"), head_axes=("mp",))
         ctx = checkpoint_name(ctx, "attn_ctx")
-        x = x + ctx @ p["out_w"] + p["out_b"]
-        y = _layernorm(x, p["ln2_g"], p["ln2_b"])
-        up = checkpoint_name(jax.nn.gelu(y @ p["up_w"] + p["up_b"]),
-                             "ffn_up")
-        x = x + up @ p["down_w"] + p["down_b"]
+        with jax.named_scope("attn_out"):
+            x = x + ctx @ p["out_w"] + p["out_b"]
+        with jax.named_scope("ffn"):
+            y = _layernorm(x, p["ln2_g"], p["ln2_b"])
+            up = checkpoint_name(jax.nn.gelu(y @ p["up_w"] + p["up_b"]),
+                                 "ffn_up")
+            x = x + up @ p["down_w"] + p["down_b"]
         return x
     return block_fn
 
@@ -157,6 +164,18 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
     offload_helper.py): ZeRO over the mesh's ``sharding`` axis — see
     fleet/meta_optimizers/zero.py.  The sharding axis co-shards the
     global batch (reference hybrid topology [dp, pp, sharding, mp]).
+
+    The step names its parts in the device trace with ``jax.named_scope``
+    (metadata only: no switch, no run-time cost): ``embed``, ``unstack``,
+    ``attn_qkv``, ``attn_out``, ``ffn``, ``final_ln``, ``loss_head``,
+    ``optimizer``.  An operation's ``op_name`` then reads ``jvp(ffn)``
+    forward, ``transpose(jvp(ffn))`` backward and
+    ``rematted_computation/ffn`` when recomputed.  The Pallas calls
+    (attention, the fused loss head) are siblings of these scopes, never
+    children: a scope around a ``pallas_call`` renames the Mosaic custom
+    call (tests/test_step_scopes.py guards the names).  The jitted
+    function is ``gpt_spmd_train_step``: the name is a symbol, so the
+    compile cache, whose key ignores scopes, tells it from any other step.
     """
     from ..distributed.fleet.meta_parallel.spmd_pipeline import (
         spmd_pipeline, spmd_pipeline_1f1b)
@@ -223,37 +242,42 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
         every weight grad a plain tensor (dblocks rebuilt by concat in
         the split transpose), dodging the bad emitter everywhere.
         """
-        if compute_dtype != jnp.float32:
-            params = jax.tree.map(
-                lambda a: a.astype(compute_dtype)
-                if a.dtype == jnp.float32 else a, params)
-        x = params["wte"][ids] + params["wpe"][:ids.shape[1]][None]
+        with jax.named_scope("embed"):
+            if compute_dtype != jnp.float32:
+                params = jax.tree.map(
+                    lambda a: a.astype(compute_dtype)
+                    if a.dtype == jnp.float32 else a, params)
+            x = params["wte"][ids] + params["wpe"][:ids.shape[1]][None]
         blocks = params["blocks"]
-        split = {k: jnp.split(v, L, axis=0) for k, v in blocks.items()}
+        with jax.named_scope("unstack"):
+            split = {k: jnp.split(v, L, axis=0) for k, v in blocks.items()}
         for i in range(L):
-            p_i = {k: jnp.squeeze(split[k][i], axis=0) for k in split}
-            # materialize the per-layer weight slices: left as bitcast
-            # views of the stacked (L, ...) arrays, XLA fuses the slice
-            # into the consuming convolution and picks a half-rate
-            # batch-in-sublanes emitter (profiled r5: the down-proj+LN
-            # fusion ran 3.43 ms vs 1.81 with materialized weights —
-            # the copies themselves are ~0.1 ms/layer)
-            p_i = lax.optimization_barrier(p_i)
+            with jax.named_scope("unstack"):
+                p_i = {k: jnp.squeeze(split[k][i], axis=0) for k in split}
+                # materialize the per-layer weight slices: left as bitcast
+                # views of the stacked (L, ...) arrays, XLA fuses the slice
+                # into the consuming convolution and picks a half-rate
+                # batch-in-sublanes emitter (profiled r5: the down-proj+LN
+                # fusion ran 3.43 ms vs 1.81 with materialized weights —
+                # the copies themselves are ~0.1 ms/layer)
+                p_i = lax.optimization_barrier(p_i)
             x = maybe_remat(block_fn)(p_i, x)
-        return _layernorm(x, params["ln_f_g"], params["ln_f_b"])
+        with jax.named_scope("final_ln"):
+            return _layernorm(x, params["ln_f_g"], params["ln_f_b"])
 
     def forward(params, ids):
         if not (use_pp or use_sp):
             x = trunk(params, ids)
             head_w = params["head_w"]
             return x @ head_w.astype(x.dtype)
-        if compute_dtype != jnp.float32:
-            # AMP O2: f32 master params, bf16 matmuls on the MXU
-            params = jax.tree.map(
-                lambda a: a.astype(compute_dtype)
-                if a.dtype == jnp.float32 else a, params)
         B, T = ids.shape
-        x = params["wte"][ids] + params["wpe"][:T][None]
+        with jax.named_scope("embed"):
+            if compute_dtype != jnp.float32:
+                # AMP O2: f32 master params, bf16 matmuls on the MXU
+                params = jax.tree.map(
+                    lambda a: a.astype(compute_dtype)
+                    if a.dtype == jnp.float32 else a, params)
+            x = params["wte"][ids] + params["wpe"][:T][None]
         if use_pp:
             # (M, mb, T, D): micro-batch dim unsharded, per-mb batch over
             # dp, sequence over sp (ring attention inside the blocks)
@@ -287,8 +311,10 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
                 seq_par, mesh=mesh, in_specs=(P(None), P(None, "sp")),
                 out_specs=P(None, "sp"), axis_names={"sp"},
                 check_vma=False)(params["blocks"], x)
-        x = _layernorm(x, params["ln_f_g"], params["ln_f_b"])
-        return x @ params["head_w"]
+        with jax.named_scope("final_ln"):
+            x = _layernorm(x, params["ln_f_g"], params["ln_f_b"])
+        with jax.named_scope("loss_head"):
+            return x @ params["head_w"]
 
     # The loss head is the single biggest HBM consumer at bench shapes:
     # full (B, T, V) bf16 logits are 4 GB (B=128 T=512 V=30k), and the
@@ -340,13 +366,14 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
             # pipelined/sequence-parallel paths keep the fused whole-
             # logits CE (head runs inside their shard_map schedules)
             logits = forward(params, ids)
-            m = jax.lax.stop_gradient(
-                jnp.max(logits, axis=-1, keepdims=True))
-            shifted = (logits - m).astype(jnp.float32)
-            lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
-            at_label = jnp.take_along_axis(shifted, labels[..., None],
-                                           axis=-1)[..., 0]
-            return jnp.mean(lse - at_label)
+            with jax.named_scope("loss_head"):
+                m = jax.lax.stop_gradient(
+                    jnp.max(logits, axis=-1, keepdims=True))
+                shifted = (logits - m).astype(jnp.float32)
+                lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
+                at_label = jnp.take_along_axis(shifted, labels[..., None],
+                                               axis=-1)[..., 0]
+                return jnp.mean(lse - at_label)
         x = trunk(params, ids)
         head_w = params["head_w"].astype(x.dtype)
         B, T, D = x.shape
@@ -361,10 +388,13 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
             # fused fwd 23.5 ms vs 28.5, and the saved-lse backward
             # skips the stat recompute).  One device only: the kernel
             # has no cross-shard lse combine for a vocab-sharded head.
+            # Like attention, outside every scope: its forward is a Mosaic
+            # call the benchmark finds by its compiler-made name.
             from ..ops.pallas.softmax_xent import softmax_xent_loss
             return softmax_xent_loss(x.reshape(B * T, D), head_w,
                                      labels.reshape(B * T), interpret)
-        return chunked_ce(x, head_w, labels)
+        with jax.named_scope("loss_head"):
+            return chunked_ce(x, head_w, labels)
 
     def adamw_update(params, grads, opt_state):
         step = opt_state["step"] + 1
@@ -391,7 +421,6 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
     def loss_and_grads_1f1b(params, ids, labels):
         """Fused loss+grad via the interleaved 1F1B pipeline (no outer
         jax.grad: the pipeline carries its own backward)."""
-        cp = _cast(params)
         B, T = ids.shape
         D = cfg.hidden_size
 
@@ -399,7 +428,9 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
             x = wte[ids] + wpe[:T][None]
             return x.reshape(M, B // M, T, D)
 
-        x, emb_vjp = jax.vjp(emb_fn, cp["wte"], cp["wpe"])
+        with jax.named_scope("embed"):
+            cp = _cast(params)
+            x, emb_vjp = jax.vjp(emb_fn, cp["wte"], cp["wpe"])
         x = lax.with_sharding_constraint(
             x, NamedSharding(mesh, P(None, batch_axes, sp_axis)))
         labels_m = labels.reshape(M, B // M, T)
@@ -410,17 +441,20 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
         def run(bp, xi, lab, hp):
             def last_fn(out_mb, lab_mb):
                 def head_loss(h, o):
-                    z = _layernorm(o, h["g"], h["b"])
-                    logits = (z @ h["w"]).astype(jnp.float32)
-                    logp = jax.nn.log_softmax(logits, axis=-1)
-                    # one-hot contraction, not take_along_axis: a gather
-                    # on mp-sharded logits inside the manual-pp region
-                    # trips XLA's SPMD partitioner (CHECK failure in
-                    # PartitionGather); the contraction partitions clean
-                    onehot = jax.nn.one_hot(lab_mb, logits.shape[-1],
-                                            dtype=logp.dtype)
-                    nll = -jnp.sum(logp * onehot, axis=-1)
-                    return jnp.sum(nll) * inv_tokens
+                    with jax.named_scope("final_ln"):
+                        z = _layernorm(o, h["g"], h["b"])
+                    with jax.named_scope("loss_head"):
+                        logits = (z @ h["w"]).astype(jnp.float32)
+                        logp = jax.nn.log_softmax(logits, axis=-1)
+                        # one-hot contraction, not take_along_axis: a
+                        # gather on mp-sharded logits inside the manual-pp
+                        # region trips XLA's SPMD partitioner (CHECK
+                        # failure in PartitionGather); the contraction
+                        # partitions clean
+                        onehot = jax.nn.one_hot(lab_mb, logits.shape[-1],
+                                                dtype=logp.dtype)
+                        nll = -jnp.sum(logp * onehot, axis=-1)
+                        return jnp.sum(nll) * inv_tokens
                 loss, (dh, dout) = jax.value_and_grad(
                     head_loss, argnums=(0, 1))(hp, out_mb)
                 return loss, dout, dh
@@ -443,7 +477,8 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
             out_specs=(P(), P("pp"), x_spec, P()),
             axis_names={"pp"} | ({"sp"} if use_sp else set()),
             check_vma=False)(cp["blocks"], x, labels_m, head)
-        dwte, dwpe = emb_vjp(dx)
+        with jax.named_scope("embed"):
+            dwte, dwpe = emb_vjp(dx)
         grads = {"wte": dwte, "wpe": dwpe, "blocks": dblocks,
                  "ln_f_g": dhead["g"], "ln_f_b": dhead["b"],
                  "head_w": dhead["w"]}
@@ -465,7 +500,7 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
         shardings, state_shardings = base_shardings, base_shardings
         grad_shardings, state_dev = None, None
 
-    def step(params, opt_state, ids, labels):
+    def gpt_spmd_train_step(params, opt_state, ids, labels):
         if use_pp and schedule_mode == "1F1B":
             loss, grads = loss_and_grads_1f1b(params, ids, labels)
         else:
@@ -480,7 +515,8 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
             mv = jax.device_put({"m": opt_state["m"], "v": opt_state["v"]},
                                 {"m": state_dev, "v": state_dev})
             opt_state = {**opt_state, **mv}
-        params, opt_state = adamw_update(params, grads, opt_state)
+        with jax.named_scope("optimizer"):
+            params, opt_state = adamw_update(params, grads, opt_state)
         if use_zero and sharding_stage < 3:
             params = jax.tree.map(lax.with_sharding_constraint, params,
                                   shardings)
@@ -507,4 +543,4 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
     # offload: opt_state lives in pinned host memory — XLA cannot alias
     # host-memory inputs onto device-memory outputs, so skip its donation
     donate = (0,) if offload else (0, 1)
-    return jax.jit(step, donate_argnums=donate), init_fn
+    return jax.jit(gpt_spmd_train_step, donate_argnums=donate), init_fn

@@ -1,0 +1,237 @@
+"""The names the GPT train step carries into the device trace.
+
+``build_spmd_train_step`` marks its parts with ``jax.named_scope``
+(embed, unstack, attn_qkv, attn_out, ffn, final_ln, loss_head,
+optimizer); the benchmark's ``scope_time`` metrics read device time by
+those names and by the phase the path shows (``jvp(`` forward,
+``transpose(`` backward, ``rematted_computation`` recompute).  Two things
+are guarded here, at no chip time:
+
+- on the CPU, that every scope reaches the compiled program in every
+  phase, at each remat policy and on the pp / sp paths;
+- for a described v5e, that no scope (and no ``name=``) renamed a Mosaic
+  call: the TPU compiler names a ``tpu_custom_call`` after the last
+  name-stack component in front of ``pallas_call``, and the benchmark's
+  roofline metrics find the kernels by exactly those names
+  (``benchmark/layer_metrics/*_roofline.json``).  A kernel call that
+  slips inside a scope would fail a ``--trace 1`` run on the chip.
+"""
+import json
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from paddle_tpu.distributed.topology import build_mesh
+from paddle_tpu.models import GPTConfig
+from paddle_tpu.models.gpt_spmd import (build_spmd_train_step,
+                                        gpt_param_shardings,
+                                        init_gpt_params)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TINY = GPTConfig(vocab_size=128, hidden_size=32, num_layers=2, num_heads=2,
+                 max_seq_len=16, ffn_mult=2)
+BLOCK = ("attn_qkv", "attn_out", "ffn")
+DIFFERENTIATED = ("embed", "unstack", *BLOCK, "final_ln", "loss_head")
+POLICIES = ("none", "ctx", "ctx_ffn", "dots", "full")
+
+
+def _lower(cfg, mesh, batch, compute_dtype=jnp.bfloat16, **kw):
+    """The step lowered from shapes alone, so that the same helper serves
+    the CPU mesh and a described TPU."""
+    step, _ = build_spmd_train_step(cfg, mesh, compute_dtype=compute_dtype,
+                                    **kw)
+    shapes = jax.eval_shape(
+        lambda: init_gpt_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree.map(
+        lambda s, ns: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=ns),
+        shapes, gpt_param_shardings(mesh, cfg))
+    opt = {"m": params, "v": params,
+           "step": jax.ShapeDtypeStruct(
+               (), jnp.int32, sharding=NamedSharding(mesh, P()))}
+    baxes = tuple(a for a in ("dp", "sharding")
+                  if mesh.shape.get(a, 1) > 1)
+    ids = jax.ShapeDtypeStruct(
+        (batch, cfg.max_seq_len), jnp.int32,
+        sharding=NamedSharding(mesh, P(baxes or None)))
+    return step.lower(params, opt, ids, ids)
+
+
+def _op_names(hlo_text):
+    return set(re.findall(r'op_name="([^"]*)"', hlo_text))
+
+
+def _under(name, scopes):
+    """The scope among ``scopes`` that the op_name path sits under."""
+    m = re.search(r"[/(](%s)[/)]" % "|".join(scopes), name)
+    return m and m.group(1)
+
+
+_compiled = {}
+
+
+def _tiny_step_text(policy):
+    if policy not in _compiled:
+        _compiled[policy] = _lower(
+            TINY, build_mesh({"dp": 1}), 4,
+            remat_policy=policy).compile().as_text()
+    return _compiled[policy]
+
+
+def test_the_step_has_a_name_of_its_own():
+    """The compile cache's key ignores scopes (they are locations, and
+    the key strips debug info) but hashes symbols: the jitted function's
+    name is what tells this step from any other one called ``step``."""
+    assert "HloModule jit_gpt_spmd_train_step" in _tiny_step_text("ctx")
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_every_scope_forward_and_backward(policy):
+    names = _op_names(_tiny_step_text(policy))
+    for scope in DIFFERENTIATED:
+        assert any(f"/jvp({scope})/" in n for n in names), scope
+        assert any("transpose(" in n and _under(n, [scope])
+                   for n in names), scope
+    # the optimizer is not differentiated: its scope stands bare
+    assert any("/optimizer/" in n and "transpose(" not in n for n in names)
+
+
+@pytest.mark.parametrize("policy", [p for p in POLICIES if p != "none"])
+def test_block_scopes_under_recompute(policy):
+    names = _op_names(_tiny_step_text(policy))
+    remat = {_under(n, BLOCK) for n in names if "rematted_computation" in n}
+    # what is saved is not recomputed: ctx_ffn keeps the FFN's up
+    # projection, dots every matmul, but LayerNorm 1 is always redone
+    assert "attn_qkv" in remat
+    if policy in ("ctx", "full"):
+        assert remat >= set(BLOCK)
+
+
+def test_no_block_recompute_without_remat():
+    # (the chunked loss head checkpoints its rows at every policy)
+    assert not any("rematted_computation" in n and _under(n, BLOCK)
+                   for n in _op_names(_tiny_step_text("none")))
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_block_matmuls_sit_under_a_block_scope(policy):
+    """Every dot_general but attention's own (XLA math on the CPU, under
+    no scope because the kernel call is a sibling of the scopes) and the
+    loss head's sits under attn_qkv, attn_out or ffn."""
+    dots = [n for n in _op_names(_tiny_step_text(policy))
+            if n.endswith("dot_general")]
+    attention = [n for n in dots if "bqd,bkd->bqk" in n or "bqk,bkd->bqd" in n]
+    assert attention and not any(
+        _under(n, DIFFERENTIATED + ("optimizer",)) for n in attention)
+    block = [n for n in dots
+             if n not in attention and not _under(n, ["loss_head"])]
+    assert len(block) >= 4
+    assert all(_under(n, BLOCK) for n in block), \
+        [n for n in block if not _under(n, BLOCK)]
+
+
+@pytest.mark.parametrize("dims,kw", [
+    ({"dp": 2, "pp": 2, "mp": 2}, dict(num_microbatches=2)),
+    ({"dp": 2, "pp": 2, "mp": 2}, dict(num_microbatches=2,
+                                       schedule_mode="1F1B")),
+    ({"dp": 2, "sp": 2}, {}),
+], ids=["pp-F-then-B", "pp-1F1B", "sp"])
+def test_mesh_paths_carry_the_scopes(dims, kw):
+    cfg = GPTConfig(vocab_size=128, hidden_size=32, num_layers=4,
+                    num_heads=2, max_seq_len=16, ffn_mult=2)
+    # float32: XLA's CPU compiler aborts on these meshes in bfloat16
+    names = _op_names(_lower(cfg, build_mesh(dims), 8, jnp.float32,
+                             **kw).compile().as_text())
+    for scope in ("embed", *BLOCK, "final_ln", "loss_head", "optimizer"):
+        assert any(_under(n, [scope]) for n in names), scope
+
+
+# ---------------------------------------------------------------------------
+# for a described v5e: the Mosaic calls keep the names the benchmark finds
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — no libtpu / no such topology
+        pytest.skip(f"no device-less TPU topology here: {e!r}")
+    return list(topo.devices)
+
+
+@pytest.fixture(scope="module")
+def real_width_step_hlo(v5e):
+    """Two layers of the benchmark's cell (gpt2-medium widths, B=32,
+    T=1024, V=50257, ``ctx`` remat) compiled for one v5e chip, Mosaic
+    steered on here and not through an option of the program."""
+    from paddle_tpu.ops import pallas
+    mp = pytest.MonkeyPatch()
+    for mod in (pallas,
+                sys.modules["paddle_tpu.ops.pallas.flash_attention"]):
+        mp.setattr(mod, "on_tpu", lambda: True)
+    try:
+        cfg = GPTConfig(vocab_size=50257, hidden_size=1024, num_layers=2,
+                        num_heads=16, max_seq_len=1024, ffn_mult=4)
+        mesh = Mesh(np.asarray(v5e[:1]), ("dp",))
+        return cfg, _lower(cfg, mesh, 32,
+                           remat_policy="ctx").compile().as_text()
+    finally:
+        mp.undo()
+
+
+def _patterns(metric):
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           metric + ".json")) as f:
+        return [re.compile(p) for p in json.load(f)["patterns"]]
+
+
+def _instructions(hlo_text):
+    """Instruction lines as the device trace names its events: the
+    instruction's text from its ``%name`` on."""
+    return [re.sub(r"^\s*(ROOT )?", "", line)
+            for line in hlo_text.splitlines() if " = " in line]
+
+
+def _mosaic_calls(hlo_text):
+    return [i for i in _instructions(hlo_text)
+            if 'custom_call_target="tpu_custom_call"' in i]
+
+
+def test_every_mosaic_call_is_one_the_benchmark_finds(real_width_step_hlo):
+    cfg, hlo = real_width_step_hlo
+    mosaic = _mosaic_calls(hlo)
+    assert len(mosaic) == 2 * cfg.num_layers + 1, \
+        [m.split(" = ")[0] for m in mosaic]
+    known = _patterns("attn_roofline") + _patterns("xent_head_roofline")
+    strangers = [m[:120] for m in mosaic
+                 if not any(r.search(m) for r in known)]
+    assert not strangers, (
+        "Mosaic calls no roofline pattern finds — did a scope or a "
+        f"name= get around a pallas_call? {strangers}")
+
+
+@pytest.mark.parametrize("metric,pattern,per_layer", [
+    ("attn_roofline", 0, True), ("attn_roofline", 1, True),
+    ("xent_head_roofline", 0, False)],
+    ids=["flash-forward", "flash-backward", "fused-head-forward"])
+def test_each_kernel_pattern_finds_its_calls(
+        real_width_step_hlo, metric, pattern, per_layer):
+    cfg, hlo = real_width_step_hlo
+    rx = _patterns(metric)[pattern]
+    hit = [m for m in _mosaic_calls(hlo) if rx.search(m)]
+    assert len(hit) == (cfg.num_layers if per_layer else 1)
+
+
+def test_loss_head_backward_loop_keeps_its_name(real_width_step_hlo):
+    """``xent_head_roofline`` finds the head's backward as XLA's while
+    loop over row chunks whose carry holds the f32 (D, V) gradient."""
+    _cfg, hlo = real_width_step_hlo
+    loop = _patterns("xent_head_roofline")[1]
+    assert any(loop.search(i) for i in _instructions(hlo))
