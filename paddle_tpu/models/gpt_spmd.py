@@ -106,12 +106,19 @@ def make_block_fn(cfg: GPTConfig, sp_axis: Optional[str] = None,
     h, hd = cfg.num_heads, cfg.hidden_size // cfg.num_heads
 
     def block_fn(p, x):
-        from ..ops.pallas.flash_attention import flash_attention_qkv
+        from ..ops.pallas.flash_attention import flash_attention_stacked
         # x: (mb, T_local, D)
         B, T, D = x.shape
         with jax.named_scope("attn_qkv"):
             y = _layernorm(x, p["ln1_g"], p["ln1_b"])
             qkv = jnp.einsum("btd,dse->btse", y, p["qkv_w"]) + p["qkv_b"]
+            # q/k/v axis to the front: the matmul then writes q, k and v
+            # each as a row-major (B, T, D) section, the form the kernels
+            # read and write — with the axis third, XLA lays the result
+            # out T-minor and pays relayout copies and packing updates on
+            # both sides of every kernel (57 of 779 ms a step, PERF.md
+            # PR 29).  A transpose in name only: it sets the layout.
+            qkv = jnp.moveaxis(qkv, 2, 0)
         # The attention call and its checkpoint name stay OUTSIDE every
         # scope: the TPU compiler names a Mosaic custom call after the
         # last name-stack component in front of pallas_call, and the
@@ -119,14 +126,13 @@ def make_block_fn(cfg: GPTConfig, sp_axis: Optional[str] = None,
         if sp_axis is not None:
             from ..distributed.fleet.meta_parallel.sequence_parallel \
                 import ring_attention
-            q, k, v = (qkv[:, :, i].reshape(B, T, h, hd)
-                       for i in range(3))
+            q, k, v = (qkv[i].reshape(B, T, h, hd) for i in range(3))
             ctx = ring_attention(q, k, v, sp_axis, causal=True)
             ctx = ctx.reshape(B, T, D)
         else:
-            # packed path: attention straight off the projection output,
-            # no head-split / transpose copies in HBM
-            ctx = flash_attention_qkv(
+            # attention straight off the projection output: no
+            # head-split, transpose or relayout in HBM
+            ctx = flash_attention_stacked(
                 qkv, h, causal=True, mesh=mesh,
                 batch_axes=("dp", "sharding"), head_axes=("mp",))
         ctx = checkpoint_name(ctx, "attn_ctx")

@@ -36,7 +36,8 @@ from jax.sharding import PartitionSpec
 
 from . import enabled, note, on_tpu, shard_kernel
 
-__all__ = ["flash_attention", "flash_attention_qkv"]
+__all__ = ["flash_attention", "flash_attention_stacked",
+           "flash_attention_qkv"]
 
 NEG_INF = -1e30
 
@@ -260,13 +261,15 @@ def _small_flash_fwd(q, k, v, scale: float, causal: bool,
 
 
 # ---------------------------------------------------------------------------
-# packed-QKV small-T kernels: consume the raw (B, T, 3*H*d) projection
-# output directly.  Each grid step takes one 128-lane column block
-# (= 128//d heads, e.g. a head pair at d=64) of q, k and v, slicing the
-# per-head (rows, d) operands in VMEM.  Zero transposes or head-split
-# copies materialise in HBM (profiled r4: those cost ~14% of the train
-# step), and the backward writes the d(qkv) cotangent blocks the
-# projection matmul's vjp consumes.
+# stacked-QKV kernels: q, k and v arrive as ONE (3, B, T, H*d) array —
+# three row-major (B, T, H*d) sections with the heads side by side on the
+# last axis, which is what one projection matmul writes when its output
+# puts the q/k/v axis first, and what its backward matmuls read.  Each
+# grid step takes one 128-lane column block (= 128//d heads, e.g. a head
+# pair at d=64) of each section and slices the per-head (rows, d)
+# operands in VMEM, so no head-split, transpose or relayout lands in HBM,
+# and the backward writes dq, dk, dv into the sections of one array of
+# the same form: nothing is packed between a kernel and a matmul.
 # ---------------------------------------------------------------------------
 def _qkv_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float,
                     causal: bool, block_q: int, seq_q: int, seq_k: int,
@@ -298,14 +301,12 @@ def _qkv_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float,
 def _qkv_bwd_kernel(q_ref, k_ref, v_ref, do_ref, dqkv_ref,
                     *, scale: float, causal: bool, seq_q: int, seq_k: int,
                     G: int, P: int, d: int):
-    """Writes dq/dk/dv straight into their column blocks of ONE
-    (G, T, 3F)-shaped output ref — the exact cotangent layout of the
-    packed projection, so no (B, T, F)x3 -> (B, T, 3F) concatenate pass
-    ever lands in HBM (profiled r5: that concat alone was ~9 ms/step on
-    the flagship)."""
+    """Small-T backward: whole rows of one 128-lane column block per
+    (b, hp) grid cell, G batch rows a step, dq/dk/dv into the three
+    sections of the (3, G, T, 128) output block.  Per-head results
+    concatenate into single full-lane-block stores (Mosaic requires
+    provably 128-aligned stores)."""
     offset = seq_k - seq_q
-    F = dqkv_ref.shape[-1] // 3
-    hp = pl.program_id(1)              # which 128-lane head-pair block
     for g in range(G):
         dq_parts, dk_parts, dv_parts = [], [], []
         for h in range(P):
@@ -342,108 +343,24 @@ def _qkv_bwd_kernel(q_ref, k_ref, v_ref, do_ref, dqkv_ref,
                 ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             ).astype(dqkv_ref.dtype))
-        # one 128-lane tile per tensor, stored at block-aligned lane
-        # offsets (Mosaic rejects dynamic stores not provably 128-
-        # aligned; hp*128 + const*F qualifies, hp*128 + h*d does not)
-        dqkv_ref[g, :, pl.ds(hp * 128, 128)] = \
-            jnp.concatenate(dq_parts, axis=-1)
-        dqkv_ref[g, :, pl.ds(F + hp * 128, 128)] = \
-            jnp.concatenate(dk_parts, axis=-1)
-        dqkv_ref[g, :, pl.ds(2 * F + hp * 128, 128)] = \
-            jnp.concatenate(dv_parts, axis=-1)
+        dqkv_ref[0, g] = jnp.concatenate(dq_parts, axis=-1)
+        dqkv_ref[1, g] = jnp.concatenate(dk_parts, axis=-1)
+        dqkv_ref[2, g] = jnp.concatenate(dv_parts, axis=-1)
 
 
-def _qkv_small_fwd(qkv, num_heads: int, scale: float, causal: bool,
-                   block_q: int = 512, G: int = None,
-                   interpret: bool = False):
-    """qkv: (B, T, 3*H*d) head-major packed -> ctx (B, T, H*d)."""
-    if G is None:
-        G = int(os.environ.get("PADDLE_FLASH_G_FWD", "4"))
-    B, T, F3 = qkv.shape
-    F = F3 // 3
-    d = F // num_heads
-    P = 128 // d                       # heads per 128-lane column block
-    HP = num_heads // P                # column blocks per tensor
-    block_q, _ = _block_sizes(T, T, block_q, T)
-    G = max(1, min(G, (4 * 512 * 512) // (block_q * T)))
-    while B % G:
-        G //= 2
-    grid = (B // G, HP, T // block_q)
-    kernel = functools.partial(_qkv_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, seq_q=T, seq_k=T, G=G,
-                               P=P, d=d)
-
-    def col(base):
-        return lambda b, hp, i: (b, 0, base + hp)
-
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((G, block_q, 128),
-                         lambda b, hp, i: (b, i, hp)),
-            pl.BlockSpec((G, T, 128), col(HP)),
-            pl.BlockSpec((G, T, 128), col(2 * HP)),
-        ],
-        out_specs=pl.BlockSpec((G, block_q, 128),
-                               lambda b, hp, i: (b, i, hp)),
-        out_shape=jax.ShapeDtypeStruct((B, T, F), qkv.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qkv, qkv, qkv)
-
-
-def _qkv_small_bwd(qkv, do, num_heads: int, scale: float, causal: bool,
-                   G: int = None, interpret: bool = False):
-    """-> dqkv (B, T, 3*H*d), written column-block-wise by the kernel
-    (the (G, T, 3F) output block stays VMEM-resident across the
-    consecutive head-pair grid steps that each fill 3 of its 128-lane
-    column blocks, flushing once per batch group)."""
-    if G is None:
-        G = int(os.environ.get("PADDLE_FLASH_G_BWD", "2"))
-    B, T, F3 = qkv.shape
-    F = F3 // 3
-    d = F // num_heads
-    P = 128 // d
-    HP = num_heads // P
-    # the full-width (G, T, 3F) output block is VMEM-resident alongside
-    # ~4 f32 (T, T) intermediates: G=2 at T=512 busts the 16M scoped
-    # limit (measured 16.92M), G=1 fits
-    G = max(1, min(G, (512 * 512) // (T * T)))
-    while B % G:
-        G //= 2
-    kernel = functools.partial(_qkv_bwd_kernel, scale=scale, causal=causal,
-                               seq_q=T, seq_k=T, G=G, P=P, d=d)
-
-    def col(base):
-        return lambda b, hp: (b, 0, base + hp)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(B // G, HP),
-        in_specs=[pl.BlockSpec((G, T, 128), col(0)),
-                  pl.BlockSpec((G, T, 128), col(HP)),
-                  pl.BlockSpec((G, T, 128), col(2 * HP)),
-                  pl.BlockSpec((G, T, 128), lambda b, hp: (b, 0, hp))],
-        out_specs=pl.BlockSpec((G, T, F3), lambda b, hp: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, T, F3), qkv.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(qkv, qkv, qkv, do)
-
-
-def _qkv_mid_bwd_kernel(q_ref, k_ref, v_ref, do_ref, dq_ref, dk_ref,
-                        dv_ref, dk_scr, dv_scr, *, scale: float,
-                        causal: bool, block_q: int, nq: int, seq_q: int,
-                        seq_k: int, P: int, d: int):
-    """Packed mid-regime backward: one 128-lane column block (= P heads)
-    of q/k/v per (b, hp) grid cell, q blocks riding the inner
-    'arbitrary' dim with dK/dV accumulated in f32 scratch across them
-    (the _tiled_bwd_kernel design applied to the packed layout).  Per-
-    head results concatenate into single full-lane-block stores (Mosaic
-    requires provably 128-aligned stores)."""
+def _qkv_mid_bwd_kernel(q_ref, k_ref, v_ref, do_ref, dqkv_ref, dk_scr,
+                        dv_scr, *, scale: float, causal: bool,
+                        block_q: int, nq: int, seq_q: int, seq_k: int,
+                        P: int, d: int):
+    """Mid-regime backward: one 128-lane column block (= P heads) of
+    q/k/v per (b, hp) grid cell, q blocks riding the inner 'arbitrary'
+    dim with dK/dV accumulated in f32 scratch across them (the
+    _tiled_bwd_kernel design applied to the stacked layout).  The
+    (3, 1, T, 128) output block stays in VMEM across the q blocks: each
+    writes its rows of the dq section, the last one the dk and dv
+    sections, and the block goes to HBM once.  Per-head results
+    concatenate into single full-lane-block stores (Mosaic requires
+    provably 128-aligned stores)."""
     qi = pl.program_id(2)
     offset = seq_k - seq_q
 
@@ -481,114 +398,142 @@ def _qkv_mid_bwd_kernel(q_ref, k_ref, v_ref, do_ref, dq_ref, dk_ref,
         ds = (p * (dp - delta)).astype(q.dtype)
         dq_parts.append((scale * jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)).astype(dq_ref.dtype))
+            preferred_element_type=jnp.float32)).astype(dqkv_ref.dtype))
         dk_parts.append(scale * jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32))         # (Tk, d)
-    dq_ref[0] = jnp.concatenate(dq_parts, axis=-1)
+    dqkv_ref[0, 0, pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)] \
+        = jnp.concatenate(dq_parts, axis=-1)
     dk_scr[...] += jnp.concatenate(dk_parts, axis=-1)
     dv_scr[...] += jnp.concatenate(dv_parts, axis=-1)
 
     @pl.when(qi == nq - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        dqkv_ref[1, 0] = dk_scr[...].astype(dqkv_ref.dtype)
+        dqkv_ref[2, 0] = dv_scr[...].astype(dqkv_ref.dtype)
 
 
 def _qkv_mid_block_q(T: int, Tk: int, itemsize: int) -> int:
     # ~4 live f32 (block_q, Tk) intermediates + 2 f32 (Tk, 128) scratch
-    # accumulators + 2 resident (Tk, 128) K/V column blocks: bf16
-    # blocks at block_q=256/Tk=2048 total ~13 MB of the 16 MB scoped
-    # VMEM; f32 K/V doubles the resident blocks and measured 16.84 MB
-    # (860K over) at the same shape, so f32 halves block_q
+    # accumulators + 2 resident (Tk, 128) K/V column blocks + the
+    # backward's resident (3, Tk, 128) output block, the blocks double-
+    # buffered: bf16 at block_q=256/Tk=2048 totals ~14 MB of the 16 MB
+    # scoped VMEM; f32 doubles every block and measured 17.30 MB at
+    # block_q=128/Tk=2048 and 16.14 MB at 64 (the resident blocks alone
+    # are 12 MB), so f32 halves block_q, and past 1024 takes an eighth
     block_q = 256 if Tk <= 2048 else 128
     if itemsize >= 4:
-        block_q //= 2
+        block_q //= 2 if Tk <= 1024 else 8
     block_q, _ = _block_sizes(T, Tk, block_q, Tk)
     return block_q
 
 
-def _qkv_mid_bwd(qkv, do, num_heads: int, scale: float, causal: bool,
-                 interpret: bool = False):
-    """-> dqkv (B, T, 3F) for the packed mid regime: three column-
-    blocked outputs + one concatenate (the (1, T, 3F) single-output
-    block of the small-T design is ~28 MB at T=2048 — VMEM-infeasible —
-    so dq/dk/dv emit separately; the concat is one bandwidth-bound pass,
-    ~6x smaller than the split+fold transposes it replaces)."""
-    B, T, F3 = qkv.shape
-    F = F3 // 3
+def _batch_rows(B: int, env: str, default: int, cap: int) -> int:
+    """Batch rows a small-regime grid step takes: the tuning variable's
+    value (else ``default``), at most ``cap`` (what VMEM holds at this
+    T), halved until it divides B."""
+    G = max(1, min(int(os.environ.get(env, default)), cap))
+    while B % G:
+        G //= 2
+    return G
+
+
+def _section(s: int, G: int, rows: int, whole: bool = False):
+    """BlockSpec over a (b, hp, i) grid for section ``s`` (0 q, 1 k, 2 v)
+    of a stacked (3, B, T, H*d) operand: G batch rows of 128-lane column
+    block hp, q block i of the rows or (``whole``) all of them."""
+    return pl.BlockSpec(
+        (None, G, rows, 128),
+        (lambda b, hp, i: (s, b, 0, hp)) if whole
+        else (lambda b, hp, i: (s, b, i, hp)))
+
+
+def _qkv_fwd(qkv, num_heads: int, scale: float, causal: bool,
+             interpret: bool = False):
+    """qkv: (3, B, T, H*d) -> ctx (B, T, H*d).  T <= 512: the small
+    regime, whole rows and G batch rows a step; beyond, the mid regime's
+    q blocks with K/V rows resident."""
+    _, B, T, F = qkv.shape
+    d = F // num_heads
+    P = 128 // d                       # heads per 128-lane column block
+    if T <= 512:
+        block_q, _ = _block_sizes(T, T, 512, T)
+        G = _batch_rows(B, "PADDLE_FLASH_G_FWD", 4,
+                        (4 * 512 * 512) // (block_q * T))
+    else:
+        block_q, G = _qkv_mid_block_q(T, T, qkv.dtype.itemsize), 1
+    kernel = functools.partial(_qkv_fwd_kernel, scale=scale, causal=causal,
+                               block_q=block_q, seq_q=T, seq_k=T, G=G,
+                               P=P, d=d)
+    return pl.pallas_call(
+        kernel,
+        grid=(B // G, num_heads // P, T // block_q),
+        in_specs=[_section(0, G, block_q), _section(1, G, T, whole=True),
+                  _section(2, G, T, whole=True)],
+        out_specs=pl.BlockSpec((G, block_q, 128),
+                               lambda b, hp, i: (b, i, hp)),
+        out_shape=jax.ShapeDtypeStruct((B, T, F), qkv.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(qkv, qkv, qkv)
+
+
+def _qkv_bwd(qkv, do, num_heads: int, scale: float, causal: bool,
+             interpret: bool = False):
+    """-> dqkv (3, B, T, H*d) for qkv as in :func:`_qkv_fwd`.  T <= 512:
+    one fused step per (batch group, column block); beyond, q blocks
+    with dK/dV accumulated in scratch."""
+    _, B, T, F = qkv.shape
     d = F // num_heads
     P = 128 // d
-    HP = num_heads // P
-    block_q = _qkv_mid_block_q(T, T, qkv.dtype.itemsize)
-    nq = T // block_q
-    kernel = functools.partial(_qkv_mid_bwd_kernel, scale=scale,
-                               causal=causal, block_q=block_q, nq=nq,
-                               seq_q=T, seq_k=T, P=P, d=d)
-
-    def col(base):
-        return lambda b, hp, i: (b, 0, base + hp)
-
-    qs = pl.BlockSpec((1, block_q, 128), lambda b, hp, i: (b, i, hp))
-    ks = pl.BlockSpec((1, T, 128), col(HP))
-    vs = pl.BlockSpec((1, T, 128), col(2 * HP))
-    dq, dk, dv = pl.pallas_call(
+    if T <= 512:
+        # ~4 f32 (T, T) intermediates per unrolled batch row: one row a
+        # step at T=512, more as the row shortens
+        G = _batch_rows(B, "PADDLE_FLASH_G_BWD", 2, (512 * 512) // (T * T))
+        block_q, scratch = T, []
+        kernel = functools.partial(_qkv_bwd_kernel, scale=scale,
+                                   causal=causal, seq_q=T, seq_k=T, G=G,
+                                   P=P, d=d)
+    else:
+        block_q, G = _qkv_mid_block_q(T, T, qkv.dtype.itemsize), 1
+        scratch = [pltpu.VMEM((T, 128), jnp.float32)] * 2
+        kernel = functools.partial(_qkv_mid_bwd_kernel, scale=scale,
+                                   causal=causal, block_q=block_q,
+                                   nq=T // block_q, seq_q=T, seq_k=T,
+                                   P=P, d=d)
+    return pl.pallas_call(
         kernel,
-        grid=(B, HP, nq),
-        in_specs=[qs, ks, vs,
-                  pl.BlockSpec((1, block_q, 128),
+        grid=(B // G, num_heads // P, T // block_q),
+        in_specs=[_section(0, G, block_q), _section(1, G, T, whole=True),
+                  _section(2, G, T, whole=True),
+                  pl.BlockSpec((G, block_q, 128),
                                lambda b, hp, i: (b, i, hp))],
-        out_specs=[qs,
-                   pl.BlockSpec((1, T, 128), lambda b, hp, i: (b, 0, hp)),
-                   pl.BlockSpec((1, T, 128), lambda b, hp, i: (b, 0, hp))],
-        out_shape=[jax.ShapeDtypeStruct((B, T, F), qkv.dtype)] * 3,
-        scratch_shapes=[pltpu.VMEM((T, 128), jnp.float32),
-                        pltpu.VMEM((T, 128), jnp.float32)],
+        out_specs=pl.BlockSpec((3, G, T, 128),
+                               lambda b, hp, i: (0, b, 0, hp)),
+        out_shape=jax.ShapeDtypeStruct(qkv.shape, qkv.dtype),
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qkv, qkv, qkv, do)
-    return jnp.concatenate([dq, dk, dv], axis=-1)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _flash_qkv_mid(qkv, num_heads, scale, causal):
-    _, interpret = _pallas_mode(qkv.shape[1], qkv.shape[1], causal)
-    T = qkv.shape[1]
-    return _qkv_small_fwd(qkv, num_heads, scale, causal,
-                          block_q=_qkv_mid_block_q(
-                              T, T, qkv.dtype.itemsize),
-                          G=1, interpret=interpret)
-
-
-def _flash_qkv_mid_vjp_fwd(qkv, num_heads, scale, causal):
-    return _flash_qkv_mid(qkv, num_heads, scale, causal), qkv
-
-
-def _flash_qkv_mid_vjp_bwd(num_heads, scale, causal, qkv, g):
-    _, interpret = _pallas_mode(qkv.shape[1], qkv.shape[1], causal)
-    return (_qkv_mid_bwd(qkv, g, num_heads, scale, causal,
-                         interpret=interpret),)
-
-
-_flash_qkv_mid.defvjp(_flash_qkv_mid_vjp_fwd, _flash_qkv_mid_vjp_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
 def _flash_qkv(qkv, num_heads, scale, causal):
-    _, interpret = _pallas_mode(qkv.shape[1], qkv.shape[1], causal)
-    return _qkv_small_fwd(qkv, num_heads, scale, causal,
-                          interpret=interpret)
+    _, interpret = _pallas_mode(qkv.shape[2], qkv.shape[2], causal)
+    return _qkv_fwd(qkv, num_heads, scale, causal, interpret)
 
 
 def _flash_qkv_vjp_fwd(qkv, num_heads, scale, causal):
+    # the residual is the raw input: under remat it rebuilds from the
+    # (cheap) projection, never by re-running the kernel
     return _flash_qkv(qkv, num_heads, scale, causal), qkv
 
 
 def _flash_qkv_vjp_bwd(num_heads, scale, causal, qkv, g):
-    _, interpret = _pallas_mode(qkv.shape[1], qkv.shape[1], causal)
-    return (_qkv_small_bwd(qkv, g, num_heads, scale, causal,
-                           interpret=interpret),)
+    _, interpret = _pallas_mode(qkv.shape[2], qkv.shape[2], causal)
+    return (_qkv_bwd(qkv, g, num_heads, scale, causal, interpret),)
 
 
 _flash_qkv.defvjp(_flash_qkv_vjp_fwd, _flash_qkv_vjp_bwd)
@@ -605,63 +550,81 @@ def _axes_entry(mesh, axes, dim: int):
     return keep if len(keep) > 1 else keep[0]
 
 
-def flash_attention_qkv(qkv, num_heads: int, *, causal: bool = False,
-                        scale=None, mesh=None, batch_axes=(),
-                        head_axes=()):
-    """Attention straight from the fused projection output.
+def flash_attention_stacked(qkv, num_heads: int, *, causal: bool = False,
+                            scale=None, mesh=None, batch_axes=(),
+                            head_axes=()):
+    """Attention between a model's projection matmuls, in their layout.
 
-    qkv: (B, T, 3*H*d) laid out [q_h0 .. q_h{H-1} | k_h0 .. | v_h0 ..]
-    (the ``reshape(B, T, 3H, d)`` + ``split`` convention), or the same
-    bytes as (B, T, 3, H*d) -> ctx (B, T, H*d), ready for the output
-    projection.  Takes the split + generic path when the packed kernels
-    don't apply.
+    qkv: (3, B, T, H*d) — q, k and v stacked on the leading axis, each
+    section row-major with the heads side by side on its last axis
+    [h0 .. h{H-1}] -> ctx (B, T, H*d), ready for the output projection;
+    the cotangent comes back as one array of qkv's form.  A projection
+    that writes this form (``einsum("btd,dse->sbte")``, or the q/k/v
+    axis of a (B, T, 3, H*d) result moved to the front) hands it over,
+    and takes its gradient back, with no relayout copy and no packing
+    update in HBM.  Takes the split + generic path when the stacked
+    kernels don't apply.
 
     Under a ``mesh`` of more than one device the kernels run per shard:
     batch split over ``batch_axes``, heads over ``head_axes`` (each
-    where the mesh has the axis and it divides the dim).  A head-sharded
-    caller must pass the 4-D form with its last axis sharded: a
-    contiguous split of the packed 3*H*d axis is not head-aligned per
-    q/k/v section.
+    where the mesh has the axis and it divides the dim) — a contiguous
+    split of the last axis gives every shard whole heads of all three.
     """
-    if qkv.ndim == 3:
-        qkv = qkv.reshape(*qkv.shape[:2], 3, qkv.shape[2] // 3)
-    B, T, _, F = qkv.shape
+    _, B, T, F = qkv.shape
     d = F // num_heads
     s = float(scale) if scale is not None else float(1.0 / np.sqrt(d))
     mode, _ = _pallas_mode(T, T, causal)
-    b_ax = _axes_entry(mesh, batch_axes, B)
-    h_ax = _axes_entry(mesh, head_axes, num_heads)
 
-    def local(x):                      # one shard: (b, T, 3, h*d)
-        b, _, _, f = x.shape
+    def local(x):                      # one shard: (3, b, T, h*d)
+        _, b, _, f = x.shape
         h = f // d
-        packed_ok = d in (32, 64, 128) and h % max(1, 128 // d) == 0
-        # packed small kernels: T <= 512 — the single-output backward
-        # holds the (G, T, 3F) cotangent block plus f32 (T, T)
-        # intermediates in VMEM, which busts the 16M scoped limit at
-        # T=1024
-        if mode == "small" and T <= 512 and packed_ok:
-            note("flash_attention.packed_small", True)
-            return _flash_qkv(x.reshape(b, T, 3 * f), h, s, causal)
-        # packed mid kernels: 512 < T <= 2048 — q-block-tiled backward
-        # with dK/dV scratch accumulation per 128-lane column block
-        # keeps VMEM bounded, and the packed entry kills the split+fold
-        # head transposes that cost ~12% of a T=2048 train step
-        # (profiled r5; measured 1.23x/1.13x over split+generic at
-        # T=1024/2048 end-to-end).  T=4096 stays on the split+generic
-        # mid path.
-        if mode in ("small", "mid") and T <= 2048 and packed_ok:
-            note("flash_attention.packed_mid", True)
-            return _flash_qkv_mid(x.reshape(b, T, 3 * f), h, s, causal)
-        q, k, v = (x[:, :, i].reshape(b, T, h, d) for i in range(3))
+        if mode in ("small", "mid") and T <= 2048 \
+                and d in (32, 64, 128) and h % max(1, 128 // d) == 0:
+            # small: T <= 512, whole rows a step.  mid: 512 < T <= 2048
+            # — the q-block-tiled backward with dK/dV scratch
+            # accumulation per 128-lane column block keeps VMEM bounded
+            # (measured 1.23x/1.13x over split+generic at T=1024/2048
+            # end-to-end, profiled r5).  T=4096, odd head sizes and head
+            # counts that do not fill a column block stay on the split +
+            # generic path.
+            note("flash_attention.packed_small" if T <= 512
+                 else "flash_attention.packed_mid", True)
+            return _flash_qkv(x, h, s, causal)
+        q, k, v = (x[i].reshape(b, T, h, d) for i in range(3))
         return flash_attention(q, k, v, causal=causal, scale=s) \
             .reshape(b, T, f)
 
     if mode == "xla":
         return local(qkv)              # XLA math: GSPMD partitions it
+    b_ax = _axes_entry(mesh, batch_axes, B)
+    h_ax = _axes_entry(mesh, head_axes, num_heads)
     return shard_kernel(
-        local, mesh, PartitionSpec(b_ax, None, None, h_ax),
+        local, mesh, PartitionSpec(None, b_ax, None, h_ax),
         PartitionSpec(b_ax, None, h_ax))(qkv)
+
+
+def flash_attention_qkv(qkv, num_heads: int, *, causal: bool = False,
+                        scale=None, mesh=None, batch_axes=(),
+                        head_axes=()):
+    """Attention from one fused projection output, batch first.
+
+    qkv: (B, T, 3*H*d) laid out [q_h0 .. q_h{H-1} | k_h0 .. | v_h0 ..]
+    (the ``reshape(B, T, 3H, d)`` + ``split`` convention), or the same
+    bytes as (B, T, 3, H*d) -> ctx (B, T, H*d), ready for the output
+    projection.  :func:`flash_attention_stacked` behind one transpose of
+    the q/k/v axis to the front (and one of the cotangent back): a model
+    whose projection can write the stacked form should call that.
+
+    ``mesh`` / ``batch_axes`` / ``head_axes``: as for
+    :func:`flash_attention_stacked`.  A head-sharded caller must pass
+    the 4-D form with its last axis sharded: a contiguous split of the
+    packed 3*H*d axis is not head-aligned per q/k/v section.
+    """
+    if qkv.ndim == 3:
+        qkv = qkv.reshape(*qkv.shape[:2], 3, qkv.shape[2] // 3)
+    return flash_attention_stacked(
+        jnp.moveaxis(qkv, 2, 0), num_heads, causal=causal, scale=scale,
+        mesh=mesh, batch_axes=batch_axes, head_axes=head_axes)
 
 
 def _mid_flash_fwd(q, k, v, scale: float, causal: bool,
@@ -1069,8 +1032,8 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
     All modes go through the folded (B*H, T, d) layout — TPU tiling
     forbids blocking the head dim of (B, T, H, d) directly (the last
     two array dims must tile (8, 128)).  Models that want the
-    transpose-free hot path should call :func:`flash_attention_qkv`
-    on the fused projection output instead.
+    transpose-free hot path should call :func:`flash_attention_stacked`
+    on a projection output of that form instead.
 
     ``mesh`` / ``batch_axes`` / ``head_axes``: as for
     :func:`flash_attention_qkv` — under a mesh of more than one device

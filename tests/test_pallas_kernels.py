@@ -167,6 +167,55 @@ def test_flash_attention_qkv_packed(force_pallas, causal, H, D):
                                atol=5e-5)
 
 
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("H,D", [(4, 64), (2, 128)])
+@pytest.mark.parametrize("B,T,regime", [(2, 256, "packed_small"),
+                                        (1, 640, "packed_mid")])
+def test_flash_attention_stacked(force_pallas, B, T, regime, H, D, causal):
+    # the entry the GPT block calls: q, k, v as the sections of one
+    # (3, B, T, H*d) array in, the cotangent in the same form out —
+    # forward and vjp against XLA math in the small (whole rows) and mid
+    # (q blocks, the output block resident across them) regimes
+    from paddle_tpu.ops import pallas
+    rs = np.random.RandomState(17)
+    qkv = jnp.asarray(rs.rand(3, B, T, H * D), jnp.float32)
+    g = jnp.asarray(rs.rand(B, T, H * D), jnp.float32)
+
+    def ref_fn(x):
+        q, k, v = (x[i].reshape(B, T, H, D) for i in range(3))
+        return _ref_attention(q, k, v, causal).reshape(B, T, H * D)
+
+    before = pallas.selections().get(f"flash_attention.{regime}.interpret", 0)
+    out, vjp = jax.vjp(
+        lambda x: fa.flash_attention_stacked(x, H, causal=causal), qkv)
+    assert pallas.selections()[
+        f"flash_attention.{regime}.interpret"] == before + 1
+    ref, ref_vjp = jax.vjp(ref_fn, qkv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    (dqkv,), (ref_d,) = vjp(g), ref_vjp(g)
+    assert dqkv.shape == qkv.shape
+    np.testing.assert_allclose(np.asarray(dqkv), np.asarray(ref_d),
+                               atol=5e-5)
+
+
+def test_flash_attention_stacked_falls_back_to_split(force_pallas):
+    # a head size that fills no 128-lane column block takes the split +
+    # generic path
+    from paddle_tpu.ops import pallas
+    rs = np.random.RandomState(19)
+    B, T, H, D = 1, 128, 2, 16
+    qkv = jnp.asarray(rs.rand(3, B, T, H * D), jnp.float32)
+    before = pallas.selections().get("flash_attention.small.interpret", 0)
+    out = fa.flash_attention_stacked(qkv, H, causal=True)
+    assert pallas.selections()[
+        "flash_attention.small.interpret"] == before + 1
+    ref = _ref_attention(*(qkv[i].reshape(B, T, H, D) for i in range(3)),
+                         True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref.reshape(B, T, H * D)),
+                               atol=2e-5)
+
+
 @pytest.mark.slow
 def test_packed_mid_qkv_t1024_gradient(force_pallas):
     """Pins the packed mid-regime entry (512 < T <= 2048): attention

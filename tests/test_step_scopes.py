@@ -166,11 +166,10 @@ def v5e():
     return list(topo.devices)
 
 
-@pytest.fixture(scope="module")
-def real_width_step_hlo(v5e):
-    """Two layers of the benchmark's cell (gpt2-medium widths, B=32,
-    T=1024, V=50257, ``ctx`` remat) compiled for one v5e chip, Mosaic
-    steered on here and not through an option of the program."""
+def _real_width_step_hlo(v5e, batch, seq_len):
+    """Two layers at gpt2-medium's widths (V=50257, ``ctx`` remat)
+    compiled for one v5e chip, Mosaic steered on here and not through an
+    option of the program."""
     from paddle_tpu.ops import pallas
     mp = pytest.MonkeyPatch()
     for mod in (pallas,
@@ -178,12 +177,24 @@ def real_width_step_hlo(v5e):
         mp.setattr(mod, "on_tpu", lambda: True)
     try:
         cfg = GPTConfig(vocab_size=50257, hidden_size=1024, num_layers=2,
-                        num_heads=16, max_seq_len=1024, ffn_mult=4)
+                        num_heads=16, max_seq_len=seq_len, ffn_mult=4)
         mesh = Mesh(np.asarray(v5e[:1]), ("dp",))
-        return cfg, _lower(cfg, mesh, 32,
+        return cfg, _lower(cfg, mesh, batch,
                            remat_policy="ctx").compile().as_text()
     finally:
         mp.undo()
+
+
+@pytest.fixture(scope="module")
+def real_width_step_hlo(v5e):
+    """The benchmark's cell, B=32 x T=1024: the mid-regime kernels."""
+    return _real_width_step_hlo(v5e, 32, 1024)
+
+
+@pytest.fixture(scope="module")
+def small_t_step_hlo(v5e):
+    """B=128 x T=256: the small-regime kernels, which no cell runs."""
+    return _real_width_step_hlo(v5e, 128, 256)
 
 
 def _patterns(metric):
@@ -235,3 +246,40 @@ def test_loss_head_backward_loop_keeps_its_name(real_width_step_hlo):
     _cfg, hlo = real_width_step_hlo
     loop = _patterns("xent_head_roofline")[1]
     assert any(loop.search(i) for i in _instructions(hlo))
+
+
+# ---------------------------------------------------------------------------
+# for a described v5e: attention pays nothing for layout
+# ---------------------------------------------------------------------------
+def _qkv_sized_layout_work(cfg, hlo, batch):
+    """Instructions that move a q+k+v-sized array without computing
+    anything: a ``copy``, a ``dynamic-update-slice`` (alone or as a
+    fusion), a ``concatenate`` or a ``pad`` whose result holds the three
+    projections of a whole batch — packed 3-D or 4-D, or stacked — in
+    any layout."""
+    T, D = cfg.max_seq_len, cfg.hidden_size
+    sized = re.compile(
+        r"^(%%[\w.\-]*) = bf16\[(?:%d,%d,%d|%d,%d,3,%d|3,%d,%d,%d)\]\S* "
+        r"([\w\-]+)\(" % (batch, T, 3 * D, batch, T, D, batch, T, D))
+    moves = re.compile(r"copy|dynamic-update-slice|concatenate|pad")
+    found = []
+    for ins in _instructions(hlo):
+        m = sized.match(ins)
+        # a fusion is named after what it holds
+        if m and moves.search(m.group(1) + " " + m.group(2)):
+            found.append(ins[:100])
+    return found
+
+
+@pytest.mark.parametrize("fixture,batch", [
+    ("real_width_step_hlo", 32), ("small_t_step_hlo", 128)],
+    ids=["t1024-mid", "t256-small"])
+def test_no_qkv_sized_layout_work_around_attention(request, fixture, batch):
+    """q, k, v and their gradients cross the flash-attention kernels in
+    the layout the projection matmuls use: the program holds no relayout
+    copy and no packing update of q+k+v size (a packed 4-D projection
+    cost 3 copies + 3 updates a layer: 57 of 779 ms a step on the chip,
+    PERF.md PR 29), and still 2 Mosaic calls a layer + the loss head."""
+    cfg, hlo = request.getfixturevalue(fixture)
+    assert not _qkv_sized_layout_work(cfg, hlo, batch)
+    assert len(_mosaic_calls(hlo)) == 2 * cfg.num_layers + 1
