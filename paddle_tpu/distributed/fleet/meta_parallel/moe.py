@@ -33,7 +33,8 @@ from ....nn import initializer as I
 from .... import nn
 
 __all__ = ["top1_gating", "moe_dispatch", "moe_combine", "moe_alltoall",
-           "moe_alltoall_inverse", "MoELayer"]
+           "moe_alltoall_inverse", "MoELayer", "sigmoid_topk_routing",
+           "routed_experts"]
 
 
 def top1_gating(logits, capacity: int):
@@ -151,3 +152,213 @@ class MoELayer(Layer):
         self.aux_loss = dispatch("moe_aux", _moe_aux,
                                  [tokens, self.gate.weight], {})
         return out.reshape([B, T, D])
+
+
+# ---------------------------------------------------------------------------
+# Routed experts with no dropped assignment: sigmoid top-k routing over the
+# router's whole width, dispatch by sort and segment offsets, grouped
+# matmuls (``lax.ragged_dot``) over the experts held here.  Functional:
+# the SPMD model blocks call it (models/lfm2_moe.py); ``MoELayer`` above
+# stays the Switch top-1 capacity layer of the Layer API.
+# ---------------------------------------------------------------------------
+def sigmoid_topk_routing(z, router_w, bias, top_k: int,
+                         scaling: float = 1.0):
+    """Scores ``s = sigmoid(z W_g)`` in float32; the ``top_k`` experts of
+    ``s + bias`` are chosen (the bias only selects: it takes no gradient
+    and is not in the weights), weighted ``s_e / (sum of the chosen s +
+    1e-6) * scaling``.  -> (idx (N, k) int32, w (N, k) float32)."""
+    s = jax.nn.sigmoid(jnp.dot(
+        z.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)),
+                       top_k)
+    # the chosen scores by a one-hot product, not a gather: its transpose
+    # is elementwise too (a gather's is a scatter-add)
+    onehot = jax.nn.one_hot(idx, s.shape[-1], dtype=s.dtype)
+    chosen = jnp.sum(s[:, None, :] * onehot, axis=-1)
+    w = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-6)
+    return idx.astype(jnp.int32), w * scaling
+
+
+def _routing_plan(idx, first: int, held: int, ranks: int, cap: int):
+    """Where every assignment goes.  Experts ``first .. first + ranks *
+    held`` live on ``ranks`` ranks, ``held`` each; the routed-row buffer
+    has ``ranks * cap`` rows, rank p's at ``p * cap ..``, each rank's
+    rows in expert order.  An assignment to an expert outside that range
+    is nobody's here and gets no row (the chip's share of a deployment);
+    one that finds its rank's ``cap`` rows taken is counted in
+    ``overflow`` — with ``cap = N * min(k, held)`` that cannot happen.
+
+    -> dict: ``slot`` (R,) the flat assignment ``n * k + j`` each row
+    serves, ``row_valid`` (R,), ``pos`` / ``valid`` (N, k) each
+    assignment's row, ``sizes`` (ranks, held) rows per expert,
+    ``counts`` (ranks, held) assignments per expert, ``overflow`` ()."""
+    N, k = idx.shape
+    G = held * ranks
+    g = idx.reshape(-1) - first
+    key = jnp.where((g >= 0) & (g < G), g, G).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True)
+    sorted_at = jnp.argsort(order)           # inverse permutation
+    counts = jnp.sum(key[:, None] == jnp.arange(G, dtype=jnp.int32),
+                     axis=0, dtype=jnp.int32).reshape(ranks, held)
+    per_rank = jnp.sum(counts, axis=1)
+    start = jnp.cumsum(per_rank) - per_rank
+    kept = jnp.minimum(per_rank, cap)
+    ends = jnp.minimum(jnp.cumsum(counts, axis=1), cap)
+    sizes = jnp.diff(ends, axis=1, prepend=0)
+    c = jnp.arange(cap, dtype=jnp.int32)
+    row_valid = (c[None, :] < kept[:, None]).reshape(-1)
+    slot = order[jnp.minimum(start[:, None] + c[None, :],
+                             N * k - 1).reshape(-1)]
+    rank_of = jnp.minimum(key // held, ranks - 1)
+    within = sorted_at - start[rank_of]
+    valid = (key < G) & (within < cap)
+    pos = jnp.where(valid, rank_of * cap + within, 0)
+    return {"slot": slot, "row_valid": row_valid,
+            "pos": pos.reshape(N, k), "valid": valid.reshape(N, k),
+            "sizes": sizes, "counts": counts,
+            "overflow": jnp.sum(per_rank - kept)}
+
+
+# Rows into buffer order and back.  ``_take_rows`` and ``_spread_rows``
+# are each other's transpose — every valid row serves exactly one valid
+# (n, j) — so both directions are gathers: autodiff alone would turn the
+# transpose of a gather into a scatter-add.
+@jax.custom_vjp
+def _take_rows(x, src, row_valid, pos, valid):
+    """x (M, D) -> (R, D): row r is x[src[r]], nought where not valid."""
+    return jnp.where(row_valid[:, None], x[src], 0).astype(x.dtype)
+
+
+@jax.custom_vjp
+def _spread_rows(y, src, row_valid, pos, valid):
+    """y (R, D) -> (M, D): row m is the sum over j of y[pos[m, j]] where
+    valid, accumulated in float32."""
+    acc = jnp.zeros((pos.shape[0], y.shape[1]), jnp.float32)
+    for j in range(pos.shape[1]):
+        acc = acc + jnp.where(valid[:, j, None], y[pos[:, j]], 0)
+    return acc.astype(y.dtype)
+
+
+_take_rows.defvjp(
+    lambda x, *plan: (_take_rows(x, *plan), plan),
+    lambda plan, g: (_spread_rows(g, *plan), None, None, None, None))
+_spread_rows.defvjp(
+    lambda y, *plan: (_spread_rows(y, *plan), plan),
+    lambda plan, g: (_take_rows(g, *plan), None, None, None, None))
+
+
+def _grouped_ffn(xs, w1, w3, w2, sizes):
+    """SwiGLU of every row by its own expert: rows in expert order,
+    ``sizes`` rows each; rows past the last group come out as nought."""
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(lax.ragged_dot(xs, w1, sizes)) \
+            * lax.ragged_dot(xs, w3, sizes)
+        return lax.ragged_dot(h, w2, sizes)
+
+
+def _exchange(xs, sizes, ep_axis: str, ep: int, cap: int):
+    """The rows to the ranks that hold their experts, and the way back.
+
+    xs: (ep * cap, D), rank p's rows at ``p * cap ..`` in expert order;
+    sizes: (ep, held) rows per expert of each rank.  After the
+    ``all_to_all`` a rank holds (source, cap) rows, each source's in
+    expert order; they are regrouped by expert across the sources — a
+    permutation, by the same pair of gathers.
+    -> (rows in expert order, (held,) group sizes, back: their results
+    -> this rank's buffer order)."""
+    D = xs.shape[-1]
+    held = sizes.shape[1]
+    recv = lax.all_to_all(xs.reshape(ep, cap, D), ep_axis, 0, 0)
+    sizes_from = lax.all_to_all(sizes, ep_axis, 0, 0)      # (source, held)
+    ends = jnp.cumsum(sizes_from, axis=1)
+    c = jnp.arange(cap, dtype=jnp.int32)
+    expert = jnp.sum(c[None, :, None] >= ends[:, None, :], axis=-1,
+                     dtype=jnp.int32).reshape(-1)          # held: no row
+    order = jnp.argsort(expert, stable=True)
+    regroup = (order, jnp.arange(ep * cap) < jnp.sum(sizes_from),
+               jnp.argsort(order)[:, None], (expert < held)[:, None])
+
+    def back(out):
+        out = _spread_rows(out, *regroup).reshape(ep, cap, D)
+        return lax.all_to_all(out, ep_axis, 0, 0).reshape(ep * cap, D)
+
+    return (_take_rows(recv.reshape(ep * cap, D), *regroup),
+            jnp.sum(sizes_from, axis=0), back)
+
+
+def routed_experts(x, router_w, bias, w1, w3, w2, *, top_k: int,
+                   first_expert: int = 0, scaling: float = 1.0,
+                   rows: Optional[int] = None, mesh=None,
+                   token_axes=(), ep_axis: Optional[str] = None):
+    """The routed-experts FFN ``sum_e w_e (silu(z W1_e) * z W3_e) W2_e``
+    over the chosen experts that are HELD here, no assignment dropped.
+
+    x: (B, T, D).  ``router_w`` (D, E) and ``bias`` (E,) span the
+    router's whole width E; ``w1``/``w3`` (held, D, F) and ``w2`` (held,
+    F, D) are experts ``first_expert .. first_expert + held``.  Routing,
+    top-k and the weights' normalisation run over all E; what the experts
+    that are not held would add is left out (one chip's share of a
+    deployment: run once per share, the results add up to the whole
+    layer).
+
+    ``rows`` is the size of the routed-row buffer per shard of tokens;
+    None takes ``N * min(k, held)``, which no routing overflows.  A
+    smaller buffer costs fewer padded rows; assignments that then find no
+    room are left out and counted.
+
+    Under a ``mesh`` of more than one device the layer runs per shard of
+    the batch (``token_axes``); with ``ep_axis`` the experts' leading
+    axis is sharded over it as well and the rows travel by two
+    ``all_to_all``s (``first_expert`` then counts from rank 0).
+
+    -> (y (B, T, D), counts (held over all ranks,) int32 assignments per
+    held expert, overflow () int32)."""
+    B, T, D = x.shape
+    k = top_k
+    ep = mesh.shape[ep_axis] if (mesh is not None and ep_axis) else 1
+    axes = tuple(a for a in token_axes
+                 if mesh is not None and mesh.shape.get(a, 1) > 1)
+    if ep > 1 and ep_axis not in axes:
+        raise ValueError(f"tokens must be sharded over {ep_axis!r} too: "
+                         f"token_axes {token_axes}")
+
+    def local(x, router_w, bias, w1, w3, w2):
+        held = w1.shape[0]
+        z = x.reshape(-1, D)
+        N = z.shape[0]
+        cap = rows if rows is not None else N * min(k, held)
+        with jax.named_scope("moe_route"):
+            idx, w = sigmoid_topk_routing(z, router_w, bias, k, scaling)
+        with jax.named_scope("moe_dispatch"):
+            plan = _routing_plan(idx, first_expert, held, ep, cap)
+            to_rows = (plan["slot"] // k, plan["row_valid"],
+                       plan["pos"], plan["valid"])
+            xs = _take_rows(z, *to_rows)
+            sizes = plan["sizes"]
+            if ep > 1:
+                xs, sizes, back = _exchange(xs, sizes, ep_axis, ep, cap)
+        out = _grouped_ffn(xs, w1, w3, w2, sizes.reshape(-1))
+        with jax.named_scope("moe_combine"):
+            if ep > 1:
+                out = back(out)
+            # each row's weight, by the same pair of gathers over the
+            # flat (N k, 1) weights
+            w_row = _take_rows(
+                w.reshape(-1, 1), plan["slot"], plan["row_valid"],
+                plan["pos"].reshape(-1, 1), plan["valid"].reshape(-1, 1))
+            y = _spread_rows(out * w_row.astype(out.dtype), *to_rows)
+        counts, overflow = plan["counts"], plan["overflow"]
+        if axes:
+            counts, overflow = lax.psum((counts, overflow), axes)
+        counts = counts[lax.axis_index(ep_axis) if ep > 1 else 0]
+        return y.reshape(x.shape), counts, overflow
+
+    if not axes:
+        return local(x, router_w, bias, w1, w3, w2)
+    e_spec = P(ep_axis) if ep > 1 else P()
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(axes), P(), P(), e_spec, e_spec, e_spec),
+        out_specs=(P(axes), e_spec, P()), check_vma=False)(
+            x, router_w, bias, w1, w3, w2)

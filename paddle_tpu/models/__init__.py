@@ -4,8 +4,12 @@ The reference ships its NLP flagships out-of-tree (ERNIE) atop
 ``python/paddle/nn/layer/transformer.py``; this package provides the
 equivalent in-tree: an eager nn.Layer GPT (optionally tensor-parallel via
 fleet mp layers) and a fully-compiled SPMD trainer that pipelines the
-blocks over the ``pp`` mesh axis.
+blocks over the ``pp`` mesh axis.  ``lfm2_moe`` is a second functional
+model for the same step builder: gated short convolutions, grouped-query
+attention and routed experts.
 """
 from .gpt import GPTConfig, GPT, GPTBlock  # noqa: F401
 from .gpt_spmd import (init_gpt_params, build_spmd_train_step,  # noqa: F401
                        gpt_param_shardings)
+from .lfm2_moe import (Lfm2MoeConfig, init_lfm2_moe_params,  # noqa: F401
+                       lfm2_moe_param_shardings)

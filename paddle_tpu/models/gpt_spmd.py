@@ -17,6 +17,7 @@ parameter shardings (slots live sharded over mp/pp like their params).
 """
 from __future__ import annotations
 
+import types
 from functools import partial
 from typing import Dict, Optional, Tuple
 
@@ -147,7 +148,7 @@ def make_block_fn(cfg: GPTConfig, sp_axis: Optional[str] = None,
     return block_fn
 
 
-def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
+def build_spmd_train_step(cfg, mesh: Mesh,
                           num_microbatches: int = 1,
                           learning_rate: float = 1e-3,
                           weight_decay: float = 0.01,
@@ -160,6 +161,18 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
 
     step(params, opt_state, ids, labels) -> (loss, params, opt_state);
     init_fn(seed) -> (params, opt_state) placed onto the mesh.
+
+    ``cfg`` is a ``GPTConfig`` or a configuration that brings its own
+    model: an object with ``spmd_parts(mesh)`` (``Lfm2MoeConfig``) whose
+    result gives ``init(key)``, ``shardings``, ``trunk(params, ids,
+    remat) -> (final hidden states, counters)``, ``batch_axes``,
+    ``step_name`` and the leaves that ``keep_float32`` or are ``frozen``
+    (no gradient, no update).  What is the step's own is kept here once
+    for every model: the cast to ``compute_dtype``, the remat policies,
+    the fused / chunked loss head, AdamW, ZeRO, the jit and its donation.
+    A model whose trunk counts something on the device (routed
+    assignments per expert, overflow) gets those int32 counters as a
+    fourth result: (loss, params, opt_state, counters).
 
     ``schedule_mode`` (reference section_worker.cc:62): "F-then-B" runs
     the fill-drain forward pipeline and lets jax.grad build the backward
@@ -188,6 +201,7 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
     from ..distributed.fleet.meta_optimizers.zero import (
         shard_tree, zero_state_shardings)
 
+    own = cfg.spmd_parts(mesh) if hasattr(cfg, "spmd_parts") else None
     pp = mesh.shape.get("pp", 1)
     sp = mesh.shape.get("sp", 1)
     sharding_n = mesh.shape.get("sharding", 1)
@@ -198,7 +212,8 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
     batch_axes = tuple(a for a in ("dp", "sharding")
                        if mesh.shape.get(a, 1) > 1) or None
     sp_axis = "sp" if use_sp else None
-    block_fn = make_block_fn(cfg, sp_axis=sp_axis, mesh=mesh)
+    block_fn = None if own else make_block_fn(cfg, sp_axis=sp_axis,
+                                              mesh=mesh)
 
     # remat policy (reference recompute_optimizer checkpoints attr):
     #   full — recompute everything in backward (min HBM, +1/3 flops)
@@ -233,7 +248,25 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
     if use_pp and L % pp != 0:
         raise ValueError(f"num_layers {L} must divide pp {pp}")
 
+    def _cast(params, keep=None):
+        """float32 masters -> ``compute_dtype`` (AMP O2: bf16 matmuls on
+        the MXU), but the leaves a model keeps in float32."""
+        if compute_dtype == jnp.float32:
+            return params
+        return jax.tree_util.tree_map_with_path(
+            lambda path, a: a.astype(compute_dtype)
+            if a.dtype == jnp.float32 and not (keep and keep(path))
+            else a, params)
+
     def trunk(params, ids):
+        """ids -> (final hidden states, the model's counters): the cast
+        (under ``embed``, where the GPT trace has always shown it) and
+        then the model's own trunk."""
+        with jax.named_scope("embed"):
+            params = _cast(params, model.keep_float32)
+        return model.trunk(params, ids, maybe_remat)
+
+    def gpt_trunk(params, ids, remat):
         """Non-pp/non-sp forward minus the head matmul: the shared path
         for plain forward() and the chunked-CE loss.
 
@@ -249,10 +282,6 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
         the split transpose), dodging the bad emitter everywhere.
         """
         with jax.named_scope("embed"):
-            if compute_dtype != jnp.float32:
-                params = jax.tree.map(
-                    lambda a: a.astype(compute_dtype)
-                    if a.dtype == jnp.float32 else a, params)
             x = params["wte"][ids] + params["wpe"][:ids.shape[1]][None]
         blocks = params["blocks"]
         with jax.named_scope("unstack"):
@@ -267,22 +296,22 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
                 # fusion ran 3.43 ms vs 1.81 with materialized weights —
                 # the copies themselves are ~0.1 ms/layer)
                 p_i = lax.optimization_barrier(p_i)
-            x = maybe_remat(block_fn)(p_i, x)
+            x = remat(block_fn)(p_i, x)
         with jax.named_scope("final_ln"):
-            return _layernorm(x, params["ln_f_g"], params["ln_f_b"])
+            return _layernorm(x, params["ln_f_g"], params["ln_f_b"]), {}
+
+    model = own or types.SimpleNamespace(
+        init=lambda key: init_gpt_params(cfg, key),
+        shardings=gpt_param_shardings(mesh, cfg), trunk=gpt_trunk,
+        batch_axes=batch_axes, step_name="gpt_spmd_train_step",
+        keep_float32=None, frozen=None)
+    batch_axes = model.batch_axes
 
     def forward(params, ids):
-        if not (use_pp or use_sp):
-            x = trunk(params, ids)
-            head_w = params["head_w"]
-            return x @ head_w.astype(x.dtype)
+        """The pp / sp paths' logits (GPT only)."""
         B, T = ids.shape
         with jax.named_scope("embed"):
-            if compute_dtype != jnp.float32:
-                # AMP O2: f32 master params, bf16 matmuls on the MXU
-                params = jax.tree.map(
-                    lambda a: a.astype(compute_dtype)
-                    if a.dtype == jnp.float32 else a, params)
+            params = _cast(params)
             x = params["wte"][ids] + params["wpe"][:T][None]
         if use_pp:
             # (M, mb, T, D): micro-batch dim unsharded, per-mb batch over
@@ -379,8 +408,8 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
                 lse = jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
                 at_label = jnp.take_along_axis(shifted, labels[..., None],
                                                axis=-1)[..., 0]
-                return jnp.mean(lse - at_label)
-        x = trunk(params, ids)
+                return jnp.mean(lse - at_label), {}
+        x, counters = trunk(params, ids)
         head_w = params["head_w"].astype(x.dtype)
         B, T, D = x.shape
         from ..ops import pallas
@@ -398,11 +427,13 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
             # call the benchmark finds by its compiler-made name.
             from ..ops.pallas.softmax_xent import softmax_xent_loss
             return softmax_xent_loss(x.reshape(B * T, D), head_w,
-                                     labels.reshape(B * T), interpret)
+                                     labels.reshape(B * T),
+                                     interpret), counters
         with jax.named_scope("loss_head"):
-            return chunked_ce(x, head_w, labels)
+            return chunked_ce(x, head_w, labels), counters
 
     def adamw_update(params, grads, opt_state):
+        old_params = params
         step = opt_state["step"] + 1
         b1, b2, eps = 0.9, 0.999, 1e-8
         m = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g,
@@ -415,14 +446,11 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
             lambda p, mm, vv: (1 - learning_rate * weight_decay) * p
             - learning_rate * (mm / c1) / (jnp.sqrt(vv / c2) + eps),
             params, m, v)
+        if model.frozen is not None:
+            params = jax.tree_util.tree_map_with_path(
+                lambda path, new, old: old if model.frozen(path) else new,
+                params, old_params)
         return params, {"m": m, "v": v, "step": step}
-
-    def _cast(params):
-        if compute_dtype == jnp.float32:
-            return params
-        return jax.tree.map(
-            lambda a: a.astype(compute_dtype)
-            if a.dtype == jnp.float32 else a, params)
 
     def loss_and_grads_1f1b(params, ids, labels):
         """Fused loss+grad via the interleaved 1F1B pipeline (no outer
@@ -493,9 +521,10 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
             lambda g, p: g.astype(p.dtype), grads, params)
         return loss, grads
 
-    base_shardings = gpt_param_shardings(mesh, cfg)
+    base_shardings = model.shardings
     shapes = jax.tree.map(
-        lambda a: a.shape, init_gpt_params(cfg, jax.random.PRNGKey(0)))
+        lambda a: a.shape,
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     if use_zero:
         shardings, state_shardings = zero_state_shardings(
             base_shardings, shapes, stage=sharding_stage, offload=offload)
@@ -506,11 +535,13 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
         shardings, state_shardings = base_shardings, base_shardings
         grad_shardings, state_dev = None, None
 
-    def gpt_spmd_train_step(params, opt_state, ids, labels):
+    def train_step(params, opt_state, ids, labels):
+        counters = {}
         if use_pp and schedule_mode == "1F1B":
             loss, grads = loss_and_grads_1f1b(params, ids, labels)
         else:
-            loss, grads = jax.value_and_grad(loss_fn)(params, ids, labels)
+            (loss, counters), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, ids, labels)
         if grad_shardings is not None:
             # ZeRO-2: constrain grads to the sharded layout — GSPMD turns
             # the data-parallel gradient all-reduce into a reduce-scatter
@@ -531,10 +562,16 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
                                 {"m": state_shardings,
                                  "v": state_shardings})
             opt_state = {**opt_state, **mv}
+        if counters:
+            return loss, params, opt_state, counters
         return loss, params, opt_state
 
+    # the jitted function's name is a symbol: the compile cache, whose
+    # key ignores scopes, tells one model's step from another's by it
+    train_step.__name__ = train_step.__qualname__ = model.step_name
+
     def init_fn(seed: int = 0):
-        params = init_gpt_params(cfg, jax.random.PRNGKey(seed))
+        params = model.init(jax.random.PRNGKey(seed))
         params = jax.tree.map(jax.device_put, params, shardings)
         opt_state = {
             "m": jax.tree.map(
@@ -549,4 +586,4 @@ def build_spmd_train_step(cfg: GPTConfig, mesh: Mesh,
     # offload: opt_state lives in pinned host memory — XLA cannot alias
     # host-memory inputs onto device-memory outputs, so skip its donation
     donate = (0,) if offload else (0, 1)
-    return jax.jit(gpt_spmd_train_step, donate_argnums=donate), init_fn
+    return jax.jit(train_step, donate_argnums=donate), init_fn

@@ -89,7 +89,11 @@ def softmax_xent_fwd(x, w, labels, block_rows: int = 1024,
     """x: (N, D) bf16/f32, w: (D, V), labels: (N,) int32 ->
     (lse (N,) f32, at (N,) f32).  loss = mean(lse - at)."""
     N, D = x.shape
-    block_rows = min(block_rows, N)
+    # the resident x block is double-buffered: past D = 1024 fewer rows
+    # keep it at the 1024 x 1024 elements the 16 MB of scoped VMEM hold
+    # beside the W tiles and the f32 logits tile (D = 2048 at 1024 rows
+    # asked for 21 MB, compiled chip-free for a v5e)
+    block_rows = min(block_rows, N, max(128, (1 << 20) // D))
     while N % block_rows:
         block_rows //= 2
     w, V, Vp = _pad_vocab(w, block_v)

@@ -42,25 +42,30 @@ DIFFERENTIATED = ("embed", "unstack", *BLOCK, "final_ln", "loss_head")
 POLICIES = ("none", "ctx", "ctx_ffn", "dots", "full")
 
 
-def _lower(cfg, mesh, batch, compute_dtype=jnp.bfloat16, **kw):
-    """The step lowered from shapes alone, so that the same helper serves
+def _lower_step(step, shapes, shardings, mesh, batch, seq_len, baxes=()):
+    """A step lowered from shapes alone, so that the same helper serves
     the CPU mesh and a described TPU."""
+    params = jax.tree.map(
+        lambda s, ns: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=ns),
+        shapes, shardings)
+    opt = {"m": params, "v": params,
+           "step": jax.ShapeDtypeStruct(
+               (), jnp.int32, sharding=NamedSharding(mesh, P()))}
+    ids = jax.ShapeDtypeStruct(
+        (batch, seq_len), jnp.int32,
+        sharding=NamedSharding(mesh, P(baxes or None)))
+    return step.lower(params, opt, ids, ids)
+
+
+def _lower(cfg, mesh, batch, compute_dtype=jnp.bfloat16, **kw):
     step, _ = build_spmd_train_step(cfg, mesh, compute_dtype=compute_dtype,
                                     **kw)
     shapes = jax.eval_shape(
         lambda: init_gpt_params(cfg, jax.random.PRNGKey(0)))
-    params = jax.tree.map(
-        lambda s, ns: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=ns),
-        shapes, gpt_param_shardings(mesh, cfg))
-    opt = {"m": params, "v": params,
-           "step": jax.ShapeDtypeStruct(
-               (), jnp.int32, sharding=NamedSharding(mesh, P()))}
     baxes = tuple(a for a in ("dp", "sharding")
                   if mesh.shape.get(a, 1) > 1)
-    ids = jax.ShapeDtypeStruct(
-        (batch, cfg.max_seq_len), jnp.int32,
-        sharding=NamedSharding(mesh, P(baxes or None)))
-    return step.lower(params, opt, ids, ids)
+    return _lower_step(step, shapes, gpt_param_shardings(mesh, cfg), mesh,
+                       batch, cfg.max_seq_len, baxes)
 
 
 def _op_names(hlo_text):
@@ -283,3 +288,112 @@ def test_no_qkv_sized_layout_work_around_attention(request, fixture, batch):
     cfg, hlo = request.getfixturevalue(fixture)
     assert not _qkv_sized_layout_work(cfg, hlo, batch)
     assert len(_mosaic_calls(hlo)) == 2 * cfg.num_layers + 1
+
+
+# ---------------------------------------------------------------------------
+# the LFM2-MoE step: its own scopes, its own name, its own kernels
+# ---------------------------------------------------------------------------
+LFM2_SCOPES = ("embed", "short_conv", "gqa_qkv", "gqa_out", "dense_ffn",
+               "moe_route", "moe_dispatch", "moe_experts", "moe_combine",
+               "final_norm")
+
+
+def _lower_lfm2(cfg, mesh, batch, seq_len, **kw):
+    step, _ = build_spmd_train_step(cfg, mesh, compute_dtype=jnp.bfloat16,
+                                    **kw)
+    parts = cfg.spmd_parts(mesh)
+    return _lower_step(
+        step, jax.eval_shape(parts.init, jax.random.PRNGKey(0)),
+        parts.shardings, mesh, batch, seq_len)
+
+
+@pytest.fixture(scope="module")
+def tiny_lfm2_step_text():
+    from paddle_tpu.models.lfm2_moe import Lfm2MoeConfig
+    cfg = Lfm2MoeConfig(
+        vocab_size=128, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_dense_layers=1, num_experts=8,
+        layer_types=("conv", "full_attention", "conv"),
+        num_experts_per_tok=2, num_experts_held=4, num_attention_heads=4,
+        num_key_value_heads=2)
+    return _lower_lfm2(cfg, build_mesh({"dp": 1}), 2, 16,
+                       remat_policy="ctx").compile().as_text()
+
+
+def test_the_lfm2_step_has_its_own_name_and_scopes(tiny_lfm2_step_text):
+    assert "HloModule jit_lfm2_moe_spmd_train_step" in tiny_lfm2_step_text
+    names = _op_names(tiny_lfm2_step_text)
+    for scope in LFM2_SCOPES:
+        assert any(f"jvp({scope})" in n for n in names), scope
+        assert any("transpose(" in n and _under(n, [scope])
+                   for n in names), scope
+    assert any("/optimizer/" in n and "transpose(" not in n for n in names)
+    # GPT's block scopes are GPT's
+    assert not any(_under(n, BLOCK + ("unstack", "final_ln")) for n in names)
+
+
+def test_lfm2_matmuls_sit_under_a_scope(tiny_lfm2_step_text):
+    """Every dot_general but attention's own (a sibling of the scopes,
+    like GPT's) and the loss head's sits under one of the model's scopes;
+    the grouped expert matmuls under ``moe_experts``."""
+    names = _op_names(tiny_lfm2_step_text)
+    dots = [n for n in names if n.endswith("dot_general")]
+    attention = [n for n in dots if "bqd,bkd->bqk" in n or "bqk,bkd->bqd" in n]
+    assert attention and not any(_under(n, LFM2_SCOPES) for n in attention)
+    rest = [n for n in dots
+            if n not in attention and not _under(n, ["loss_head"])]
+    assert rest and all(_under(n, LFM2_SCOPES) for n in rest), \
+        [n for n in rest if not _under(n, LFM2_SCOPES)]
+    # (off the TPU ``lax.ragged_dot`` lowers to masked dot_generals)
+    assert any(_under(n, ["moe_experts"]) for n in rest)
+
+
+@pytest.fixture(scope="module")
+def lfm2_real_width_hlo(v5e):
+    """An attention + dense layer and a conv + experts layer at the
+    published widths and the cell's B=4 x T=8192, for one v5e chip."""
+    from paddle_tpu.models.lfm2_moe import Lfm2MoeConfig
+    from paddle_tpu.ops import pallas
+    mp = pytest.MonkeyPatch()
+    for mod in (pallas,
+                sys.modules["paddle_tpu.ops.pallas.flash_attention"]):
+        mp.setattr(mod, "on_tpu", lambda: True)
+    try:
+        cfg = Lfm2MoeConfig(vocab_size=8192, num_dense_layers=1,
+                            layer_types=("full_attention", "conv"),
+                            num_experts_held=8, moe_rows_factor=2.0)
+        mesh = Mesh(np.asarray(v5e[:1]), ("dp",))
+        return _lower_lfm2(cfg, mesh, 4, 8192,
+                           remat_policy="ctx").compile().as_text()
+    finally:
+        mp.undo()
+
+
+def test_every_lfm2_mosaic_call_is_one_the_benchmark_finds(
+        lfm2_real_width_hlo):
+    """The streaming flash kernels (forward, the forward again under
+    ``ctx``, dq, dk/dv), the compiler's grouped expert matmuls and the
+    fused loss head: each Mosaic call matches a pattern of the cell's
+    metric files, and each pattern finds a call."""
+    mosaic = _mosaic_calls(lfm2_real_width_hlo)
+    groups = {m: _patterns(m) for m in (
+        "stream_attn_roofline", "moe_experts_roofline",
+        "lfm2_loss_head_events")}
+    hits = {m: [c for c in mosaic if any(r.search(c) for r in rx)]
+            for m, rx in groups.items()}
+    assert len(hits["stream_attn_roofline"]) == 4
+    for rx in groups["stream_attn_roofline"]:
+        assert any(rx.search(c) for c in mosaic), rx.pattern
+    # three grouped matmuls forward, three recomputed, six backward, and
+    # the compiler's own calls that lay out the groups for them
+    experts = hits["moe_experts_roofline"]
+    assert sum(c.startswith("%ragged-dot-metadata") for c in experts) >= 1
+    assert sum(not c.startswith("%ragged-dot-metadata")
+               for c in experts) == 12
+    assert len(hits["lfm2_loss_head_events"]) == 1
+    assert sum(map(len, hits.values())) == len(mosaic), \
+        [c[:100] for c in mosaic
+         if not any(c in h for h in hits.values())]
+    # and GPT's attention patterns do not claim the loss head or experts
+    gpt_fwd = _patterns("attn_roofline")[0]
+    assert not any(gpt_fwd.search(c) for c in mosaic)
