@@ -29,6 +29,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops.pallas.flash_attention import RESIDUAL_NAMES
 from .gpt import GPTConfig
 
 __all__ = ["init_gpt_params", "gpt_param_shardings",
@@ -217,8 +218,11 @@ def build_spmd_train_step(cfg, mesh: Mesh,
 
     # remat policy (reference recompute_optimizer checkpoints attr):
     #   full — recompute everything in backward (min HBM, +1/3 flops)
-    #   ctx  — save each block's attention output: the backward skips the
-    #          second flash-attention forward (the costliest recompute)
+    #   ctx  — save each block's attention output, and what a flash
+    #          kernel whose backward needs its own forward's results
+    #          names (the stream regime's out and lse; no GPT-length
+    #          kernel names anything): the backward never runs a
+    #          flash-attention forward again (the costliest recompute)
     #   dots — save all matmul outputs (XLA's dots_saveable)
     #   none — no remat: XLA keeps what backward needs (max HBM)
     if remat_policy == "none":
@@ -228,7 +232,7 @@ def build_spmd_train_step(cfg, mesh: Mesh,
         def maybe_remat(f):
             return jax.checkpoint(
                 f, policy=jax.checkpoint_policies.save_only_these_names(
-                    "attn_ctx"))
+                    "attn_ctx", *RESIDUAL_NAMES))
     elif remat_policy == "ctx_ffn":
         # save attention outputs AND the gelu(ffn-up) activation: the
         # backward skips the two biggest recomputed matmuls; fits only
@@ -236,7 +240,7 @@ def build_spmd_train_step(cfg, mesh: Mesh,
         def maybe_remat(f):
             return jax.checkpoint(
                 f, policy=jax.checkpoint_policies.save_only_these_names(
-                    "attn_ctx", "ffn_up"))
+                    "attn_ctx", "ffn_up", *RESIDUAL_NAMES))
     elif remat_policy == "dots":
         def maybe_remat(f):
             return jax.checkpoint(

@@ -2,19 +2,57 @@
 
 Reference parity: the capability of ``operators/fused/fused_attention_op.cu``
 (+ cuDNN attention) — attention without materialising the (T, T) score
-matrix in HBM.  Mechanism is the TPU one: pallas kernels that stream K/V
-blocks through VMEM with the online-softmax rescaling (flash-attention
-algorithm), keeping the running max/denominator in f32 while the matmuls
-ride the MXU.
+matrix in HBM.  Mechanism is the TPU one: pallas kernels that hold or
+stream K/V through VMEM, keeping the softmax statistics in f32 while the
+matmuls ride the MXU.
 
-Forward saves the per-row log-sum-exp; backward is two pallas kernels
-(dQ over k-blocks; dK/dV over q-blocks) that rebuild the normalised
-probabilities as ``exp(s - lse)`` — no (T, T) tensor, no extra softmax
-pass.  On a TPU the kernels are always compiled; off-TPU, for lengths
-that are not a multiple of 128 and for causal ``seq_q > seq_k`` both
-directions run plain XLA math (``PADDLE_PALLAS_FORCE=1`` takes the
-kernels in interpret mode off-TPU — the kernel unit tests).  Each public
-call records the regime it selected (``ops.pallas.selections()``).
+Which kernels own which key length Tk (``_pallas_mode``):
+
+- Tk <= 1024, "small": whole K/V rows and the whole (block_q, Tk) score
+  row in VMEM, G batch-heads a grid step, one fused backward that
+  rebuilds lse and delta in-kernel; residuals are (q, k, v) alone.
+- Tk <= 4096, "mid": the same design, q blocks tiled.  What bounds it is
+  the f32 (block_q, Tk) score row and its companions, not K and V.
+- Tk > 4096, "stream": the score intermediates are bounded to
+  (block_q, chunk) whatever Tk is.  Two forms, chosen by shape:
+
+  * **resident** (``_resident_flash_fwd`` / ``_resident_flash_bwd``):
+    the mid design with a loop inside the kernel.  K and V rows stay in
+    VMEM for all q blocks of a head (fetched once a head, not once a q
+    block); a ``fori_loop`` runs over the key chunks the causal mask
+    leaves live for this q block and builds the mask only on the chunks
+    the diagonal crosses; the forward carries the online softmax and
+    emits lse; ONE fused backward (5 matmuls and one exponential pass a
+    live tile) writes dq per q block and accumulates dK/dV in f32 VMEM
+    scratch across the q blocks.  Its VMEM is
+    ``_resident_vmem_bytes(Tk, d, itemsize, block_q, chunk)`` =
+    2 x (K + V + dK + dV blocks) + 2 f32 (Tk, d) accumulators + the
+    q-sized blocks + 8 f32 (block_q, chunk) tiles, every row padded to
+    128 lanes: 35.7 MB at Tk = 8192, d = 64 or 128, bf16, blocks of
+    512 (the compiler takes between 28 and 32 MB for the backward,
+    20 MB for the forward at 1024 x 1024).  That is over Mosaic's
+    default scoped limit (16 MiB, a compiler default and not the chip's
+    VMEM), so the pair asks for its budget and a quarter more through
+    ``vmem_limit_bytes`` (44.6 MB) and is taken when that is within
+    ``_RESIDENT_VMEM_SHARE`` of what the installed jax reports for the
+    chip (128 MiB a core on a v5e: rows to Tk = 16384 in bf16).
+  * **grid-streamed** (``_flash_fwd`` / ``_flash_bwd``), for rows whose
+    budget does not fit: K/V blocks ride the innermost grid dimension
+    with the online-softmax state in scratch, dq and dk/dv are two
+    kernels.  Dead tiles are still grid steps, but their index maps are
+    clamped to the last live block, so they fetch nothing.
+
+  Both forms keep ``out`` and ``lse`` for the backward, under the
+  checkpoint names ``RESIDUAL_NAMES``: a remat policy that lists them
+  (the step builder's ``ctx`` policies do) never runs the forward a
+  second time.  ``out`` is kept in the caller's (B, T, H, d) layout, so a
+  model that saves its attention output saves these bytes once.
+
+On a TPU the kernels are always compiled; off-TPU, for lengths that are
+not a multiple of 128 and for causal ``seq_q > seq_k`` both directions
+run plain XLA math (``PADDLE_PALLAS_FORCE=1`` takes the kernels in
+interpret mode off-TPU — the kernel unit tests).  Each public call
+records the regime it selected (``ops.pallas.selections()``).
 
 Under a mesh of more than one device the public entries take the mesh
 and the axes that shard batch and heads, and run the kernels per shard
@@ -30,6 +68,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec
@@ -44,15 +83,17 @@ NEG_INF = -1e30
 # Largest K-length whose full (T, T) score block comfortably fits VMEM
 # f32 alongside the resident K/V blocks — the "small-T" kernel regime.
 SMALL_T_MAX = 1024
-# Largest K-length whose FULL K/V rows stay VMEM-resident while q tiles
-# stream through (the "mid" regime: q-block-tiled forward + one fused
-# backward with in-kernel lse/delta — the r4 small-T techniques carried
-# into the long-context shapes the r4 streaming kernels only tied XLA
-# on).  Bounded by the backward's VMEM: ~3 live f32 (block_q, Tk)
-# intermediates + 2 f32 (Tk, d) accumulators; at Tk=4096/block_q=256
-# that is ~8 MB of 16.  Beyond this the streaming kernels take over
-# with O(T) memory.
+# Largest K-length whose whole (block_q, Tk) f32 score row stays in VMEM
+# (the "mid" regime: q-block-tiled forward + one fused backward with
+# in-kernel lse/delta).  Bounded by the backward's ~3-5 live f32
+# (block_q, Tk) intermediates under Mosaic's default 16 MiB scoped
+# limit, which these kernels do not raise.  Beyond this the "stream"
+# regime bounds the score tile to (block_q, chunk): K/V rows resident
+# under a requested VMEM limit while they fit, grid-streamed after.
 MID_T_MAX = 4096
+# Names the stream regime's residuals are saved under (a remat policy
+# that lists them keeps the forward from running twice).
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 def _pallas_mode(seq_q: int, seq_k: int, causal: bool):
@@ -76,8 +117,9 @@ def _pallas_mode(seq_q: int, seq_k: int, causal: bool):
     # T=512 materialises f32 (T, T) score tensors in the backward and
     # costs ~21 ms/layer fwd+bwd; the small-T kernel pair (full-K
     # resident, G batch-heads per grid step, one fused backward) beats
-    # it.  The mid kernels carry the same design to T<=MID_T_MAX (4096); the
-    # streaming kernels own anything longer with O(T) memory.
+    # it.  The mid kernels carry the same design to T<=MID_T_MAX (4096);
+    # the stream regime owns anything longer (resident K/V with a loop
+    # over live key chunks while that fits VMEM, grid-streamed beyond).
     small = seq_k <= SMALL_T_MAX and seq_q <= SMALL_T_MAX
     mid = not small and seq_k <= MID_T_MAX and seq_q <= MID_T_MAX
     return ("small" if small else "mid" if mid else "stream"), \
@@ -151,6 +193,30 @@ def _block_sizes(T, Tk, block_q, block_k):
     return block_q, block_k
 
 
+def _clamped_k_map(block_q, block_k, offset, nk, causal):
+    """Index map of a K/V block over a (b, q block i, k block j) grid: a
+    step the causal mask leaves dead names the last live block again, so
+    that Mosaic (which fetches only when the index changes) fetches
+    nothing for it."""
+    if not causal:
+        return lambda b, i, j: (b, j, 0)
+
+    def index(b, i, j):
+        _, n_live = _live_chunks(i, block_q, block_k, offset, nk)
+        return b, jnp.minimum(j, n_live - 1), 0
+    return index
+
+
+def _clamped_q_map(block_q, block_k, offset, causal):
+    """The same over a (b, k block j, q block i) grid, for a q-sized
+    block: the q blocks in front of the first whose last row sees column
+    ``j * block_k`` are dead steps and name that one."""
+    if not causal:
+        return lambda b, j, i: (b, i, 0)
+    return lambda b, j, i: (
+        b, jnp.maximum(i, (j * block_k - offset) // block_q), 0)
+
+
 def _flash_fwd(q, k, v, scale: float, causal: bool,
                block_q: int = 256, block_k: int = 512,
                interpret: bool = False):
@@ -163,13 +229,14 @@ def _flash_fwd(q, k, v, scale: float, causal: bool,
     kernel = functools.partial(_fwd_kernel_pipelined, scale=scale,
                                causal=causal, block_q=block_q,
                                block_k=block_k, nk=nk, seq_q=T, seq_k=Tk)
+    k_spec = pl.BlockSpec(
+        (1, block_k, d), _clamped_k_map(block_q, block_k, Tk - T, nk, causal))
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            k_spec, k_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -900,6 +967,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+def _delta(do, o):
+    """D_i = rowsum(dO * O), (BH, T, 1) f32 — one fused elementwise
+    reduce in XLA."""
+    return jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                   axis=-1, keepdims=True)
+
+
 def _flash_bwd(q, k, v, o, lse, do, scale: float, causal: bool,
                block_q: int = 256, block_k: int = 256,
                interpret: bool = False):
@@ -907,12 +981,11 @@ def _flash_bwd(q, k, v, o, lse, do, scale: float, causal: bool,
     Tk = k.shape[1]
     block_q, block_k = _block_sizes(T, Tk, block_q, block_k)
     nq, nk = T // block_q, Tk // block_k
-    # D_i = rowsum(dO * O) — one fused elementwise reduce in XLA
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)              # (BH, T, 1)
+    delta = _delta(do, o)
 
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+    k_spec = pl.BlockSpec(
+        (1, block_k, d), _clamped_k_map(block_q, block_k, Tk - T, nk, causal))
     r_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -929,9 +1002,10 @@ def _flash_bwd(q, k, v, o, lse, do, scale: float, causal: bool,
     )(q, k, v, do, lse, delta)
 
     # dkv grid: (BH, k blocks, q blocks) — same specs re-indexed
-    qs = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
+    q_map = _clamped_q_map(block_q, block_k, Tk - T, causal)
+    qs = pl.BlockSpec((1, block_q, d), q_map)
     ks = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    rs = pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0))
+    rs = pl.BlockSpec((1, block_q, 1), q_map)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, nq=nq,
@@ -951,6 +1025,263 @@ def _flash_bwd(q, k, v, o, lse, do, scale: float, causal: bool,
 
 
 # ---------------------------------------------------------------------------
+# stream regime, resident form: K/V rows stay in VMEM for every q block of
+# a head, a loop inside the kernel runs over the key chunks the causal
+# mask leaves live, one fused backward
+# ---------------------------------------------------------------------------
+# Share of the chip's VMEM the resident pair may ask for; the rest is
+# Mosaic's own (internal scratch, semaphores, spills).
+_RESIDENT_VMEM_SHARE = 0.75
+
+
+def _live_chunks(qi, block_q: int, chunk: int, offset: int, nk: int,
+                 causal: bool = True):
+    """(n_full, n_live) for q block ``qi``: key chunks [0, n_full) hold
+    no masked score, chunks [n_full, n_live) are crossed by the diagonal
+    (row r sees columns <= r + offset), chunks from n_live on are dead.
+    ``qi`` may be a Python int or a traced scalar."""
+    if not causal:
+        return nk, nk
+    n_full = jnp.minimum(nk, (qi * block_q + offset + 1) // chunk)
+    n_live = jnp.minimum(nk, ((qi + 1) * block_q - 1 + offset) // chunk + 1)
+    return n_full, n_live
+
+
+def _causal_mask(qi, j, block_q: int, chunk: int, offset: int):
+    rows = lax.broadcasted_iota(jnp.int32, (block_q, chunk), 0) \
+        + (qi * block_q + offset)
+    cols = lax.broadcasted_iota(jnp.int32, (block_q, chunk), 1) + j * chunk
+    return rows >= cols
+
+
+def _resident_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                         acc_scr, *, scale: float, causal: bool,
+                         block_q: int, chunk: int, seq_q: int, seq_k: int):
+    qi = pl.program_id(1)
+    offset = seq_k - seq_q
+    n_full, n_live = _live_chunks(qi, block_q, chunk, offset,
+                                  seq_k // chunk, causal)
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    q = q_ref[0]                                         # (bq, d)
+
+    def step(j, masked):
+        rows = pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
+        k = k_ref[0, rows, :]                            # (chunk, d)
+        v = v_ref[0, rows, :]
+        # operands stay in input dtype: bf16 x bf16 -> f32 runs the MXU
+        # at full rate; scale folds into the f32 scores
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (bq, chunk)
+        if masked:
+            s = jnp.where(_causal_mask(qi, j, block_q, chunk, offset),
+                          s, NEG_INF)
+        # chunk 0 is live for every row (column 0 is), so m is finite
+        # from the first step on and a row wholly masked in a later
+        # chunk adds exp(NEG_INF - m) = 0
+        m = m_scr[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+
+    lax.fori_loop(0, n_full, lambda j, c: step(j, False), None)
+    if causal:
+        lax.fori_loop(n_full, n_live, lambda j, c: step(j, True), None)
+    l = l_scr[...]
+    o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+    lse_ref[0] = m_scr[...] + jnp.log(l)
+
+
+def _resident_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                         dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                         *, scale: float, causal: bool, block_q: int,
+                         chunk: int, nq: int, seq_q: int, seq_k: int):
+    """q blocks ride the inner ('arbitrary') grid dim; for each, the
+    live key chunks of the resident K/V rows: p = exp(s - lse) from the
+    saved lse, dq accumulated over the chunks and written per q block,
+    dK/dV accumulated in f32 scratch rows until the head's last q block."""
+    qi = pl.program_id(1)
+    offset = seq_k - seq_q
+    n_full, n_live = _live_chunks(qi, block_q, chunk, offset,
+                                  seq_k // chunk, causal)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    dq_scr[...] = jnp.zeros_like(dq_scr)
+    q = q_ref[0]                                         # (bq, d)
+    do = do_ref[0]
+    lse = lse_ref[0]                                     # (bq, 1)
+    delta = delta_ref[0]
+
+    def step(j, masked):
+        rows = pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
+        k = k_ref[0, rows, :]                            # (chunk, d)
+        v = v_ref[0, rows, :]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (bq, chunk)
+        p = jnp.exp(s - lse)
+        if masked:
+            p = jnp.where(_causal_mask(qi, j, block_q, chunk, offset),
+                          p, 0.0)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (bq, chunk)
+        dv_scr[rows, :] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (chunk, d)
+        ds = (p * (dp - delta)).astype(q.dtype)
+        dq_scr[...] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_scr[rows, :] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (chunk, d)
+
+    lax.fori_loop(0, n_full, lambda j, c: step(j, False), None)
+    if causal:
+        lax.fori_loop(n_full, n_live, lambda j, c: step(j, True), None)
+    dq_ref[0] = (scale * dq_scr[...]).astype(dq_ref.dtype)
+
+    @pl.when(qi == nq - 1)
+    def _finalize():
+        dk_ref[0] = (scale * dk_scr[...]).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _resident_blocks(T: int, Tk: int, backward: bool):
+    """(block_q, chunk): the largest power-of-two multiples of 128 that
+    divide the lengths, up to what a v5e measured best at T = 8192,
+    d = 64 (PERF.md, PR 31).  The forward wants long chunks — its
+    per-chunk rescaling of the (block_q, 1) statistics and of the
+    accumulator costs as much as a 256-column slab of scores: 33.7 ms
+    at 512 x 512, 21.9 at 1024 x 1024, 23.7 at 1024 x 2048.  The
+    backward has no such pass and is flat from 512 x 512 (41.7 ms) to
+    1024 x 1024 (42.3); it takes the smaller tiles for their VMEM."""
+    cap = 512 if backward else 1024
+
+    def dividing(n):
+        b = cap
+        while n % b:
+            b //= 2
+        return b
+    return dividing(T), dividing(Tk)
+
+
+def _resident_vmem_bytes(Tk: int, d: int, itemsize: int, block_q: int,
+                         chunk: int) -> int:
+    """VMEM the fused backward (the larger of the pair) holds: every
+    BlockSpec'd operand twice (Mosaic double-buffers them), rows padded
+    to whole 128-lane tiles."""
+    lanes = -(-d // 128) * 128
+    rows = Tk * lanes
+    resident = 2 * (2 * rows * itemsize      # K, V
+                    + 2 * rows * itemsize)   # dK, dV output blocks
+    accumulators = 2 * rows * 4              # dK, dV in f32
+    q_sized = 2 * (3 * block_q * lanes * itemsize     # q, dO, dq
+                   + 2 * block_q * 128 * 4)           # lse, delta: 1 lane
+    dq_acc = block_q * lanes * 4
+    # s, p, dp, ds in f32, p and ds again in the operand dtype, and the
+    # transposes of those two for the contractions over rows
+    tiles = 8 * block_q * chunk * 4
+    return resident + accumulators + q_sized + dq_acc + tiles
+
+
+def _vmem_capacity() -> int:
+    """VMEM bytes of one core as the installed jax reports for the
+    attached chip; with no chip attached (interpret mode, a device-less
+    compile) the smallest of the generations it lists beyond v3."""
+    try:
+        return int(pltpu.get_tpu_info().vmem_capacity_bytes)
+    except ValueError:          # the device is no TPU jax knows
+        return 64 << 20
+
+
+def _resident_vmem_limit(T: int, Tk: int, d: int, itemsize: int):
+    """``vmem_limit_bytes`` for the resident pair at this shape, or None
+    when its budget is over the share of VMEM the pair may take (the
+    grid-streamed kernels then own the shape)."""
+    need = _resident_vmem_bytes(Tk, d, itemsize,
+                                *_resident_blocks(T, Tk, backward=True))
+    # a quarter on top for what Mosaic allocates beside the operands
+    limit = need + need // 4
+    return limit if limit <= _RESIDENT_VMEM_SHARE * _vmem_capacity() \
+        else None
+
+
+def _resident_flash_fwd(q, k, v, scale: float, causal: bool,
+                        block_q: int = None, chunk: int = None,
+                        vmem_limit: int = None, interpret: bool = False):
+    """q/k/v: (BH, T, d) -> (out (BH, T, d), lse (BH, T, 1) f32)."""
+    BH, T, d = q.shape
+    Tk = k.shape[1]
+    bq, ck = _resident_blocks(T, Tk, backward=False)
+    block_q, chunk = _block_sizes(T, Tk, block_q or bq, chunk or ck)
+    qs = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
+    ks = pl.BlockSpec((1, Tk, d), lambda b, i: (b, 0, 0))
+    rs = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
+    return pl.pallas_call(
+        functools.partial(_resident_fwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, chunk=chunk, seq_q=T, seq_k=Tk),
+        grid=(BH, T // block_q),
+        in_specs=[qs, ks, ks],
+        out_specs=[qs, rs],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, d), q.dtype),
+                   jax.ShapeDtypeStruct((BH, T, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+    )(q, k, v)
+
+
+def _resident_flash_bwd(q, k, v, o, lse, do, scale: float, causal: bool,
+                        block_q: int = None, chunk: int = None,
+                        vmem_limit: int = None, interpret: bool = False):
+    """-> (dq, dk, dv), each (BH, ., d), from one kernel."""
+    BH, T, d = q.shape
+    Tk = k.shape[1]
+    bq, ck = _resident_blocks(T, Tk, backward=True)
+    block_q, chunk = _block_sizes(T, Tk, block_q or bq, chunk or ck)
+    nq = T // block_q
+    delta = _delta(do, o)
+    qs = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
+    ks = pl.BlockSpec((1, Tk, d), lambda b, i: (b, 0, 0))
+    rs = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
+    return pl.pallas_call(
+        functools.partial(_resident_bwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, chunk=chunk, nq=nq, seq_q=T,
+                          seq_k=Tk),
+        grid=(BH, nq),
+        in_specs=[qs, ks, ks, qs, rs, rs],
+        out_specs=[qs, ks, ks],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, d), q.dtype),
+                   jax.ShapeDtypeStruct((BH, Tk, d), k.dtype),
+                   jax.ShapeDtypeStruct((BH, Tk, d), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((Tk, d), jnp.float32),
+                        pltpu.VMEM((Tk, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+    )(q, k, v, do, lse, delta)
+
+
+# ---------------------------------------------------------------------------
 # XLA fallback + custom_vjp stitching
 # ---------------------------------------------------------------------------
 def _xla_attention(q, k, v, scale, causal):
@@ -965,8 +1296,31 @@ def _xla_attention(q, k, v, scale, causal):
     return jnp.einsum("bqk,bkd->bqd", p, v)
 
 
+def _stream_flash_fwd(q, k, v, scale, causal, interpret):
+    """The stream regime's forward: resident K/V where the pair's VMEM
+    budget fits the chip, grid-streamed beyond."""
+    limit = _resident_vmem_limit(q.shape[1], k.shape[1], q.shape[2],
+                                 q.dtype.itemsize)
+    if limit is None:
+        return _flash_fwd(q, k, v, scale, causal, interpret=interpret)
+    return _resident_flash_fwd(q, k, v, scale, causal, vmem_limit=limit,
+                               interpret=interpret)
+
+
+def _stream_flash_bwd(q, k, v, o, lse, do, scale, causal, interpret):
+    limit = _resident_vmem_limit(q.shape[1], k.shape[1], q.shape[2],
+                                 q.dtype.itemsize)
+    if limit is None:
+        return _flash_bwd(q, k, v, o, lse, do, scale, causal,
+                          interpret=interpret)
+    return _resident_flash_bwd(q, k, v, o, lse, do, scale, causal,
+                               vmem_limit=limit, interpret=interpret)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash(q, k, v, scale, causal):
+    """(BH, T, d) attention of the small, mid and xla modes: residuals
+    are the inputs alone."""
     mode, interpret = _pallas_mode(q.shape[1], k.shape[1], causal)
     if mode == "small":
         return _small_flash_fwd(q, k, v, scale, causal,
@@ -974,9 +1328,6 @@ def _flash(q, k, v, scale, causal):
     if mode == "mid":
         return _mid_flash_fwd(q, k, v, scale, causal,
                               interpret=interpret)
-    if mode == "stream":
-        out, _ = _flash_fwd(q, k, v, scale, causal, interpret=interpret)
-        return out
     return _xla_attention(q, k, v, scale, causal).astype(q.dtype)
 
 
@@ -987,19 +1338,16 @@ def _flash_vjp_fwd(q, k, v, scale, causal):
         # the (cheap) qkv projection, never by re-running the kernel
         out = _small_flash_fwd(q, k, v, scale, causal,
                                interpret=interpret)
-        return out, (q, k, v, None, None)
+        return out, (q, k, v)
     if mode == "mid":
         out = _mid_flash_fwd(q, k, v, scale, causal, interpret=interpret)
-        return out, (q, k, v, None, None)
-    if mode == "stream":
-        out, lse = _flash_fwd(q, k, v, scale, causal, interpret=interpret)
-        return out, (q, k, v, out, lse)
+        return out, (q, k, v)
     return _xla_attention(q, k, v, scale, causal).astype(q.dtype), \
-        (q, k, v, None, None)
+        (q, k, v)
 
 
 def _flash_vjp_bwd(scale, causal, res, g):
-    q, k, v, o, lse = res
+    q, k, v = res
     mode, interpret = _pallas_mode(q.shape[1], k.shape[1], causal)
     if mode == "small":
         if k.shape[1] > 512:
@@ -1014,15 +1362,67 @@ def _flash_vjp_bwd(scale, causal, res, g):
     if mode == "mid":
         return _tiled_flash_bwd(q, k, v, g, scale, causal,
                                 interpret=interpret)
-    if mode == "stream" and lse is not None:
-        return _flash_bwd(q, k, v, o, lse, g, scale, causal,
-                          interpret=interpret)
     _, vjp = jax.vjp(lambda q, k, v: _xla_attention(q, k, v, scale, causal)
                      .astype(q.dtype), q, k, v)
     return vjp(g)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+def _fold(x):
+    """(B, T, H, d) -> (B*H, T, d)."""
+    b, t, h, d = x.shape
+    return jnp.swapaxes(x, 1, 2).reshape(b * h, t, d)
+
+
+def _unfold(x, b: int):
+    """(B*H, T, d) -> (B, T, H, d)."""
+    bh, t, d = x.shape
+    return jnp.swapaxes(x.reshape(b, bh // b, t, d), 1, 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_stream(q, k, v, scale, causal):
+    """(B, T, H, d) attention of the stream mode.  Its backward needs
+    the forward's own ``out`` and ``lse``; the vjp is cut on the
+    caller's layout so that the ``out`` it keeps is the array the caller
+    holds (a model that saves its attention output saves these bytes
+    once), and both carry names a remat policy can list — q, k and v
+    rebuild from the (cheap) projection, out and lse only by running
+    the kernel again."""
+    _, interpret = _pallas_mode(q.shape[1], k.shape[1], causal)
+    out, _ = _stream_flash_fwd(_fold(q), _fold(k), _fold(v), scale, causal,
+                               interpret)
+    return _unfold(out, q.shape[0])
+
+
+def _flash_stream_vjp_fwd(q, k, v, scale, causal):
+    _, interpret = _pallas_mode(q.shape[1], k.shape[1], causal)
+    out, lse = _stream_flash_fwd(_fold(q), _fold(k), _fold(v), scale,
+                                 causal, interpret)
+    # lse is kept as (BH, T): a trailing axis of 1 is padded to 128 lanes
+    # in HBM (0.5 GB at BH=128, T=8192 where this holds 4 MB).  The
+    # barrier ties the compact copy to out, so that it is made before
+    # anything reads out and the padded array dies here, not at the
+    # backward
+    out, lse = lax.optimization_barrier(
+        (_unfold(out, q.shape[0]), lse[..., 0]))
+    out = checkpoint_name(out, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
+    return out, (q, k, v, out, lse)
+
+
+def _flash_stream_vjp_bwd(scale, causal, res, g):
+    q, k, v, out, lse = res
+    _, interpret = _pallas_mode(q.shape[1], k.shape[1], causal)
+    grads = _stream_flash_bwd(
+        _fold(q), _fold(k), _fold(v), _fold(out), lse[..., None], _fold(g),
+        scale, causal, interpret)
+    return tuple(_unfold(x, q.shape[0]) for x in grads)
+
+
+_flash_stream.defvjp(_flash_stream_vjp_fwd, _flash_stream_vjp_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = False, scale=None,
@@ -1045,15 +1445,14 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
     mode, _ = _pallas_mode(T, Tk, causal)
 
     def local(q, k, v):
-        b, _, h, _ = q.shape
-
-        def fold(x):
-            return jnp.swapaxes(x, 1, 2).reshape(b * h, x.shape[1], D)
-
         note(f"flash_attention.{mode}" if mode != "xla"
              else "flash_attention", mode != "xla")
-        out = _flash(fold(q), fold(k), fold(v), s, causal)
-        return jnp.swapaxes(out.reshape(b, h, T, D), 1, 2)
+        if mode == "stream":
+            if _resident_vmem_limit(T, Tk, D, q.dtype.itemsize) is not None:
+                note("flash_attention.stream_resident", True)
+            return _flash_stream(q, k, v, s, causal)
+        out = _flash(_fold(q), _fold(k), _fold(v), s, causal)
+        return _unfold(out, q.shape[0])
 
     if mode == "xla":
         return local(q, k, v)          # XLA math: GSPMD partitions it

@@ -373,17 +373,212 @@ class TestSoftmaxXentHead:
             assert not np.asarray(dl[:, V:]).any()
 
 
-def test_lse_matches_logsumexp(force_pallas):
+def _stream_forwards():
+    """The stream regime's two forwards, at blocks small enough for a
+    256-row call to take several of each."""
+    return {
+        "grid": lambda q, k, v, s, c: fa._flash_fwd(
+            q, k, v, s, c, block_q=128, block_k=128, interpret=True),
+        "resident": lambda q, k, v, s, c: fa._resident_flash_fwd(
+            q, k, v, s, c, block_q=128, chunk=128, interpret=True),
+    }
+
+
+@pytest.mark.parametrize("form", ["grid", "resident"])
+def test_lse_matches_logsumexp(force_pallas, form):
     rs = np.random.RandomState(2)
     BH, T, D = 2, 256, 32
     q = jnp.asarray(rs.rand(BH, T, D), jnp.float32)
     k = jnp.asarray(rs.rand(BH, T, D), jnp.float32)
     v = jnp.asarray(rs.rand(BH, T, D), jnp.float32)
     scale = 1.0 / np.sqrt(D)
-    _, lse = fa._flash_fwd(q, k, v, scale, False, interpret=True)
+    _, lse = _stream_forwards()[form](q, k, v, scale, False)
     s = jnp.einsum("bqd,bkd->bqk", q, k) * scale
     ref = jax.scipy.special.logsumexp(s, axis=-1)[..., None]
     np.testing.assert_allclose(np.asarray(lse), np.asarray(ref), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the stream regime's kernels, called directly: the resident pair (K/V
+# rows in VMEM, a loop over the live key chunks, one fused backward) and
+# the grid-streamed pair with its clamped index maps
+# ---------------------------------------------------------------------------
+def _stream_cases():
+    cases = []
+    # every block shape x causal x decode offset, at the LFM2 head size:
+    # block_q / chunk puts 1, 2 and 4 chunks on the diagonal
+    for form, bq, ck in (("resident", 128, 128), ("resident", 256, 128),
+                         ("resident", 512, 128), ("resident", 128, 256),
+                         ("grid", 128, 128), ("grid", 256, 128)):
+        for causal in (True, False):
+            for tq, tk in ((512, 512), (256, 512)):
+                if bq <= tq:
+                    cases.append((form, bq, ck, causal, tq, tk, 64,
+                                  "float32"))
+    # head size and dtype, where the mask works hardest
+    for d, dtype in ((128, "float32"), (64, "bfloat16"), (128, "bfloat16")):
+        for causal in (True, False):
+            for tq, tk in ((256, 256), (128, 384)):
+                cases.append(("resident", 128, 128, causal, tq, tk, d,
+                              dtype))
+    return cases
+
+
+@pytest.mark.parametrize("form,bq,ck,causal,tq,tk,d,dtype", _stream_cases())
+def test_stream_kernels_vs_xla(form, bq, ck, causal, tq, tk, d, dtype):
+    """out, lse, dq, dk, dv of each stream-regime pair against the XLA
+    math and its ``jax.vjp``."""
+    rs = np.random.RandomState(3)
+    dt = jnp.dtype(dtype)
+    q, g = (jnp.asarray(rs.randn(1, tq, d), dt) for _ in range(2))
+    k, v = (jnp.asarray(rs.randn(1, tk, d), dt) for _ in range(2))
+    scale = 1.0 / np.sqrt(d)
+    if form == "resident":
+        out, lse = fa._resident_flash_fwd(q, k, v, scale, causal,
+                                          block_q=bq, chunk=ck,
+                                          interpret=True)
+        grads = fa._resident_flash_bwd(q, k, v, out, lse, g, scale, causal,
+                                       block_q=bq, chunk=ck, interpret=True)
+    else:
+        out, lse = fa._flash_fwd(q, k, v, scale, causal, block_q=bq,
+                                 block_k=ck, interpret=True)
+        grads = fa._flash_bwd(q, k, v, out, lse, g, scale, causal,
+                              block_q=bq, block_k=ck, interpret=True)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    ref, vjp = jax.vjp(
+        lambda a, b, c: fa._xla_attention(a, b, c, scale, causal), *f32)
+    s = jnp.einsum("bqd,bkd->bqk", f32[0], f32[1]) * scale
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq), s,
+                      -jnp.inf)
+    want_lse = jax.scipy.special.logsumexp(s, axis=-1)[..., None]
+    tol = 3e-5 if dt == jnp.float32 else 4e-2
+    assert out.dtype == dt and lse.dtype == jnp.float32
+    assert lse.shape == (1, tq, 1)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               atol=3e-5 if dt == jnp.float32 else 2e-2)
+    for name, got, want in zip(("out", "dq", "dk", "dv"), (out, *grads),
+                               (ref, *vjp(g.astype(jnp.float32)))):
+        assert got.dtype == dt, name
+        np.testing.assert_allclose(
+            np.asarray(got.astype(jnp.float32)), np.asarray(want),
+            atol=tol, rtol=tol, err_msg=name)
+
+
+def test_live_chunks_against_the_mask():
+    """The loop bounds of the resident kernels for every q block of a
+    small grid of (block_q, chunk, offset): chunks below ``n_full`` hold
+    no masked score, chunks from ``n_live`` on hold no live one, and
+    every chunk between is crossed by the diagonal."""
+    checked = 0
+    for block_q in (1, 2, 3, 4, 8):
+        for chunk in (1, 2, 3, 4, 8):
+            for nq in (1, 2, 5):
+                for offset in (0, 1, 3, 8, 9):
+                    seq_q = nq * block_q
+                    seq_k = seq_q + offset
+                    if seq_k % chunk:
+                        continue
+                    nk = seq_k // chunk
+                    mask = np.tril(np.ones((seq_q, seq_k), bool), k=offset)
+                    for qi in range(nq):
+                        n_full, n_live = (int(n) for n in fa._live_chunks(
+                            qi, block_q, chunk, offset, nk))
+                        rows = mask[qi * block_q:(qi + 1) * block_q]
+                        tiles = [rows[:, j * chunk:(j + 1) * chunk]
+                                 for j in range(nk)]
+                        assert 0 <= n_full <= n_live <= nk
+                        assert n_live >= 1          # column 0 is live
+                        assert all(t.all() for t in tiles[:n_full])
+                        assert not any(t.any() for t in tiles[n_live:])
+                        assert all(t.any() and not t.all()
+                                   for t in tiles[n_full:n_live])
+                        checked += 1
+    assert checked > 300
+    assert fa._live_chunks(3, 4, 2, 0, 7, causal=False) == (7, 7)
+
+
+@pytest.mark.parametrize("offset", [0, 256])
+def test_dead_grid_steps_name_a_live_block(offset):
+    """The grid-streamed kernels' index maps: a step the causal mask
+    leaves dead names the block of the nearest live step (so nothing is
+    fetched for it), a live step names its own."""
+    block_q, block_k, nq = 256, 128, 4
+    nk = (nq * block_q + offset) // block_k
+    k_map = fa._clamped_k_map(block_q, block_k, offset, nk, True)
+    q_map = fa._clamped_q_map(block_q, block_k, offset, True)
+
+    def live(i, j):
+        return (i + 1) * block_q - 1 + offset >= j * block_k
+
+    for i in range(nq):
+        for j in range(nk):
+            kj = int(k_map(0, i, j)[1])
+            qi = int(q_map(0, j, i)[1])
+            assert live(i, kj) and live(qi, j)
+            if live(i, j):
+                assert (kj, qi) == (j, i)
+            else:
+                assert kj == max(jj for jj in range(nk) if live(i, jj))
+                assert qi == min(ii for ii in range(nq) if live(ii, j))
+    plain = fa._clamped_k_map(block_q, block_k, offset, nk, False)
+    assert plain(0, 0, nk - 1) == (0, nk - 1, 0)
+
+
+def test_stream_mode_through_the_public_entry(force_pallas, monkeypatch):
+    """``flash_attention`` in the stream mode (the thresholds lowered so
+    that 256 rows select it): values and gradients against the XLA math,
+    the selection counted under both names, and a remat policy that
+    lists ``RESIDUAL_NAMES`` keeps the forward kernel out of the
+    backward pass."""
+    from paddle_tpu.ops import pallas
+    monkeypatch.setattr(fa, "SMALL_T_MAX", 0)
+    monkeypatch.setattr(fa, "MID_T_MAX", 0)
+    rs = np.random.RandomState(4)
+    q, k, v, g = (jnp.asarray(rs.randn(1, 256, 2, 64), jnp.float32)
+                  for _ in range(4))
+    before = pallas.selections()
+
+    def attend(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True)
+
+    out, vjp = jax.vjp(attend, q, k, v)
+    ref, ref_vjp = jax.vjp(lambda a, b, c: _ref_attention(a, b, c, True),
+                           q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    for got, want in zip(vjp(g), ref_vjp(g)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=5e-5)
+    after = pallas.selections()
+    for name in ("flash_attention.stream.interpret",
+                 "flash_attention.stream_resident.interpret"):
+        assert after.get(name, 0) > before.get(name, 0), name
+
+    def kernels_in_grad(policy):
+        loss = jax.checkpoint(lambda q: jnp.sum(attend(q, k, v) * g),
+                              policy=policy)
+        return str(jax.make_jaxpr(jax.grad(loss))(q)).count("pallas_call")
+
+    policies = jax.checkpoint_policies
+    # forward + fused backward; with nothing kept, the forward again
+    assert kernels_in_grad(
+        policies.save_only_these_names(*fa.RESIDUAL_NAMES)) == 2
+    assert kernels_in_grad(policies.nothing_saveable) == 3
+
+
+def test_resident_pair_is_taken_while_its_vmem_fits():
+    """Selection inside the stream mode is by (Tk, d, itemsize) alone:
+    the resident pair asks for its budget and more, and hands very long
+    rows to the grid-streamed kernels."""
+    need = fa._resident_vmem_bytes(8192, 64, 2, 512, 512)
+    limit = fa._resident_vmem_limit(8192, 8192, 64, 2)
+    assert 24 << 20 < need < limit <= 0.75 * fa._vmem_capacity()
+    # head size 128 fills the lanes head size 64 pads
+    assert fa._resident_vmem_limit(8192, 8192, 128, 2) == limit
+    assert fa._resident_vmem_limit(65536, 65536, 128, 2) is None
+    assert fa._resident_blocks(8192, 8192, backward=False) == (1024, 1024)
+    assert fa._resident_blocks(8192, 8192, backward=True) == (512, 512)
+    assert fa._resident_blocks(4224, 4608, backward=False) == (128, 512)
 
 
 # ---------------------------------------------------------------------------
