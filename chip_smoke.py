@@ -163,15 +163,14 @@ def _rand(rs, *shape, dtype="bfloat16"):
 
 def kernels_attention(B, T, regime, heads, head_dim, impl):
     """flash attention forward and backward at one shape, through the
-    batch-first packed entry and through the stacked entry the GPT block
-    calls; the regime selected is part of the check."""
+    stacked entry the GPT block calls; the regime selected is part of
+    the check."""
     import functools
     import jax
     import jax.numpy as jnp
     import numpy as np
     from paddle_tpu.ops import pallas
-    from paddle_tpu.ops.pallas.flash_attention import (
-        flash_attention_qkv, flash_attention_stacked)
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_stacked
     rs = np.random.RandomState(T)
     qkv = _rand(rs, B, T, 3 * heads * head_dim)
     g = _rand(rs, B, T, heads * head_dim)
@@ -185,24 +184,20 @@ def kernels_attention(B, T, regime, heads, head_dim, impl):
             jnp.moveaxis(a.reshape(B, T, 3, -1), 2, 0),
             num_heads=heads, causal=True)
 
-    for entry, fn in (("qkv", functools.partial(
-            flash_attention_qkv, num_heads=heads, causal=True)),
-            ("stacked", stacked)):
-        before = pallas.selections().get(key, 0)
-        out, vjp = jax.vjp(jax.jit(fn), qkv)
-        (dqkv,) = vjp(g)
-        check(pallas.selections().get(key, 0) > before,
-              f"T={T} {entry}: expected selection {key}, got "
-              f"{pallas.selections()}")
-        check(out.dtype == qkv.dtype and dqkv.dtype == qkv.dtype,
-              f"T={T} {entry}: kernel left {qkv.dtype}")
-        e_out, e_d = _rel_err(out, ref), _rel_err(dqkv, ref_d)
-        check(e_out < 2e-2 and e_d < 5e-2,
-              f"flash attention T={T} ({regime}, {entry}) off the "
-              f"reference: out {e_out:.2e} dqkv {e_d:.2e}")
-        say("kernels", f"flash_attention_{entry} {regime} B={B} T={T} "
-            f"H={heads} d={head_dim} bf16 causal fwd+bwd ok "
-            f"(err out {e_out:.1e}, dqkv {e_d:.1e})")
+    before = pallas.selections().get(key, 0)
+    out, vjp = jax.vjp(jax.jit(stacked), qkv)
+    (dqkv,) = vjp(g)
+    check(pallas.selections().get(key, 0) > before,
+          f"T={T}: expected selection {key}, got {pallas.selections()}")
+    check(out.dtype == qkv.dtype and dqkv.dtype == qkv.dtype,
+          f"T={T}: kernel left {qkv.dtype}")
+    e_out, e_d = _rel_err(out, ref), _rel_err(dqkv, ref_d)
+    check(e_out < 2e-2 and e_d < 5e-2,
+          f"flash attention T={T} ({regime}) off the reference: "
+          f"out {e_out:.2e} dqkv {e_d:.2e}")
+    say("kernels", f"flash_attention_stacked {regime} B={B} T={T} "
+        f"H={heads} d={head_dim} bf16 causal fwd+bwd ok "
+        f"(err out {e_out:.1e}, dqkv {e_d:.1e})")
 
 
 def kernels_xent(N, D, V, impl):

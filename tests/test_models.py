@@ -11,7 +11,7 @@ from paddle_tpu.models import GPT, GPTConfig
 from paddle_tpu.models.gpt_spmd import (build_spmd_train_step,
                                         init_gpt_params,
                                         gpt_param_shardings)
-from paddle_tpu.ops.pallas.flash_attention import (_flash_fwd,
+from paddle_tpu.ops.pallas.flash_attention import (_Plan, _stream_flash_fwd,
                                                    _xla_attention)
 
 
@@ -25,22 +25,21 @@ def test_flash_kernel_matches_reference():
     q, k, v = (jnp.asarray(rng.randn(BH, T, D).astype(np.float32))
                for _ in range(3))
     s = 1.0 / np.sqrt(D)
+    plan = _Plan("stream", interpret=True, fwd=(128, 128, 1))
     for causal in (False, True):
-        out, _ = _flash_fwd(q, k, v, s, causal, block_q=128,
-                            block_k=128, interpret=True)
+        out, _ = _stream_flash_fwd(q, k, v, s, causal, plan)
         ref = _xla_attention(q, k, v, s, causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
     # Tq != Tk causal: bottom-right alignment must match the XLA math
     q2 = q[:, :128]
-    out, _ = _flash_fwd(q2, k, v, s, True, block_q=128, block_k=128,
-                        interpret=True)
+    out, _ = _stream_flash_fwd(q2, k, v, s, True, plan)
     ref = _xla_attention(q2, k, v, s, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
-    # T=384: divisible by 128 but not by the default 256 block
-    out, _ = _flash_fwd(q[:, :384], k[:, :384], v[:, :384], s, True,
-                        interpret=True)
+    # T=384: three blocks of 128
+    out, _ = _stream_flash_fwd(q[:, :384], k[:, :384], v[:, :384], s, True,
+                               plan)
     ref = _xla_attention(q[:, :384], k[:, :384], v[:, :384], s, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)

@@ -1,12 +1,13 @@
 """Pallas kernel tests — run the real kernels in interpret mode on CPU.
 
-The `_pallas_mode` gate normally routes CPU to the XLA fallback; setting
+`_plan` normally routes CPU to the XLA fallback; setting
 ``PADDLE_PALLAS_FORCE=1`` forces the pallas path with ``interpret=True`` so
 the forward (lse-emitting) kernel and both backward kernels
 (`_bwd_dq_kernel`, `_bwd_dkv_kernel`) are exercised by CI, compared against
 the XLA reference math (reference parity net: the same numpy-oracle
 posture as OpTest, ``tests/unittests/op_test.py:277``).
 """
+import functools
 import importlib
 import os
 
@@ -91,21 +92,132 @@ def test_flash_under_jit(force_pallas):
 def test_causal_cross_attention_gated_off(monkeypatch):
     # causal with seq_q > seq_k degenerates (fully-masked rows) — must
     # stay on the XLA path regardless of the force flag
-    mode, _ = fa._pallas_mode(384, 128, True)
-    assert mode == "xla"
-    mode, _ = fa._pallas_mode(128, 384, True)  # kv-cache decode shape: ok
-    if jax.default_backend() == "cpu":
-        assert mode == "xla"
-    else:
-        assert mode == "small"
-    # regime split: short sequences take the full-K-resident kernels,
-    # mid sequences the q-block-tiled full-K kernels, and anything past
-    # MID_T_MAX the online-softmax streaming kernels
+    assert fa._plan("folded", 1, 384, 128, 2, 64, 4, True).name == "xla"
+    plan = fa._plan("folded", 1, 128, 384, 2, 64, 4, True)  # decode shape: ok
+    assert plan.name == ("xla" if jax.default_backend() == "cpu"
+                         else "small")
     monkeypatch.setenv("PADDLE_PALLAS_FORCE", "1")
-    assert fa._pallas_mode(512, 512, True)[0] == "small"
-    assert fa._pallas_mode(2048, 2048, True)[0] == "mid"
-    assert fa._pallas_mode(4096, 4096, True)[0] == "mid"
-    assert fa._pallas_mode(8192, 8192, True)[0] == "stream"
+    assert fa._plan("folded", 1, 384, 128, 2, 64, 4, True).name == "xla"
+    assert not fa._kernels_apply(384, 128, True)
+    assert fa._kernels_apply(384, 128, False)
+    assert not fa._kernels_apply(100, 128, False)
+
+
+# layout, B, T, Tk, heads, d, dtype, causal -> selection, forward and backward
+# (block_q, key chunk, rows a step), vmem_limit.  Regime split: short
+# sequences take the full-K-resident kernels, mid sequences the
+# q-block-tiled full-K kernels, and anything past MID_T_MAX the
+# online-softmax streaming kernels
+_PLAN_TABLE = [
+    # the two cells: gpt2-medium.train-t1024, lfm2-24b-a2b.train-t8192
+    ("stacked", 32, 1024, 1024, 16, 64, "bfloat16", True,
+     "packed_mid", (256, None, 1), (256, None, 1), None),
+    ("folded", 4, 8192, 8192, 32, 64, "bfloat16", True,
+     "stream_resident", (1024, 1024, 1), (512, 512, 1), 44564480),
+    # stacked: whole rows to 512, f32 halves the q block and past 1024
+    # takes an eighth, 4 heads of 32 fill a column block as 2 of 64 do
+    ("stacked", 8, 512, 512, 12, 64, "bfloat16", True,
+     "packed_small", (512, None, 4), (None, None, 1), None),
+    ("stacked", 8, 256, 256, 12, 64, "float32", True,
+     "packed_small", (128, None, 4), (None, None, 2), None),
+    ("stacked", 6, 128, 128, 2, 128, "float32", True,
+     "packed_small", (128, None, 2), (None, None, 2), None),
+    ("stacked", 2, 1024, 1024, 12, 64, "float32", True,
+     "packed_mid", (128, None, 1), (128, None, 1), None),
+    ("stacked", 2, 2048, 2048, 12, 64, "float32", True,
+     "packed_mid", (32, None, 1), (32, None, 1), None),
+    ("stacked", 2, 2048, 2048, 8, 32, "bfloat16", True,
+     "packed_mid", (256, None, 1), (256, None, 1), None),
+    ("stacked", 1, 640, 640, 4, 64, "float32", True,
+     "packed_mid", (128, None, 1), (128, None, 1), None),
+    # stacked shapes the stacked kernels do not take fall to the folded
+    # plan: T = 4096, a head size that fills no column block, a head
+    # count that leaves one half full
+    ("stacked", 1, 4096, 4096, 12, 64, "bfloat16", True,
+     "mid", (256, None, 1), (64, None, 1), None),
+    ("stacked", 1, 128, 128, 2, 16, "float32", True,
+     "small", (128, None, 2), (None, None, 2), None),
+    ("stacked", 2, 512, 512, 3, 64, "bfloat16", True,
+     "small", (512, None, 2), (None, None, 2), None),
+    # folded: small (whole-row backward to Tk = 512, tiled beyond), mid
+    ("folded", 128, 512, 512, 12, 64, "bfloat16", True,
+     "small", (512, None, 8), (None, None, 2), None),
+    ("folded", 2, 256, 512, 2, 64, "float32", True,
+     "small", (128, None, 4), (None, None, 2), None),
+    ("folded", 2, 1024, 1024, 2, 64, "bfloat16", True,
+     "small", (512, None, 4), (512, None, 1), None),
+    ("folded", 2, 1152, 1152, 2, 64, "float32", True,
+     "mid", (128, None, 1), (128, None, 1), None),
+    ("folded", 1, 2048, 2048, 2, 64, "bfloat16", True,
+     "mid", (256, None, 2), (256, None, 1), None),
+    ("folded", 1, 4096, 4096, 12, 64, "bfloat16", True,
+     "mid", (256, None, 1), (64, None, 1), None),
+    ("folded", 1, 2048, 128, 16, 64, "bfloat16", False,
+     "mid", (512, None, 16), (512, None, 1), None),
+    # XLA math: causal with more queries than keys, a length that is no
+    # multiple of 128
+    ("folded", 1, 2048, 128, 16, 64, "bfloat16", True,
+     "xla", (None, None, 1), (None, None, 1), None),
+    ("stacked", 1, 100, 100, 2, 64, "float32", False,
+     "xla", (None, None, 1), (None, None, 1), None),
+    # stream: head size 128 fills the lanes head size 64 pads; lengths
+    # that no long block divides; rows whose budget no chip holds
+    ("folded", 1, 8192, 8192, 4, 128, "bfloat16", True,
+     "stream_resident", (1024, 1024, 1), (512, 512, 1), 44564480),
+    ("folded", 1, 4224, 4608, 4, 64, "bfloat16", True,
+     "stream_resident", (128, 512, 1), (128, 512, 1), 20971520),
+    ("folded", 1, 65536, 65536, 1, 128, "bfloat16", True,
+     "stream", (256, 512, 1), (256, 256, 1), None),
+    ("folded", 1, 8320, 8320, 1, 128, "float32", True,
+     "stream", (128, 128, 1), (128, 128, 1), None),
+]
+
+
+@pytest.mark.parametrize(
+    "layout,B,T,Tk,heads,d,dtype,causal,name,fwd,bwd,vmem_limit", _PLAN_TABLE)
+def test_plan_table(force_pallas, layout, B, T, Tk, heads, d, dtype, causal,
+                    name, fwd, bwd, vmem_limit):
+    plan = fa._plan(layout, B, T, Tk, heads, d, jnp.dtype(dtype).itemsize,
+                    causal)
+    interpret = name != "xla" and jax.default_backend() != "tpu"
+    assert plan == fa._Plan(name, interpret, fwd, bwd, vmem_limit)
+    hash(plan)                  # a custom_vjp's static argument
+
+
+def _traced_kernels(fn, *args):
+    """Number of pallas calls in fn's forward and vjp, traced only."""
+    def fwd_bwd(*args):
+        out, vjp = jax.vjp(fn, *args)
+        return vjp(out)
+    return str(jax.make_jaxpr(fwd_bwd)(*args)).count("pallas_call")
+
+
+@pytest.mark.parametrize("regime,layout,T,kernels", [
+    ("packed_small", "stacked", 256, 2), ("packed_mid", "stacked", 640, 2),
+    ("small", "folded", 256, 2), ("mid", "folded", 1152, 2),
+    ("stream_resident", "folded", 8192, 2), ("stream", "folded", 8192, 3),
+    ("xla", "folded", 100, 0)])
+def test_backward_runs_under_the_forwards_plan(force_pallas, monkeypatch,
+                                               regime, layout, T, kernels):
+    """One ``_plan`` a public call, none from a vjp rule: the backward
+    reads the record its forward ran under."""
+    plans = []
+    make = fa._plan
+    monkeypatch.setattr(fa, "_plan",
+                        lambda *a: plans.append(make(*a)) or plans[-1])
+    if regime == "stream":      # a chip too small for the resident pair
+        monkeypatch.setattr(fa, "_vmem_capacity", lambda: 16 << 20)
+    S = jax.ShapeDtypeStruct
+    if layout == "stacked":
+        n = _traced_kernels(
+            lambda x: fa.flash_attention_stacked(x, 2, causal=True),
+            S((3, 1, T, 128), jnp.float32))
+    else:
+        n = _traced_kernels(
+            lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+            *[S((1, T, 2, 64), jnp.bfloat16)] * 3)
+    assert [p.name for p in plans] == [regime]
+    assert n == kernels
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -138,26 +250,34 @@ def test_flash_bf16_no_fp32_fallback(force_pallas, causal):
                                    np.asarray(r), atol=5e-2)
 
 
+def _packed(qkv, H, causal):
+    """The stacked entry on a batch-first (B, T, 3*H*d) projection output."""
+    B, T, F = qkv.shape
+    return fa.flash_attention_stacked(
+        jnp.moveaxis(qkv.reshape(B, T, 3, F // 3), 2, 0), H, causal=causal)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("H,D", [
     (4, 64), (2, 128),
     # the P=4 packing regime (8 heads of d=32) is the slowest interpret
     # run of the three — slow tier keeps it gating without the tier-1 cost
     pytest.param(8, 32, marks=pytest.mark.slow)])
-def test_flash_attention_qkv_packed(force_pallas, causal, H, D):
-    # packed projection-output entry: same numbers as split + generic,
+def test_flash_attention_packed_layout(force_pallas, causal, H, D):
+    # a (B, T, 3, H*d) projection output with its q/k/v axis moved to
+    # the front: same numbers as split + generic,
     # across the head-packing regimes (P = 128//d heads per column
     # block: 2 at d=64, 4 at d=32, 1 at d=128)
     rs = np.random.RandomState(3)
     B, T = 2, 256
     qkv = jnp.asarray(rs.rand(B, T, 3 * H * D), jnp.float32)
-    out = fa.flash_attention_qkv(qkv, H, causal=causal)
+    out = _packed(qkv, H, causal)
     q, k, v = jnp.split(qkv.reshape(B, T, 3 * H, D), 3, axis=2)
     ref = _ref_attention(q, k, v, causal).reshape(B, T, H * D)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
     g = jnp.asarray(rs.rand(B, T, H * D), jnp.float32)
-    dqkv = jax.vjp(lambda a: fa.flash_attention_qkv(a, H, causal=causal),
+    dqkv = jax.vjp(lambda a: _packed(a, H, causal),
                    qkv)[1](g)[0]
     ref_d = jax.vjp(
         lambda a: _ref_attention(
@@ -225,13 +345,13 @@ def test_packed_mid_qkv_t1024_gradient(force_pallas):
     rs = np.random.RandomState(11)
     B, T, H, D = 1, 1024, 2, 64
     qkv = jnp.asarray(rs.rand(B, T, 3 * H * D), jnp.float32)
-    out = fa.flash_attention_qkv(qkv, H, causal=True)
+    out = _packed(qkv, H, True)
     q, k, v = jnp.split(qkv.reshape(B, T, 3 * H, D), 3, axis=2)
     ref = _ref_attention(q, k, v, True).reshape(B, T, H * D)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5)
     g = jnp.asarray(rs.rand(B, T, H * D), jnp.float32)
-    dqkv = jax.vjp(lambda a: fa.flash_attention_qkv(a, H, causal=True),
+    dqkv = jax.vjp(lambda a: _packed(a, H, True),
                    qkv)[1](g)[0]
     ref_d = jax.vjp(
         lambda a: _ref_attention(
@@ -249,13 +369,13 @@ def test_packed_mid_qkv_more_shapes(force_pallas, T, H, D):
     rs = np.random.RandomState(13)
     B = 1
     qkv = jnp.asarray(rs.rand(B, T, 3 * H * D), jnp.float32)
-    out = fa.flash_attention_qkv(qkv, H, causal=True)
+    out = _packed(qkv, H, True)
     q, k, v = jnp.split(qkv.reshape(B, T, 3 * H, D), 3, axis=2)
     ref = _ref_attention(q, k, v, True).reshape(B, T, H * D)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5)
     g = jnp.asarray(rs.rand(B, T, H * D), jnp.float32)
-    dqkv = jax.vjp(lambda a: fa.flash_attention_qkv(a, H, causal=True),
+    dqkv = jax.vjp(lambda a: _packed(a, H, True),
                    qkv)[1](g)[0]
     ref_d = jax.vjp(
         lambda a: _ref_attention(
@@ -277,8 +397,7 @@ def test_mid_regime_t2048_gradient(force_pallas):
     k = jnp.asarray(rs.rand(B, T, H, D), jnp.float32)
     v = jnp.asarray(rs.rand(B, T, H, D), jnp.float32)
     g = jnp.asarray(rs.rand(B, T, H, D), jnp.float32)
-    mode, _ = fa._pallas_mode(T, T, True)
-    assert mode == "mid", mode
+    assert fa._plan("folded", B, T, T, H, D, 4, True).name == "mid"
     for causal in (False, True):
         out, vjp = jax.vjp(
             lambda a, b, c: fa.flash_attention(a, b, c, causal=causal),
@@ -373,15 +492,20 @@ class TestSoftmaxXentHead:
             assert not np.asarray(dl[:, V:]).any()
 
 
+def _stream_plan(form, block_q, chunk):
+    """A plan for a direct call of the stream regime's launchers, in
+    interpret mode, at blocks of the test's choosing."""
+    blocks = (block_q, chunk, 1)
+    return fa._Plan("stream_resident" if form == "resident" else "stream",
+                    True, fwd=blocks, bwd=blocks)
+
+
 def _stream_forwards():
     """The stream regime's two forwards, at blocks small enough for a
     256-row call to take several of each."""
-    return {
-        "grid": lambda q, k, v, s, c: fa._flash_fwd(
-            q, k, v, s, c, block_q=128, block_k=128, interpret=True),
-        "resident": lambda q, k, v, s, c: fa._resident_flash_fwd(
-            q, k, v, s, c, block_q=128, chunk=128, interpret=True),
-    }
+    return {form: functools.partial(fa._stream_flash_fwd,
+                                    plan=_stream_plan(form, 128, 128))
+            for form in ("grid", "resident")}
 
 
 @pytest.mark.parametrize("form", ["grid", "resident"])
@@ -433,17 +557,10 @@ def test_stream_kernels_vs_xla(form, bq, ck, causal, tq, tk, d, dtype):
     q, g = (jnp.asarray(rs.randn(1, tq, d), dt) for _ in range(2))
     k, v = (jnp.asarray(rs.randn(1, tk, d), dt) for _ in range(2))
     scale = 1.0 / np.sqrt(d)
-    if form == "resident":
-        out, lse = fa._resident_flash_fwd(q, k, v, scale, causal,
-                                          block_q=bq, chunk=ck,
-                                          interpret=True)
-        grads = fa._resident_flash_bwd(q, k, v, out, lse, g, scale, causal,
-                                       block_q=bq, chunk=ck, interpret=True)
-    else:
-        out, lse = fa._flash_fwd(q, k, v, scale, causal, block_q=bq,
-                                 block_k=ck, interpret=True)
-        grads = fa._flash_bwd(q, k, v, out, lse, g, scale, causal,
-                              block_q=bq, block_k=ck, interpret=True)
+    plan = _stream_plan(form, bq, ck)
+    bwd = fa._resident_flash_bwd if form == "resident" else fa._flash_bwd
+    out, lse = fa._stream_flash_fwd(q, k, v, scale, causal, plan)
+    grads = bwd(q, k, v, out, lse, g, scale, causal, plan)
     f32 = [x.astype(jnp.float32) for x in (q, k, v)]
     ref, vjp = jax.vjp(
         lambda a, b, c: fa._xla_attention(a, b, c, scale, causal), *f32)
@@ -566,19 +683,20 @@ def test_stream_mode_through_the_public_entry(force_pallas, monkeypatch):
     assert kernels_in_grad(policies.nothing_saveable) == 3
 
 
-def test_resident_pair_is_taken_while_its_vmem_fits():
-    """Selection inside the stream mode is by (Tk, d, itemsize) alone:
-    the resident pair asks for its budget and more, and hands very long
-    rows to the grid-streamed kernels."""
+def test_resident_pair_is_taken_while_its_vmem_fits(force_pallas,
+                                                    monkeypatch):
+    """Selection inside the stream regime is by (Tk, d, itemsize) and the
+    chip's VMEM alone: the resident pair asks for its budget and a
+    quarter more, and hands rows that do not fit to the grid-streamed
+    kernels (``test_plan_table`` holds the shapes)."""
     need = fa._resident_vmem_bytes(8192, 64, 2, 512, 512)
-    limit = fa._resident_vmem_limit(8192, 8192, 64, 2)
-    assert 24 << 20 < need < limit <= 0.75 * fa._vmem_capacity()
-    # head size 128 fills the lanes head size 64 pads
-    assert fa._resident_vmem_limit(8192, 8192, 128, 2) == limit
-    assert fa._resident_vmem_limit(65536, 65536, 128, 2) is None
-    assert fa._resident_blocks(8192, 8192, backward=False) == (1024, 1024)
-    assert fa._resident_blocks(8192, 8192, backward=True) == (512, 512)
-    assert fa._resident_blocks(4224, 4608, backward=False) == (128, 512)
+    plan = fa._plan("folded", 4, 8192, 8192, 32, 64, 2, True)
+    assert plan.vmem_limit == need + need // 4
+    assert 24 << 20 < need < plan.vmem_limit <= 0.75 * fa._vmem_capacity()
+    # the same shape on a chip with half the VMEM
+    monkeypatch.setattr(fa, "_vmem_capacity", lambda: 32 << 20)
+    small_chip = fa._plan("folded", 4, 8192, 8192, 32, 64, 2, True)
+    assert (small_chip.name, small_chip.vmem_limit) == ("stream", None)
 
 
 # ---------------------------------------------------------------------------

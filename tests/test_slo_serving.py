@@ -20,7 +20,6 @@ Acceptance surface:
   clamped to [1, 30] s, over HTTP too.
 """
 import json
-import threading
 import time
 
 import numpy as np
@@ -64,6 +63,19 @@ def paged_engine(net, name, **kw):
 
 # -- priority classes --------------------------------------------------
 
+def _admissions(eng, names):
+    """The order in which the scheduler admits requests to a decode slot,
+    as names by the first prompt token — read where the engine notes the
+    admission, not from the order in which waiting threads wake up."""
+    order, note = [], eng._note_slot_admit
+
+    def record(slot, req):
+        order.append(names[int(req.prompt[0])])
+        note(slot, req)
+    eng._note_slot_admit = record
+    return order
+
+
 def test_priority_rank_mapping():
     assert priority_rank("interactive") == 0
     assert priority_rank("standard") == 1
@@ -82,25 +94,14 @@ def test_priority_dequeue_order(net):
     try:
         eng.pause()
         p = np.arange(1, 6, dtype=np.int32)
-        order = []
-
-        def tag(stream, name):
-            def run():
-                stream.result(timeout=60)
-                order.append(name)
-            return threading.Thread(target=run, daemon=True)
-
+        order = _admissions(eng, {1: "batch", 2: "interactive",
+                                  3: "standard"})
         sb = eng.submit(p, max_new_tokens=2, priority="batch")
         si = eng.submit(p + 1, max_new_tokens=2, priority="interactive")
         ss = eng.submit(p + 2, max_new_tokens=2)   # standard default
-        threads = [tag(s, n) for s, n in
-                   ((sb, "batch"), (si, "interactive"),
-                    (ss, "standard"))]
-        for t in threads:
-            t.start()
         eng.resume()
-        for t in threads:
-            t.join(timeout=60)
+        for stream in (sb, si, ss):
+            stream.result(timeout=60)
         assert order == ["interactive", "standard", "batch"]
     finally:
         eng.close()
@@ -117,20 +118,10 @@ def test_priority_aging_prevents_starvation(net):
         sb = eng.submit(p, max_new_tokens=2, priority="batch")
         time.sleep(0.15)                  # batch ages >= 2 classes
         si = eng.submit(p + 1, max_new_tokens=2, priority="interactive")
-        order = []
-
-        def waiter(stream, name):
-            def run():
-                stream.result(timeout=60)
-                order.append(name)
-            t = threading.Thread(target=run, daemon=True)
-            t.start()
-            return t
-
-        ts = [waiter(sb, "batch"), waiter(si, "interactive")]
+        order = _admissions(eng, {1: "batch", 2: "interactive"})
         eng.resume()
-        for t in ts:
-            t.join(timeout=60)
+        for stream in (sb, si):
+            stream.result(timeout=60)
         assert order[0] == "batch"        # aged past the fresh burst
     finally:
         eng.close()

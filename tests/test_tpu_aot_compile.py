@@ -58,30 +58,25 @@ def _on(dev):
 @pytest.mark.parametrize("B,T,regime", chip_smoke.ATTN_SHAPES)
 def test_flash_attention_regimes_compile(v5e, B, T, regime):
     from paddle_tpu.ops import pallas
-    from paddle_tpu.ops.pallas.flash_attention import (
-        flash_attention_qkv, flash_attention_stacked)
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_stacked
     H, d = chip_smoke.ATTN_HEADS, chip_smoke.ATTN_HEAD_DIM
     S = _on(v5e[0])
 
-    def fwd_bwd(entry):
-        def f(qkv, g):
-            out, vjp = jax.vjp(functools.partial(
-                entry, num_heads=H, causal=True), qkv)
-            return out, vjp(g)[0]
-        return jax.jit(f)
+    @jax.jit
+    def fwd_bwd(qkv, g):               # stacked as the GPT block hands it
+        out, vjp = jax.vjp(functools.partial(
+            flash_attention_stacked, num_heads=H, causal=True), qkv)
+        return out, vjp(g)[0]
 
     for dtype in (jnp.bfloat16, jnp.float32):
-        # batch first, and stacked as the GPT block hands it over
-        for entry, shape in ((flash_attention_qkv, (B, T, 3 * H * d)),
-                             (flash_attention_stacked, (3, B, T, H * d))):
-            before = pallas.selections().get(
-                f"flash_attention.{regime}.mosaic", 0)
-            low = fwd_bwd(entry).lower(S(shape, dtype),
-                                       S((B, T, H * d), dtype))
-            assert pallas.selections()[
-                f"flash_attention.{regime}.mosaic"] > before
-            assert _mosaic_calls(low) >= 2
-            low.compile()
+        before = pallas.selections().get(
+            f"flash_attention.{regime}.mosaic", 0)
+        low = fwd_bwd.lower(S((3, B, T, H * d), dtype),
+                            S((B, T, H * d), dtype))
+        assert pallas.selections()[
+            f"flash_attention.{regime}.mosaic"] > before
+        assert _mosaic_calls(low) >= 2
+        low.compile()
 
 
 def test_softmax_xent_compiles(v5e):
