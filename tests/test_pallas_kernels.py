@@ -9,6 +9,7 @@ posture as OpTest, ``tests/unittests/op_test.py:277``).
 """
 import functools
 import importlib
+import math
 import os
 
 import numpy as np
@@ -39,6 +40,9 @@ def _ref_attention(q, k, v, causal):
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("tq,tk", [
     (256, 256), (128, 256),
+    # more than one q block in front of an offset: the forward's q blocks
+    # of 128 end at columns 384, 512, 640 and the tiled backward's too
+    (384, 640),
     # tq >= 512 interpret-mode runs cost seconds each on one CPU core;
     # they gate in the slow tier (run_all_tests.sh --runslow)
     pytest.param(512, 512, marks=pytest.mark.slow),
@@ -104,37 +108,48 @@ def test_causal_cross_attention_gated_off(monkeypatch):
 
 
 # layout, B, T, Tk, heads, d, dtype, causal -> selection, forward and backward
-# (block_q, key chunk, rows a step), vmem_limit.  Regime split: short
+# (block_q, key columns, rows a step), vmem_limit.  Regime split: short
 # sequences take the full-K-resident kernels, mid sequences the
 # q-block-tiled full-K kernels, and anything past MID_T_MAX the
-# online-softmax streaming kernels
+# online-softmax streaming kernels.  The key columns are the stream
+# kernels' chunk or, in a causal call of a whole-row kernel with more than
+# one q block, the granule each q block's extent is rounded up to
 _PLAN_TABLE = [
     # the two cells: gpt2-medium.train-t1024, lfm2-24b-a2b.train-t8192
     ("stacked", 32, 1024, 1024, 16, 64, "bfloat16", True,
-     "packed_mid", (256, None, 1), (256, None, 1), None),
+     "packed_mid", (512, 512, 1), (512, 512, 1), None),
     ("folded", 4, 8192, 8192, 32, 64, "bfloat16", True,
      "stream_resident", (1024, 1024, 1), (512, 512, 1), 44564480),
-    # stacked: whole rows to 512, f32 halves the q block and past 1024
-    # takes an eighth, 4 heads of 32 fill a column block as 2 of 64 do
+    # stacked: whole rows to 512; bf16 takes q blocks of 512 to T = 1024
+    # and 256 past it (the largest that divides T), f32 128 and 32; 4
+    # heads of 32 fill a column block as 2 of 64 do.  One q block, or a
+    # call that is not causal, has no granule: its rows are taken whole;
+    # 64 q blocks of 32 share 8 extents
     ("stacked", 8, 512, 512, 12, 64, "bfloat16", True,
      "packed_small", (512, None, 4), (None, None, 1), None),
     ("stacked", 8, 256, 256, 12, 64, "float32", True,
-     "packed_small", (128, None, 4), (None, None, 2), None),
+     "packed_small", (128, 128, 4), (None, None, 2), None),
     ("stacked", 6, 128, 128, 2, 128, "float32", True,
      "packed_small", (128, None, 2), (None, None, 2), None),
     ("stacked", 2, 1024, 1024, 12, 64, "float32", True,
-     "packed_mid", (128, None, 1), (128, None, 1), None),
+     "packed_mid", (128, 128, 1), (128, 128, 1), None),
     ("stacked", 2, 2048, 2048, 12, 64, "float32", True,
-     "packed_mid", (32, None, 1), (32, None, 1), None),
+     "packed_mid", (32, 256, 1), (32, 256, 1), None),
     ("stacked", 2, 2048, 2048, 8, 32, "bfloat16", True,
-     "packed_mid", (256, None, 1), (256, None, 1), None),
+     "packed_mid", (256, 256, 1), (256, 256, 1), None),
     ("stacked", 1, 640, 640, 4, 64, "float32", True,
-     "packed_mid", (128, None, 1), (128, None, 1), None),
+     "packed_mid", (128, 128, 1), (128, 128, 1), None),
+    ("stacked", 1, 768, 768, 4, 64, "bfloat16", True,
+     "packed_mid", (256, 256, 1), (256, 256, 1), None),
+    ("stacked", 32, 1024, 1024, 16, 64, "bfloat16", False,
+     "packed_mid", (512, None, 1), (512, None, 1), None),
+    ("stacked", 2, 1152, 1152, 2, 64, "float32", True,
+     "packed_mid", (32, 256, 1), (32, 256, 1), None),
     # stacked shapes the stacked kernels do not take fall to the folded
     # plan: T = 4096, a head size that fills no column block, a head
     # count that leaves one half full
     ("stacked", 1, 4096, 4096, 12, 64, "bfloat16", True,
-     "mid", (256, None, 1), (64, None, 1), None),
+     "mid", (256, 512, 1), (64, 512, 1), None),
     ("stacked", 1, 128, 128, 2, 16, "float32", True,
      "small", (128, None, 2), (None, None, 2), None),
     ("stacked", 2, 512, 512, 3, 64, "bfloat16", True,
@@ -143,15 +158,15 @@ _PLAN_TABLE = [
     ("folded", 128, 512, 512, 12, 64, "bfloat16", True,
      "small", (512, None, 8), (None, None, 2), None),
     ("folded", 2, 256, 512, 2, 64, "float32", True,
-     "small", (128, None, 4), (None, None, 2), None),
+     "small", (128, 128, 4), (None, None, 2), None),
     ("folded", 2, 1024, 1024, 2, 64, "bfloat16", True,
-     "small", (512, None, 4), (512, None, 1), None),
+     "small", (512, 512, 4), (512, 512, 1), None),
     ("folded", 2, 1152, 1152, 2, 64, "float32", True,
-     "mid", (128, None, 1), (128, None, 1), None),
+     "mid", (128, 256, 1), (128, 256, 1), None),
     ("folded", 1, 2048, 2048, 2, 64, "bfloat16", True,
-     "mid", (256, None, 2), (256, None, 1), None),
+     "mid", (256, 256, 2), (256, 256, 1), None),
     ("folded", 1, 4096, 4096, 12, 64, "bfloat16", True,
-     "mid", (256, None, 1), (64, None, 1), None),
+     "mid", (256, 512, 1), (64, 512, 1), None),
     ("folded", 1, 2048, 128, 16, 64, "bfloat16", False,
      "mid", (512, None, 16), (512, None, 1), None),
     # XLA math: causal with more queries than keys, a length that is no
@@ -334,6 +349,173 @@ def test_flash_attention_stacked_falls_back_to_split(force_pallas):
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(ref.reshape(B, T, H * D)),
                                atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the causal extents of the whole-row kernels: q block qi attends over the
+# key columns up to its diagonal, rounded up to the plan's granule — one
+# kernel body a distinct extent, the mask on the tile the diagonal crosses
+# ---------------------------------------------------------------------------
+def test_causal_extents_against_the_mask():
+    """For every q block of a grid of (block_q, granule, offset): no
+    column from its extent on is seen by any of its rows, every column
+    in front of ``start`` is seen by all of them, ``start`` is whole
+    128-lane tiles, and the bodies are contiguous runs of q blocks."""
+    checked = 0
+    for block_q, nq in ((128, 5), (256, 4), (512, 2), (32, 36), (64, 64)):
+        for offset in (0, 128, 256, 640):
+            T = block_q * nq
+            Tk = T + offset
+            for granule in {fa._granule(T, Tk, block_q, True),
+                            math.lcm(block_q, 128), 2 * math.lcm(block_q,
+                                                                  128)}:
+                mask = np.tril(np.ones((T, Tk), bool), k=offset)
+                extents = fa._causal_extents(block_q, Tk, offset, granule)
+                assert [lo for lo, *_ in extents] == \
+                    [0] + [hi + 1 for _, hi, *_ in extents[:-1]]
+                assert extents[-1][1:3] == (nq - 1, Tk)
+                for lo, hi, extent, start in extents:
+                    rows = mask[lo * block_q:(hi + 1) * block_q]
+                    assert start % 128 == 0 and 0 <= start < extent <= Tk
+                    assert extent % granule == 0 or extent == Tk
+                    assert not rows[:, extent:].any()
+                    assert rows[:, :start].all()
+                    # no body is longer than its granule asks for
+                    assert rows[:, max(0, extent - granule):extent].any()
+                    checked += 1
+    assert checked > 200
+    # what the plan records keeps a kernel to 8 bodies
+    for block_q, T, Tk in ((32, 2048, 2048), (64, 4096, 4096),
+                           (128, 1152, 1152), (512, 1024, 1024),
+                           (128, 1024, 4096), (32, 1152, 2048)):
+        granule = fa._granule(T, Tk, block_q, True)
+        assert granule % block_q == 0 and granule % 128 == 0
+        assert len(fa._causal_extents(block_q, Tk, Tk - T, granule)) <= 8
+    assert fa._granule(1024, 1024, 512, False) is None      # not causal
+    assert fa._granule(512, 512, 512, True) is None         # one q block
+    assert fa._causal_extents(512, 1024, 0, 512) == \
+        [(0, 0, 512, 0), (1, 1, 1024, 512)]                 # the GPT cell's
+
+
+def _stacked_case(B, T, H, D, dtype, seed=23):
+    rs = np.random.RandomState(seed)
+    qkv = jnp.asarray(rs.rand(3, B, T, H * D), jnp.float32)
+    g = jnp.asarray(rs.rand(B, T, H * D), jnp.float32)
+
+    def ref_fn(x):
+        q, k, v = (x[i].reshape(B, T, H, D) for i in range(3))
+        return _ref_attention(q, k, v, True).reshape(B, T, H * D)
+
+    return qkv, g, ref_fn
+
+
+# T, H, D, dtype -> block_q, distinct extents: where the extent logic has
+# its edges.  640: five q blocks of 128.  1024 in bf16: the GPT cell's two
+# blocks of 512; in f32 eight of 128.  2048 in f32: 64 q blocks of 32
+# share 8 extents, so a body's mask tile spans 8 q blocks
+@pytest.mark.parametrize("T,H,D,dtype,block_q,bodies", [
+    (640, 2, 64, "float32", 128, 5), (640, 1, 128, "float32", 128, 5),
+    (1024, 2, 64, "bfloat16", 512, 2), (1024, 1, 128, "bfloat16", 512, 2),
+    (1024, 2, 64, "float32", 128, 8), (1024, 1, 128, "float32", 128, 8),
+    (2048, 2, 64, "float32", 32, 8), (2048, 1, 128, "float32", 32, 8),
+    (768, 2, 64, "bfloat16", 256, 3)])
+def test_packed_mid_causal_extents_vs_xla(force_pallas, T, H, D, dtype,
+                                          block_q, bodies):
+    """Forward and dqkv of the stacked entry against XLA math where each
+    q block stops at its own extent."""
+    dt = jnp.dtype(dtype)
+    plan = fa._plan("stacked", 1, T, T, H, D, dt.itemsize, True)
+    assert plan.name == "packed_mid" and plan.fwd[0] == block_q
+    assert len(fa._causal_extents(block_q, T, 0, plan.fwd[1])) == bodies
+    qkv, g, ref_fn = _stacked_case(1, T, H, D, dtype)
+    out, vjp = jax.vjp(
+        lambda x: fa.flash_attention_stacked(x, H, causal=True),
+        qkv.astype(dt))
+    (dqkv,) = vjp(g.astype(dt))
+    ref, ref_vjp = jax.vjp(ref_fn, qkv)
+    (ref_d,) = ref_vjp(g)
+    assert out.dtype == dt and dqkv.dtype == dt
+    fwd_tol, bwd_tol = (2e-5, 5e-5) if dt == jnp.float32 else (3e-2, 5e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               atol=fwd_tol)
+    np.testing.assert_allclose(np.asarray(dqkv, np.float32),
+                               np.asarray(ref_d), atol=bwd_tol)
+
+
+def _nt_dot_elements(jaxpr, found):
+    """Output elements of every ``a @ b.T`` in a jaxpr and the jaxprs
+    under it (a kernel's QK^T — and, in a backward, dO V^T — products),
+    by output shape."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and \
+                eqn.params["dimension_numbers"] == fa._NT:
+            shape = eqn.outvars[0].aval.shape
+            found[shape] = found.get(shape, 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _nt_dot_elements(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("causal,live", [(True, 12 / 16), (False, 1.0)])
+def test_packed_mid_multiplies_only_the_live_score_columns(force_pallas,
+                                                           causal, live):
+    """The engagement, counted from the traced kernels at the GPT cell's
+    shape: the score elements the QK^T products of the q-block bodies
+    make are 12 / 16 of ``nq * block_q * T`` (block_q 512: 512 + 1024 of
+    2 x 1024 columns; 10 / 16 at block_q 256) in a causal call, and all
+    of them where nothing is masked.  Forward: one such product a head;
+    backward: two (scores and dP)."""
+    T, H, D = 1024, 2, 64
+    S = jax.ShapeDtypeStruct
+
+    def fwd_bwd(x):
+        out, vjp = jax.vjp(
+            lambda x: fa.flash_attention_stacked(x, H, causal=causal), x)
+        return vjp(out)
+
+    calls = [e for e in jax.make_jaxpr(fwd_bwd)(
+        S((3, 1, T, H * D), jnp.bfloat16)).jaxpr.eqns
+        if e.primitive.name == "pallas_call"]
+    assert len(calls) == 2
+    plan = fa._plan("stacked", 1, T, T, H, D, 2, causal)
+    block_q = plan.fwd[0]
+    nq = T // block_q
+    for call, per_head in zip(calls, (1, 2)):
+        shapes = _nt_dot_elements(call.params["jaxpr"], {})
+        assert all(n == per_head * H for n in shapes.values()), shapes
+        assert all(rows == block_q for rows, _ in shapes)
+        # a causal body runs for one q block, the one unmasked body for all
+        runs = 1 if causal else nq
+        elements = sum(rows * cols for rows, cols in shapes) * runs
+        assert elements == live * nq * block_q * T
+    # the same count at the block the kernels had to PR 33
+    tiles = (256, fa._granule(T, T, 256, causal), 1)
+    plan = plan._replace(fwd=tiles, bwd=tiles)
+    jaxpr = jax.make_jaxpr(
+        lambda x: fa._qkv_fwd(x, H, 0.125, causal, plan))(
+        S((3, 1, T, H * D), jnp.bfloat16)).jaxpr
+    shapes = _nt_dot_elements(jaxpr, {})
+    elements = sum(r * c for r, c in shapes) * (1 if causal else 4)
+    assert elements == (10 / 16 if causal else 1.0) * 4 * 256 * T
+
+
+def test_layers_share_one_trace_of_the_stacked_kernels(force_pallas):
+    """A model's unrolled layer loop calls the stacked entry once a layer
+    with the same shapes: every layer's forward and backward call carry
+    the kernel jaxpr the first layer traced (``_traced_once``), so set-up
+    pays for two kernel bodies and not for two a layer."""
+    def layers(x):
+        for _ in range(3):
+            y, vjp = jax.vjp(
+                lambda x: fa.flash_attention_stacked(x, 2, causal=True), x)
+            x = x + vjp(y)[0]
+        return x
+
+    calls = [e for e in jax.make_jaxpr(layers)(
+        jax.ShapeDtypeStruct((3, 1, 640, 128), jnp.float32)).jaxpr.eqns
+        if e.primitive.name == "pallas_call"]
+    assert len(calls) == 6
+    assert len({id(e.params["jaxpr"]) for e in calls}) == 2
 
 
 @pytest.mark.slow
