@@ -17,6 +17,7 @@ compiler collectives.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import numpy as np
@@ -24,7 +25,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ....core.dispatch import dispatch
 from ....core.tensor import Tensor
@@ -34,6 +35,7 @@ from .... import nn
 
 __all__ = ["top1_gating", "moe_dispatch", "moe_combine", "moe_alltoall",
            "moe_alltoall_inverse", "MoELayer", "sigmoid_topk_routing",
+           "softmax_topk_routing", "routed_rows", "held_expert_shardings",
            "routed_experts"]
 
 
@@ -155,29 +157,84 @@ class MoELayer(Layer):
 
 
 # ---------------------------------------------------------------------------
-# Routed experts with no dropped assignment: sigmoid top-k routing over the
-# router's whole width, dispatch by sort and segment offsets, grouped
+# Routed experts with no dropped assignment: top-k routing over the router's
+# whole width (the caller's: a ``(z, router_w, bias) -> (idx, w)`` function,
+# two of which live here), dispatch by sort and segment offsets, grouped
 # matmuls (``lax.ragged_dot``) over the experts held here.  Functional:
-# the SPMD model blocks call it (models/lfm2_moe.py); ``MoELayer`` above
-# stays the Switch top-1 capacity layer of the Layer API.
+# the SPMD model blocks call it (models/lfm2_moe.py, models/qwen3_next.py);
+# ``MoELayer`` above stays the Switch top-1 capacity layer of the Layer API.
 # ---------------------------------------------------------------------------
+def _chosen(s, idx):
+    """The scores at the chosen experts by a one-hot product, not a
+    gather: its transpose is elementwise too (a gather's is a
+    scatter-add)."""
+    onehot = jax.nn.one_hot(idx, s.shape[-1], dtype=s.dtype)
+    return jnp.sum(s[:, None, :] * onehot, axis=-1)
+
+
+def _router_logits(z, router_w):
+    return jnp.dot(z.astype(jnp.float32), router_w.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST)
+
+
 def sigmoid_topk_routing(z, router_w, bias, top_k: int,
                          scaling: float = 1.0):
-    """Scores ``s = sigmoid(z W_g)`` in float32; the ``top_k`` experts of
-    ``s + bias`` are chosen (the bias only selects: it takes no gradient
+    """One of the routings a caller may hand ``routed_experts`` (bound
+    to its ``top_k`` and ``scaling``; the default there).  Scores ``s =
+    sigmoid(z W_g)`` in float32; the ``top_k`` experts of ``s + bias`` are
+    chosen (the bias only selects: it takes no gradient
     and is not in the weights), weighted ``s_e / (sum of the chosen s +
     1e-6) * scaling``.  -> (idx (N, k) int32, w (N, k) float32)."""
-    s = jax.nn.sigmoid(jnp.dot(
-        z.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
+    s = jax.nn.sigmoid(_router_logits(z, router_w))
     _, idx = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)),
                        top_k)
-    # the chosen scores by a one-hot product, not a gather: its transpose
-    # is elementwise too (a gather's is a scatter-add)
-    onehot = jax.nn.one_hot(idx, s.shape[-1], dtype=s.dtype)
-    chosen = jnp.sum(s[:, None, :] * onehot, axis=-1)
+    chosen = _chosen(s, idx)
     w = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-6)
     return idx.astype(jnp.int32), w * scaling
+
+
+def softmax_topk_routing(z, router_w, bias=None, *, top_k: int,
+                         renormalize: bool = True):
+    """The other routing: ``p = softmax(z W_g)`` over the router's whole
+    width in float32, the ``top_k`` largest chosen, weighted ``p_e`` or,
+    with ``renormalize``, ``p_e / (sum of the chosen p)``.  No selection
+    bias (``bias`` is there for the signature and must be None).
+    -> (idx (N, k) int32, w (N, k) float32)."""
+    assert bias is None, "softmax routing has no selection bias"
+    p = jax.nn.softmax(_router_logits(z, router_w), axis=-1)
+    _, idx = lax.top_k(p, top_k)
+    w = _chosen(p, idx)
+    if renormalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), w
+
+
+def routed_rows(tokens: int, top_k: int, held: int, width: int,
+                factor: Optional[float]) -> Optional[int]:
+    """Rows of a static routed-row buffer (``routed_experts``' ``rows``)
+    for ``tokens`` tokens: ``factor`` x the rows a uniform router over
+    ``width`` experts sends to the ``held`` ones, in whole 128s, at most
+    every row a router could send; None (nothing can overflow) where
+    ``factor`` is None."""
+    if factor is None:
+        return None
+    even = tokens * top_k * held / width
+    worst = tokens * min(top_k, held)
+    return min(worst, 128 * math.ceil(factor * even / 128))
+
+
+def held_expert_shardings(mesh, shapes):
+    """Shardings of a model's parameter tree (``shapes``: its
+    ``jax.eval_shape``) whose expert layers hold ``w1``/``w3`` (held, D,
+    F) and ``w2`` (held, F, D): everything whole on every device, but
+    the experts' leading axis over ``ep`` where the mesh has one."""
+    ep = "ep" if mesh.shape.get("ep", 1) > 1 else None
+
+    def spec(path, leaf):
+        expert = path[-1].key in ("w1", "w3", "w2") and leaf.ndim == 3
+        return NamedSharding(mesh, P(ep) if expert else P())
+
+    return jax.tree_util.tree_map_with_path(spec, shapes)
 
 
 def _routing_plan(idx, first: int, held: int, ranks: int, cap: int):
@@ -290,13 +347,20 @@ def _exchange(xs, sizes, ep_axis: str, ep: int, cap: int):
 def routed_experts(x, router_w, bias, w1, w3, w2, *, top_k: int,
                    first_expert: int = 0, scaling: float = 1.0,
                    rows: Optional[int] = None, mesh=None,
-                   token_axes=(), ep_axis: Optional[str] = None):
+                   token_axes=(), ep_axis: Optional[str] = None,
+                   routing=None):
     """The routed-experts FFN ``sum_e w_e (silu(z W1_e) * z W3_e) W2_e``
     over the chosen experts that are HELD here, no assignment dropped.
 
     x: (B, T, D).  ``router_w`` (D, E) and ``bias`` (E,) span the
     router's whole width E; ``w1``/``w3`` (held, D, F) and ``w2`` (held,
-    F, D) are experts ``first_expert .. first_expert + held``.  Routing,
+    F, D) are experts ``first_expert .. first_expert + held``.  The
+    routing is the caller's: ``routing(z (N, D), router_w, bias) -> (idx
+    (N, top_k) int32, w (N, top_k) float32)``, for instance
+    ``functools.partial(softmax_topk_routing, top_k=...)`` with ``bias``
+    None; left out, it is ``sigmoid_topk_routing`` with this call's
+    ``top_k`` and ``scaling``.  The plan, the grouped matmuls and the
+    exchange are one copy under every routing.  Routing,
     top-k and the weights' normalisation run over all E; what the experts
     that are not held would add is left out (one chip's share of a
     deployment: run once per share, the results add up to the whole
@@ -322,6 +386,9 @@ def routed_experts(x, router_w, bias, w1, w3, w2, *, top_k: int,
     if ep > 1 and ep_axis not in axes:
         raise ValueError(f"tokens must be sharded over {ep_axis!r} too: "
                          f"token_axes {token_axes}")
+    if routing is None:
+        def routing(z, router_w, bias):
+            return sigmoid_topk_routing(z, router_w, bias, k, scaling)
 
     def local(x, router_w, bias, w1, w3, w2):
         held = w1.shape[0]
@@ -329,7 +396,7 @@ def routed_experts(x, router_w, bias, w1, w3, w2, *, top_k: int,
         N = z.shape[0]
         cap = rows if rows is not None else N * min(k, held)
         with jax.named_scope("moe_route"):
-            idx, w = sigmoid_topk_routing(z, router_w, bias, k, scaling)
+            idx, w = routing(z, router_w, bias)
         with jax.named_scope("moe_dispatch"):
             plan = _routing_plan(idx, first_expert, held, ep, cap)
             to_rows = (plan["slot"] // k, plan["row_valid"],
@@ -359,6 +426,7 @@ def routed_experts(x, router_w, bias, w1, w3, w2, *, top_k: int,
     e_spec = P(ep_axis) if ep > 1 else P()
     return jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(axes), P(), P(), e_spec, e_spec, e_spec),
+        in_specs=(P(axes), P(), None if bias is None else P(),
+                  e_spec, e_spec, e_spec),
         out_specs=(P(axes), e_spec, P()), check_vma=False)(
             x, router_w, bias, w1, w3, w2)

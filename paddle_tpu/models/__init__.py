@@ -6,10 +6,15 @@ equivalent in-tree: an eager nn.Layer GPT (optionally tensor-parallel via
 fleet mp layers) and a fully-compiled SPMD trainer that pipelines the
 blocks over the ``pp`` mesh axis.  ``lfm2_moe`` is a second functional
 model for the same step builder: gated short convolutions, grouped-query
-attention and routed experts.
+attention and routed experts.  ``qwen3_next`` is a third: gated
+delta-rule (linear-attention) layers with a chunked scan, gated softmax
+attention, softmax-routed experts beside a gated shared expert.
 """
 from .gpt import GPTConfig, GPT, GPTBlock  # noqa: F401
 from .gpt_spmd import (init_gpt_params, build_spmd_train_step,  # noqa: F401
                        gpt_param_shardings)
 from .lfm2_moe import (Lfm2MoeConfig, init_lfm2_moe_params,  # noqa: F401
                        lfm2_moe_param_shardings)
+from .qwen3_next import (Qwen3NextConfig,  # noqa: F401
+                         init_qwen3_next_params,
+                         qwen3_next_param_shardings)

@@ -30,7 +30,6 @@ stacked ``(L, ...)`` arrays: the layers are of four kinds).
 """
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 from types import SimpleNamespace
 from typing import Dict, Optional, Tuple
@@ -41,7 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
 __all__ = ["Lfm2MoeConfig", "init_lfm2_moe_params",
            "lfm2_moe_param_shardings"]
@@ -87,12 +86,9 @@ class Lfm2MoeConfig:
 
     def moe_rows(self, tokens: int) -> Optional[int]:
         """Rows of the routed-row buffer for ``tokens`` tokens."""
-        if self.moe_rows_factor is None:
-            return None
-        even = tokens * self.num_experts_per_tok * self.held \
-            / self.num_experts
-        worst = tokens * min(self.num_experts_per_tok, self.held)
-        return min(worst, 128 * math.ceil(self.moe_rows_factor * even / 128))
+        from ..distributed.fleet.meta_parallel.moe import routed_rows
+        return routed_rows(tokens, self.num_experts_per_tok, self.held,
+                           self.num_experts, self.moe_rows_factor)
 
     def spmd_parts(self, mesh: Mesh):
         """What ``build_spmd_train_step`` asks of a model."""
@@ -137,19 +133,12 @@ def init_lfm2_moe_params(cfg: Lfm2MoeConfig, key) -> Dict:
             "head_w": normal(D, cfg.vocab_size)}
 
 
-def _is_expert_weight(path, leaf) -> bool:
-    return path[-1].key in ("w1", "w3", "w2") and leaf.ndim == 3
-
-
 def lfm2_moe_param_shardings(mesh: Mesh, cfg: Lfm2MoeConfig) -> Dict:
     """Everything whole on every device, but the experts' leading axis
     over ``ep`` where the mesh has one."""
-    ep = "ep" if mesh.shape.get("ep", 1) > 1 else None
-    shapes = jax.eval_shape(
-        lambda: init_lfm2_moe_params(cfg, jax.random.PRNGKey(0)))
-    return jax.tree_util.tree_map_with_path(
-        lambda path, s: NamedSharding(
-            mesh, P(ep) if _is_expert_weight(path, s) else P()), shapes)
+    from ..distributed.fleet.meta_parallel.moe import held_expert_shardings
+    return held_expert_shardings(mesh, jax.eval_shape(
+        lambda: init_lfm2_moe_params(cfg, jax.random.PRNGKey(0))))
 
 
 def _rms(x, g, eps):

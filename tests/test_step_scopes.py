@@ -421,3 +421,131 @@ def test_the_gpt_step_holds_the_mosaic_calls_it_held(real_width_step_hlo):
                    for c in _mosaic_calls(hlo))
     assert names == sorted(["%jvp__"] * (cfg.num_layers + 1)
                            + ["%checkpoint"] * cfg.num_layers), names
+
+
+# ---------------------------------------------------------------------------
+# the Qwen3-Next step: its own scopes, its own name, its own kernels
+# ---------------------------------------------------------------------------
+QWEN3_NEXT_SCOPES = (
+    "embed", "gdn_in", "gdn_conv", "gdn_scan", "gdn_out", "gattn_qkv",
+    "gattn_out", "shared_expert", "moe_route", "moe_dispatch",
+    "moe_experts", "moe_combine", "final_norm")
+
+
+@pytest.fixture(scope="module")
+def tiny_qwen3_next_step_text():
+    from paddle_tpu.models import Qwen3NextConfig
+    cfg = Qwen3NextConfig(
+        vocab_size=128, hidden_size=32, num_hidden_layers=2,
+        full_attention_interval=2, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=8,
+        linear_value_head_dim=8, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=16, shared_expert_intermediate_size=16,
+        num_experts_held=4, gdn_chunk=8)
+    return _lower_lfm2(cfg, build_mesh({"dp": 1}), 2, 16,
+                       remat_policy="ctx").compile().as_text()
+
+
+def test_the_qwen3_next_step_has_its_own_name(tiny_qwen3_next_step_text):
+    assert "HloModule jit_qwen3_next_spmd_train_step" \
+        in tiny_qwen3_next_step_text
+    names = _op_names(tiny_qwen3_next_step_text)
+    assert any("/optimizer/" in n and "transpose(" not in n for n in names)
+    # GPT's and LFM2's scopes are theirs
+    assert not any(_under(n, BLOCK + ("unstack", "final_ln", "short_conv",
+                                      "gqa_qkv", "gqa_out", "dense_ffn"))
+                   for n in names)
+
+
+@pytest.mark.parametrize("scope", QWEN3_NEXT_SCOPES)
+def test_a_qwen3_next_scope_forward_and_backward(
+        tiny_qwen3_next_step_text, scope):
+    names = _op_names(tiny_qwen3_next_step_text)
+    assert any(f"jvp({scope})" in n for n in names), scope
+    assert any("transpose(" in n and _under(n, [scope]) for n in names), \
+        scope
+
+
+def test_qwen3_next_matmuls_sit_under_a_scope(tiny_qwen3_next_step_text):
+    """Every dot_general but attention's own (a sibling of the scopes,
+    like the other models') and the loss head's sits under one of the
+    model's scopes; the chunked rule's — the products inside a chunk and
+    the state's in the scan over chunks — under ``gdn_scan``."""
+    names = _op_names(tiny_qwen3_next_step_text)
+    dots = [n for n in names if n.endswith("dot_general")]
+    attention = [n for n in dots if "bqd,bkd->bqk" in n or "bqk,bkd->bqd" in n]
+    assert attention and not any(_under(n, QWEN3_NEXT_SCOPES)
+                                 for n in attention)
+    rest = [n for n in dots
+            if n not in attention and not _under(n, ["loss_head"])]
+    assert rest and all(_under(n, QWEN3_NEXT_SCOPES) for n in rest), \
+        [n for n in rest if not _under(n, QWEN3_NEXT_SCOPES)]
+    scan = [n for n in rest if _under(n, ["gdn_scan"])]
+    # the rows' loop holds the products inside a chunk, and the loop
+    # over chunks inside it the state's
+    assert {min(n.count("while/body"), 2) for n in scan} == {1, 2}
+    assert any(_under(n, ["moe_experts"]) for n in rest)
+    assert any(_under(n, ["shared_expert"]) for n in rest)
+    # the triangular inverse of a chunk is the rule's too
+    assert any(_under(n, ["gdn_scan"]) and "triangular_solve" in n
+               for n in names)
+
+
+@pytest.fixture(scope="module")
+def qwen3_next_real_width_hlo(v5e):
+    """A delta-rule layer and a gated-attention layer at the published
+    widths, the cell's share (16 of 512 experts, V = 18 992) and the
+    cell's B=4 x T=8192, for one v5e chip.  A v5e reports 128 MiB of
+    VMEM, a described one nothing: the capacity is steered here."""
+    from paddle_tpu.models import Qwen3NextConfig
+    from paddle_tpu.ops import pallas
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+    mp = pytest.MonkeyPatch()
+    for mod in (pallas, fa):
+        mp.setattr(mod, "on_tpu", lambda: True)
+    mp.setattr(fa, "_vmem_capacity", lambda: 128 << 20)
+    try:
+        cfg = Qwen3NextConfig(vocab_size=18992, num_hidden_layers=2,
+                              full_attention_interval=2,
+                              num_experts_held=16, moe_rows_factor=2.0)
+        mesh = Mesh(np.asarray(v5e[:1]), ("dp",))
+        return _lower_lfm2(cfg, mesh, 4, 8192,
+                           remat_policy="ctx").compile().as_text()
+    finally:
+        mp.undo()
+
+
+def test_every_qwen3_next_mosaic_call_is_one_the_benchmark_finds(
+        qwen3_next_real_width_hlo):
+    """The resident flash pair at head size 256 (one forward, one fused
+    backward, nothing run again), the compiler's grouped expert matmuls
+    and the fused loss head at V = 18 992: each Mosaic call matches a
+    pattern of exactly one of the cell's metric files; the rule is XLA
+    operations and holds none."""
+    mosaic = _mosaic_calls(qwen3_next_real_width_hlo)
+    groups = {m: _patterns(m) for m in (
+        "gattn_roofline", "qwen3next_moe_experts_roofline",
+        "qwen3next_loss_head_events")}
+    hits = {m: [c for c in mosaic if any(r.search(c) for r in rx)]
+            for m, rx in groups.items()}
+    attention = hits["gattn_roofline"]
+    assert len(attention) == 2, [c[:100] for c in attention]
+    assert sum(c.startswith("%jvp__") for c in attention) == 1
+    assert sum(c.startswith("%checkpoint") for c in attention) == 1
+    assert "bf16[64,8192,256]" in attention[0]
+    # two expert layers: three grouped matmuls forward, three recomputed
+    # and six backward each, and the compiler's own layout calls
+    experts = hits["qwen3next_moe_experts_roofline"]
+    assert sum(not c.startswith("%ragged-dot-metadata")
+               for c in experts) == 24
+    assert len(hits["qwen3next_loss_head_events"]) == 1
+    assert sum(map(len, hits.values())) == len(mosaic), \
+        [c[:100] for c in mosaic
+         if not any(c in h for h in hits.values())]
+    loop = groups["qwen3next_loss_head_events"][1]
+    assert any(loop.search(i)
+               for i in _instructions(qwen3_next_real_width_hlo))
+    # LFM2's generic stream pattern would find these two calls as well:
+    # each cell lists its own metric, so neither reads the other's step
+    assert not any(_patterns("attn_roofline")[0].search(c) for c in mosaic)
