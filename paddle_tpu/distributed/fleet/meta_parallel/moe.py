@@ -178,18 +178,22 @@ def _router_logits(z, router_w):
 
 
 def sigmoid_topk_routing(z, router_w, bias, top_k: int,
-                         scaling: float = 1.0):
+                         scaling: float = 1.0, eps: float = 1e-6):
     """One of the routings a caller may hand ``routed_experts`` (bound
     to its ``top_k`` and ``scaling``; the default there).  Scores ``s =
     sigmoid(z W_g)`` in float32; the ``top_k`` experts of ``s + bias`` are
     chosen (the bias only selects: it takes no gradient
     and is not in the weights), weighted ``s_e / (sum of the chosen s +
-    1e-6) * scaling``.  -> (idx (N, k) int32, w (N, k) float32)."""
+    eps) * scaling``.  ``eps`` is the published family's: 1e-6 in the
+    ``lfm2_moe`` modelling code (the default), 1e-20 in the DeepSeek-V3
+    family's ``noaux_tc`` gate (``joyai_llm_flash``: with ``n_group`` =
+    ``topk_group`` = 1 that gate is this routing, the group limit
+    selecting nothing).  -> (idx (N, k) int32, w (N, k) float32)."""
     s = jax.nn.sigmoid(_router_logits(z, router_w))
     _, idx = lax.top_k(s + lax.stop_gradient(bias.astype(jnp.float32)),
                        top_k)
     chosen = _chosen(s, idx)
-    w = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-6)
+    w = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + eps)
     return idx.astype(jnp.int32), w * scaling
 
 

@@ -9,6 +9,11 @@ model for the same step builder: gated short convolutions, grouped-query
 attention and routed experts.  ``qwen3_next`` is a third: gated
 delta-rule (linear-attention) layers with a chunked scan, gated softmax
 attention, softmax-routed experts beside a gated shared expert.
+``joyai_flash`` is a fourth: latent attention (MLA) with a 192-wide q/k
+head over a 128-wide v head, sigmoid-routed experts beside an ungated
+shared expert, and a multi-token-prediction module scored by the one loss
+head a second time.  ``sparse_blocks`` holds what the three sparse models
+share.
 """
 from .gpt import GPTConfig, GPT, GPTBlock  # noqa: F401
 from .gpt_spmd import (init_gpt_params, build_spmd_train_step,  # noqa: F401
@@ -18,3 +23,6 @@ from .lfm2_moe import (Lfm2MoeConfig, init_lfm2_moe_params,  # noqa: F401
 from .qwen3_next import (Qwen3NextConfig,  # noqa: F401
                          init_qwen3_next_params,
                          qwen3_next_param_shardings)
+from .joyai_flash import (JoyAIFlashConfig,  # noqa: F401
+                          init_joyai_flash_params,
+                          joyai_flash_param_shardings)

@@ -169,16 +169,26 @@ def build_spmd_train_step(cfg, mesh: Mesh,
     init_fn(seed) -> (params, opt_state) placed onto the mesh.
 
     ``cfg`` is a ``GPTConfig`` or a configuration that brings its own
-    model: an object with ``spmd_parts(mesh)`` (``Lfm2MoeConfig``) whose
-    result gives ``init(key)``, ``shardings``, ``trunk(params, ids,
-    remat) -> (final hidden states, counters)``, ``batch_axes``,
-    ``step_name`` and the leaves that ``keep_float32`` or are ``frozen``
-    (no gradient, no update).  What is the step's own is kept here once
-    for every model: the cast to ``compute_dtype``, the remat policies,
-    the fused / chunked loss head, AdamW, ZeRO, the jit and its donation.
-    A model whose trunk counts something on the device (routed
-    assignments per expert, overflow) gets those int32 counters as a
-    fourth result: (loss, params, opt_state, counters).
+    model: an object with ``spmd_parts(mesh)`` (``Lfm2MoeConfig``,
+    ``Qwen3NextConfig``, ``JoyAIFlashConfig``) whose result gives
+    ``init(key)``, ``shardings``, ``trunk(params, ids, remat) -> (final
+    hidden states, counters)``, ``batch_axes``, ``step_name`` and the
+    leaves that ``keep_float32`` or are ``frozen`` (no gradient, no
+    update).  A model that predicts further ahead than the next token
+    says ``further_depths=True``; its trunk is then ``trunk(params, ids,
+    remat, labels) -> (final hidden states, counters, depths)`` (a
+    multi-token-prediction module embeds the labels) and each of
+    ``depths`` is a dict: ``name``, ``hidden`` (B, T, D), ``labels`` (B,
+    T), ``row_weight`` (B, T; 0 where a position has no target that far
+    on) and ``loss_weight``.  The builder runs its ONE head over the main
+    hidden states and over each depth's and returns ``loss_main +
+    sum(loss_weight * loss_<name>)``, with every term among the counters.
+    What is the step's own is kept here once for every model: the cast to
+    ``compute_dtype``, the remat policies, the fused / chunked loss head,
+    AdamW, ZeRO, the jit and its donation.  A model whose trunk counts
+    something on the device (routed assignments per expert, overflow)
+    gets those counters as a fourth result: (loss, params, opt_state,
+    counters).
 
     ``schedule_mode`` (reference section_worker.cc:62): "F-then-B" runs
     the fill-drain forward pipeline and lets jax.grad build the backward
@@ -269,13 +279,17 @@ def build_spmd_train_step(cfg, mesh: Mesh,
             if a.dtype == jnp.float32 and not (keep and keep(path))
             else a, params)
 
-    def trunk(params, ids):
-        """ids -> (final hidden states, the model's counters): the cast
-        (under ``embed``, where the GPT trace has always shown it) and
-        then the model's own trunk."""
+    def trunk(params, ids, labels):
+        """ids -> (final hidden states, the model's counters, its further
+        prediction depths): the cast (under ``embed``, where the GPT trace
+        has always shown it) and then the model's own trunk, which sees
+        the labels where it predicts further ahead than the next token
+        (a multi-token-prediction module embeds them)."""
         with jax.named_scope("embed"):
             params = _cast(params, model.keep_float32)
-        return model.trunk(params, ids, maybe_remat)
+        if getattr(model, "further_depths", False):
+            return model.trunk(params, ids, maybe_remat, labels)
+        return (*model.trunk(params, ids, maybe_remat), ())
 
     def gpt_trunk(params, ids, remat):
         """Non-pp/non-sp forward minus the head matmul: the shared path
@@ -371,8 +385,9 @@ def build_spmd_train_step(cfg, mesh: Mesh,
     # (jax.checkpoint), so live logits are chunk x V instead of BT x V.
     CE_CHUNK = 4096
 
-    def _ce_rows(xc, head_w, lc):
-        # xc: (C, D) hidden rows; lc: (C,) labels -> summed CE.  The
+    def _ce_rows(xc, head_w, lc, wc=None):
+        # xc: (C, D) hidden rows; lc: (C,) labels; wc: (C,) row weights
+        # or None -> summed (weighted) CE.  The
         # logits come out of the MXU in f32 directly (free on TPU), so
         # no separate (C, V) bf16->f32 subtract/convert pass ever
         # materialises (profiled r4: that pass alone was ~4% of step)
@@ -382,30 +397,32 @@ def build_spmd_train_step(cfg, mesh: Mesh,
         m = jax.lax.stop_gradient(jnp.max(logits, -1, keepdims=True))
         lse = m[..., 0] + jnp.log(jnp.sum(jnp.exp(logits - m), axis=-1))
         at = jnp.take_along_axis(logits, lc[:, None], axis=-1)[..., 0]
-        return jnp.sum(lse - at)
+        return jnp.sum(lse - at if wc is None else wc * (lse - at))
 
-    def chunked_ce(x, head_w, labels):
+    def chunked_ce(x, head_w, labels, row_weight=None):
         B, T, D = x.shape
         n = B * T
-        xf = x.reshape(n, D)
-        lf = labels.reshape(n)
+        # per row: (hidden, label) and, where rows are weighted, the weight
+        rows = [x.reshape(n, D), labels.reshape(n)]
+        if row_weight is not None:
+            rows.append(row_weight.reshape(n).astype(jnp.float32))
         ce = jax.checkpoint(_ce_rows)
         nc = n // CE_CHUNK
         total = jnp.zeros((), jnp.float32)
         if nc:
             def body(acc, args):
-                xc, lc = args
-                return acc + ce(xc, head_w, lc), None
+                xc, lc, *wc = args
+                return acc + ce(xc, head_w, lc, *wc), None
             head_n = nc * CE_CHUNK
-            total, _ = lax.scan(body, total,
-                                (xf[:head_n].reshape(nc, CE_CHUNK, D),
-                                 lf[:head_n].reshape(nc, CE_CHUNK)))
+            total, _ = lax.scan(body, total, tuple(
+                r[:head_n].reshape(nc, CE_CHUNK, *r.shape[1:])
+                for r in rows))
         if n % CE_CHUNK:
             # remainder rows get their own (still-checkpointed) chunk so
             # odd batch sizes never fall back to whole-logits CE
-            total = total + ce(xf[nc * CE_CHUNK:], head_w,
-                               lf[nc * CE_CHUNK:])
-        return total / n
+            xc, lc, *wc = (r[nc * CE_CHUNK:] for r in rows)
+            total = total + ce(xc, head_w, lc, *wc)
+        return total / (n if row_weight is None else jnp.sum(rows[2]))
 
     def loss_fn(params, ids, labels):
         if use_pp or use_sp:
@@ -420,28 +437,51 @@ def build_spmd_train_step(cfg, mesh: Mesh,
                 at_label = jnp.take_along_axis(shifted, labels[..., None],
                                                axis=-1)[..., 0]
                 return jnp.mean(lse - at_label), {}
-        x, counters = trunk(params, ids)
+        x, counters, further = trunk(params, ids, labels)
         head_w = params["head_w"].astype(x.dtype)
-        B, T, D = x.shape
         from ..ops import pallas
         fused = pallas.enabled() and mesh.size == 1
-        interpret = pallas.note("softmax_xent", fused)
-        if fused:
-            # fused pallas head (softmax_xent.py): no (N, V) logits in
-            # the forward at all — the kernel streams W tiles through
-            # VMEM with online stats (the chunked path below writes +
-            # re-reads 500 MB of f32 logits per chunk; measured r5:
-            # fused fwd 23.5 ms vs 28.5, and the saved-lse backward
-            # skips the stat recompute).  One device only: the kernel
-            # has no cross-shard lse combine for a vocab-sharded head.
-            # Like attention, outside every scope: its forward is a Mosaic
-            # call the benchmark finds by its compiler-made name.
-            from ..ops.pallas.softmax_xent import softmax_xent_loss
-            return softmax_xent_loss(x.reshape(B * T, D), head_w,
-                                     labels.reshape(B * T),
-                                     interpret), counters
-        with jax.named_scope("loss_head"):
-            return chunked_ce(x, head_w, labels), counters
+
+        def head(x, labels, row_weight=None):
+            """The one loss head, called once a prediction depth: mean
+            cross-entropy of ``x @ head_w`` over the rows, or over the
+            rows' weights where they are given."""
+            B, T, D = x.shape
+            interpret = pallas.note("softmax_xent", fused)
+            if fused:
+                # fused pallas head (softmax_xent.py): no (N, V) logits in
+                # the forward at all — the kernel streams W tiles through
+                # VMEM with online stats (the chunked path below writes +
+                # re-reads 500 MB of f32 logits per chunk; measured r5:
+                # fused fwd 23.5 ms vs 28.5, and the saved-lse backward
+                # skips the stat recompute).  One device only: the kernel
+                # has no cross-shard lse combine for a vocab-sharded head.
+                # Like attention, outside every scope: its forward is a
+                # Mosaic call the benchmark finds by its compiler-made
+                # name.
+                from ..ops.pallas.softmax_xent import softmax_xent_loss
+                weight = None if row_weight is None \
+                    else row_weight.reshape(B * T)
+                return softmax_xent_loss(x.reshape(B * T, D), head_w,
+                                         labels.reshape(B * T), interpret,
+                                         weight)
+            with jax.named_scope("loss_head"):
+                return chunked_ce(x, head_w, labels, row_weight)
+
+        loss = head(x, labels)
+        if further:
+            # a model that predicts further ahead (multi-token prediction)
+            # hands back, a depth: its hidden states, their labels, a
+            # weight a row (a row's last positions have no target that far
+            # on) and the depth's weight in the loss.  Each term is a
+            # counter of the step, so that a run can tell them apart
+            counters = dict(counters, loss_main=loss)
+            for depth in further:
+                term = head(depth["hidden"], depth["labels"],
+                            depth["row_weight"])
+                counters["loss_" + depth["name"]] = term
+                loss = loss + depth["loss_weight"] * term
+        return loss, counters
 
     def adamw_update(params, grads, opt_state):
         old_params = params

@@ -42,6 +42,10 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
+from .sparse_blocks import (batch_axes_of, dense_ffn as _dense_ffn,
+                            held_experts, leaf_name, moe_counters,
+                            rms_norm as _rms, rope_angles)
+
 __all__ = ["Lfm2MoeConfig", "init_lfm2_moe_params",
            "lfm2_moe_param_shardings"]
 
@@ -141,17 +145,10 @@ def lfm2_moe_param_shardings(mesh: Mesh, cfg: Lfm2MoeConfig) -> Dict:
         lambda: init_lfm2_moe_params(cfg, jax.random.PRNGKey(0))))
 
 
-def _rms(x, g, eps):
-    xf = x.astype(jnp.float32)
-    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return y.astype(x.dtype) * g.astype(x.dtype)
-
-
 def _rope(x, theta):
     """Rotate-half RoPE over the whole head; x: (B, T, H, hd)."""
     T, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / (theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd))
-    ang = np.arange(T, dtype=np.float64)[:, None] * inv[None, :]
+    ang = rope_angles(T, theta, hd)
     cos = jnp.asarray(np.cos(np.concatenate([ang, ang], -1)), jnp.float32)
     sin = jnp.asarray(np.sin(np.concatenate([ang, ang], -1)), jnp.float32)
     xf = x.astype(jnp.float32)
@@ -200,38 +197,18 @@ def _gqa(p, x, cfg, mesh, batch_axes):
         return x + ctx @ p["o_w"]
 
 
-def _dense_ffn(p, x, eps):
-    with jax.named_scope("dense_ffn"):
-        z = _rms(x, p["ffn_norm"], eps)
-        return x + (jax.nn.silu(z @ p["w1"]) * (z @ p["w3"])) @ p["w2"]
-
-
 def _expert_ffn(p, x, cfg, mesh, batch_axes):
-    from ..distributed.fleet.meta_parallel.moe import routed_experts
     with jax.named_scope("moe_route"):
         z = _rms(x, p["ffn_norm"], cfg.norm_eps)
-    ep = mesh.shape.get("ep", 1) > 1
-    shards = int(np.prod([mesh.shape[a] for a in batch_axes])) \
-        if batch_axes else 1
-    y, counts, overflow = routed_experts(
-        z, p["router_w"], p["router_bias"], p["w1"], p["w3"], p["w2"],
-        top_k=cfg.num_experts_per_tok, first_expert=cfg.first_expert,
-        scaling=cfg.routed_scaling_factor,
-        rows=cfg.moe_rows(x.shape[0] * x.shape[1] // shards),
-        mesh=mesh, token_axes=batch_axes or (),
-        ep_axis="ep" if ep else None)
+    y, counts, overflow = held_experts(
+        z, p, cfg, mesh, batch_axes, p["router_bias"],
+        scaling=cfg.routed_scaling_factor)
     with jax.named_scope("moe_combine"):
         return x + y, counts, overflow
 
 
 def _spmd_parts(cfg: Lfm2MoeConfig, mesh: Mesh):
-    for axis in ("pp", "sp", "mp"):
-        if mesh.shape.get(axis, 1) > 1:
-            raise NotImplementedError(
-                f"the LFM2-MoE step runs on one device, dp and ep; the "
-                f"mesh has {axis}={mesh.shape[axis]}")
-    batch_axes = tuple(a for a in ("dp", "sharding", "ep")
-                       if mesh.shape.get(a, 1) > 1) or None
+    batch_axes = batch_axes_of(mesh, "LFM2-MoE")
 
     def block(l):
         conv = cfg.layer_types[l] == "conv"
@@ -258,15 +235,7 @@ def _spmd_parts(cfg: Lfm2MoeConfig, mesh: Mesh):
                 counted.append(aux)
         with jax.named_scope("final_norm"):
             x = _rms(x, params["out_norm"], cfg.norm_eps)
-        counters = {}
-        if counted:
-            counters = {
-                "moe_counts": jnp.stack([c for c, _ in counted]),
-                "moe_overflow": sum(o for _, o in counted)}
-        return x, counters
-
-    def leaf_name(path):
-        return getattr(path[-1], "key", None)
+        return x, moe_counters(counted)
 
     return SimpleNamespace(
         init=lambda key: init_lfm2_moe_params(cfg, key),

@@ -53,6 +53,9 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
+from .sparse_blocks import (batch_axes_of, held_experts, leaf_name,
+                            moe_counters, rope_angles, swiglu)
+
 __all__ = ["Qwen3NextConfig", "init_qwen3_next_params",
            "qwen3_next_param_shardings"]
 
@@ -167,10 +170,7 @@ def _norm(x, w, eps):
 def _partial_rope(x, theta: float, rotary: int):
     """Rotate-half RoPE on the first ``rotary`` components of every head
     (pairs ``(i, i + rotary / 2)``), the rest untouched; x: (B, T, H, hd)."""
-    T = x.shape[1]
-    inv = 1.0 / (theta ** (np.arange(0, rotary, 2, dtype=np.float64)
-                           / rotary))
-    ang = np.arange(T, dtype=np.float64)[:, None] * inv[None, :]
+    ang = rope_angles(x.shape[1], theta, rotary)
     cos = jnp.asarray(np.cos(ang), jnp.float32)[None, :, None, :]
     sin = jnp.asarray(np.sin(ang), jnp.float32)[None, :, None, :]
     xf = x.astype(jnp.float32)
@@ -262,39 +262,25 @@ def _gated_delta_net(p, x, cfg, mesh, batch_axes):
 
 
 def _expert_ffn(p, x, cfg, mesh, batch_axes):
-    from ..distributed.fleet.meta_parallel.moe import (
-        routed_experts, softmax_topk_routing)
+    from ..distributed.fleet.meta_parallel.moe import softmax_topk_routing
     with jax.named_scope("moe_route"):
         z = _norm(x, p["ffn_norm"], cfg.rms_norm_eps)
-    ep = mesh.shape.get("ep", 1) > 1
-    shards = int(np.prod([mesh.shape[a] for a in batch_axes])) \
-        if batch_axes else 1
-    y, counts, overflow = routed_experts(
-        z, p["router_w"], None, p["w1"], p["w3"], p["w2"],
-        top_k=cfg.num_experts_per_tok, first_expert=cfg.first_expert,
-        rows=cfg.moe_rows(x.shape[0] * x.shape[1] // shards),
-        mesh=mesh, token_axes=batch_axes or (),
-        ep_axis="ep" if ep else None,
+    y, counts, overflow = held_experts(
+        z, p, cfg, mesh, batch_axes,
         routing=functools.partial(
             softmax_topk_routing, top_k=cfg.num_experts_per_tok,
             renormalize=cfg.norm_topk_prob))
     with jax.named_scope("shared_expert"):
         # every chip computes it alike: outside the exchange
         gate = jax.nn.sigmoid((z @ p["shared_gate_w"]).astype(jnp.float32))
-        h = jax.nn.silu(z @ p["shared_w1"]) * (z @ p["shared_w3"])
-        shared = gate.astype(z.dtype) * (h @ p["shared_w2"])
+        shared = gate.astype(z.dtype) * swiglu(
+            z, p["shared_w1"], p["shared_w3"], p["shared_w2"])
     with jax.named_scope("moe_combine"):
         return x + y + shared, counts, overflow
 
 
 def _spmd_parts(cfg: Qwen3NextConfig, mesh: Mesh):
-    for axis in ("pp", "sp", "mp"):
-        if mesh.shape.get(axis, 1) > 1:
-            raise NotImplementedError(
-                f"the Qwen3-Next step runs on one device, dp and ep; the "
-                f"mesh has {axis}={mesh.shape[axis]}")
-    batch_axes = tuple(a for a in ("dp", "sharding", "ep")
-                       if mesh.shape.get(a, 1) > 1) or None
+    batch_axes = batch_axes_of(mesh, "Qwen3-Next")
 
     def block(l):
         attention = cfg.is_attention(l)
@@ -319,11 +305,7 @@ def _spmd_parts(cfg: Qwen3NextConfig, mesh: Mesh):
             counted.append(aux)
         with jax.named_scope("final_norm"):
             x = _norm(x, params["out_norm"], cfg.rms_norm_eps)
-        return x, {"moe_counts": jnp.stack([c for c, _ in counted]),
-                   "moe_overflow": sum(o for _, o in counted)}
-
-    def leaf_name(path):
-        return getattr(path[-1], "key", None)
+        return x, moe_counters(counted)
 
     return SimpleNamespace(
         init=lambda key: init_qwen3_next_params(cfg, key),

@@ -1,0 +1,93 @@
+"""What the sparse functional models share (``lfm2_moe``, ``qwen3_next``,
+``joyai_flash``): the pieces of a block that are the same mathematics
+under each of them, kept once.  Each model keeps what is its own — its
+mixers, its norm where that differs (Qwen3-Next's is zero-centred), its
+RoPE pairing — and the scopes it names its parts with.
+"""
+from __future__ import annotations
+
+from typing import Optional
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import Mesh
+
+__all__ = ["rms_norm", "rope_angles", "swiglu", "dense_ffn", "held_experts",
+           "batch_axes_of", "moe_counters", "leaf_name"]
+
+
+def rms_norm(x, g, eps):
+    """``g * x rsqrt(mean(x^2) + eps)``: the statistics in float32, the
+    gain (initialised 1) applied in the compute type."""
+    xf = x.astype(jnp.float32)
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return y.astype(x.dtype) * g.astype(x.dtype)
+
+
+def rope_angles(T: int, theta: float, rotary: int):
+    """(T, rotary / 2) float64 angles ``t * theta^(-2i / rotary)`` of
+    RoPE over ``rotary`` components; which components pair up
+    (rotate-half, interleaved) is the model's."""
+    inv = 1.0 / (theta ** (np.arange(0, rotary, 2, dtype=np.float64)
+                           / rotary))
+    return np.arange(T, dtype=np.float64)[:, None] * inv[None, :]
+
+
+def swiglu(z, w1, w3, w2):
+    return (jax.nn.silu(z @ w1) * (z @ w3)) @ w2
+
+
+def dense_ffn(p, x, eps):
+    """``x + SwiGLU(RMS(x))`` of a leading dense layer."""
+    with jax.named_scope("dense_ffn"):
+        z = rms_norm(x, p["ffn_norm"], eps)
+        return x + swiglu(z, p["w1"], p["w3"], p["w2"])
+
+
+def held_experts(z, p, cfg, mesh: Mesh, batch_axes, bias=None, **routing):
+    """``routed_experts`` over the experts a model holds of one layer
+    (``p``: ``router_w``, ``w1``, ``w3``, ``w2``; ``cfg``:
+    ``num_experts_per_tok``, ``first_expert``, ``moe_rows``): the
+    routed-row buffer sized per shard of the batch, the exchange over
+    ``ep`` where the mesh has it.  ``routing`` is handed through
+    (``scaling=`` of the default sigmoid routing, or ``routing=``).
+    -> (y, counts, overflow)."""
+    from ..distributed.fleet.meta_parallel.moe import routed_experts
+    ep = mesh.shape.get("ep", 1) > 1
+    shards = int(np.prod([mesh.shape[a] for a in batch_axes])) \
+        if batch_axes else 1
+    return routed_experts(
+        z, p["router_w"], bias, p["w1"], p["w3"], p["w2"],
+        top_k=cfg.num_experts_per_tok, first_expert=cfg.first_expert,
+        rows=cfg.moe_rows(z.shape[0] * z.shape[1] // shards),
+        mesh=mesh, token_axes=batch_axes or (),
+        ep_axis="ep" if ep else None, **routing)
+
+
+def batch_axes_of(mesh: Mesh, model: str) -> Optional[tuple]:
+    """The mesh axes that shard a sparse model's batch (None on one
+    device); a mesh with an axis these models have no path for is
+    refused."""
+    for axis in ("pp", "sp", "mp"):
+        if mesh.shape.get(axis, 1) > 1:
+            raise NotImplementedError(
+                f"the {model} step runs on one device, dp and ep; the "
+                f"mesh has {axis}={mesh.shape[axis]}")
+    return tuple(a for a in ("dp", "sharding", "ep")
+                 if mesh.shape.get(a, 1) > 1) or None
+
+
+def moe_counters(counted):
+    """The step's counters from each expert layer's (counts, overflow);
+    none for a model with no expert layer."""
+    if not counted:
+        return {}
+    return {"moe_counts": jnp.stack([c for c, _ in counted]),
+            "moe_overflow": sum(o for _, o in counted)}
+
+
+def leaf_name(path):
+    return getattr(path[-1], "key", None)

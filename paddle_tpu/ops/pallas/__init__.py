@@ -6,7 +6,9 @@ and ``operators/kernel_primitives/`` — the ops where HBM bandwidth or
 softmax-rescaling tricks beat what the compiler fuses on its own.
 
 The kernels: ``flash_attention`` (eleven attention kernels behind one
-plan), ``fused_ln``, ``softmax_xent`` (the fused loss head's forward) and
+plan; the resident pair also at a v head size other than q's and k's:
+latent attention's 192 over 128), ``fused_ln``, ``softmax_xent`` (the
+fused loss head's forward, with an optional weight a row) and
 ``gated_delta_rule`` (the linear-attention recurrence over chunks, the
 state in VMEM: forward, state pass, reverse pass).
 

@@ -10,30 +10,39 @@ Which kernels a call runs is decided once, by ``_plan``, from the layout,
 the per-shard shapes and the chip's VMEM — never from an option.  The
 selection name is what ``ops.pallas.selections()`` counts:
 
-========================  ===============  ==============================  ====
-layout and lengths        selection        kernels, forward / backward     cell
-========================  ===============  ==============================  ====
-stacked, T <= 512         packed_small     _qkv_fwd_kernel /               none
+========================  ===============  ==============================  ====  ========
+layout and lengths        selection        kernels, forward / backward     cell  d_v != d
+========================  ===============  ==============================  ====  ========
+stacked, T <= 512         packed_small     _qkv_fwd_kernel /               none  (one d)
                                            _qkv_bwd_kernel
-stacked, T <= 2048        packed_mid       _qkv_fwd_kernel /               GPT
+stacked, T <= 2048        packed_mid       _qkv_fwd_kernel /               GPT   (one d)
                                            _qkv_mid_bwd_kernel
 stacked, anything else    (split; then as the folded layout below)
-folded, T, Tk <= 1024     small            _small_fwd_kernel /             none
+folded, T, Tk <= 1024     small            _small_fwd_kernel /             none  XLA math
                                            _small_bwd_kernel (Tk <= 512),
                                            _tiled_bwd_kernel (beyond)
-folded, T, Tk <= 4096     mid              _small_fwd_kernel /             none
+folded, T, Tk <= 4096     mid              _small_fwd_kernel /             none  XLA math
                                            _tiled_bwd_kernel
-folded, longer, resident  stream and       _resident_fwd_kernel /          LFM2
-budget fits the chip      stream_resident  _resident_bwd_kernel
-folded, longer, it does   stream           _fwd_kernel_pipelined /         none
+folded, longer, resident  stream and       _resident_fwd_kernel /          LFM2  kernels
+budget fits the chip      stream_resident  _resident_bwd_kernel            Q3N
+                                                                           JoyAI
+folded, longer, it does   stream           _fwd_kernel_pipelined /         none  XLA math
 not fit                                    _bwd_dq_kernel, _bwd_dkv_kernel
 a length no multiple of   (XLA math, both directions; counted as           none
 128, causal T > Tk, off   ``flash_attention.xla``)
 a TPU without FORCE
-========================  ===============  ==============================  ====
+========================  ===============  ==============================  ====  ========
 
 stacked is (3, B, T, H*d), folded (B*H, T, d).  GPT is the benchmark cell
-``gpt2-medium.train-t1024``, LFM2 is ``lfm2-24b-a2b.train-t8192``.
+``gpt2-medium.train-t1024``, LFM2 is ``lfm2-24b-a2b.train-t8192`` (d =
+64), Q3N ``qwen3-next-80b-a3b.train-t8192`` (d = 256), JoyAI
+``joyai-llm-flash.train-t8192``.  ``d`` is the head size of q and k,
+``d_v`` that of v and the output: ``flash_attention`` takes a ``d_v`` of
+its own (latent attention: 192 over 128, the last column).  The resident
+pair carries it — V rows, the output, dO, the output accumulator and the
+dV accumulator at ``d_v``, q, K, dq and dK at ``d``, the VMEM budget from
+both —, every other regime hands such a call to the XLA math, counted
+``flash_attention.xla``; no cell depends on that.
 "stacked" takes its kernels when the head size is 32, 64 or 128 and the
 heads fill 128-lane column blocks.  The regimes:
 
@@ -60,7 +69,8 @@ heads fill 128-lane column blocks.  The regimes:
   emits lse; ONE fused backward (5 matmuls and one exponential pass a live
   tile) writes dq per q block and accumulates dK/dV in f32 VMEM scratch.
   Its VMEM is ``_resident_vmem_bytes``: 35.7 MB at Tk = 8192, d = 64 or
-  128, bf16, blocks of 512 (the compiler takes 28 to 32 MB for the
+  128 (49.0 MB at 192 over 128: K rows padded to 256 lanes, V rows 128),
+  bf16, blocks of 512 (the compiler takes 28 to 32 MB for the
   backward, 20 MB for the forward at 1024 x 1024) — over Mosaic's default
   scoped limit (16 MiB, a compiler default and not the chip's VMEM), so the
   pair asks for its budget and a quarter more through ``vmem_limit_bytes``
@@ -189,16 +199,18 @@ def _granule(T: int, Tk: int, block_q: int, causal: bool) -> Optional[int]:
 
 
 def _resident_vmem_bytes(Tk: int, d: int, itemsize: int, block_q: int,
-                         chunk: int) -> int:
+                         chunk: int, d_v: Optional[int] = None) -> int:
     """VMEM the fused backward (the larger of the pair) holds: every
     BlockSpec'd operand twice (Mosaic double-buffers them), rows padded
-    to whole 128-lane tiles."""
+    to whole 128-lane tiles.  q, K and their gradients are ``d`` wide, V,
+    dO and dV ``d_v`` (``d`` where it is left out)."""
     lanes = -(-d // 128) * 128
-    rows = Tk * lanes
-    resident = 2 * (2 * rows * itemsize      # K, V
-                    + 2 * rows * itemsize)   # dK, dV output blocks
-    accumulators = 2 * rows * 4              # dK, dV in f32
-    q_sized = 2 * (3 * block_q * lanes * itemsize     # q, dO, dq
+    lanes_v = lanes if d_v is None else -(-d_v // 128) * 128
+    rows = Tk * (lanes + lanes_v)            # a K row and a V row
+    resident = 2 * (rows * itemsize          # K, V
+                    + rows * itemsize)       # dK, dV output blocks
+    accumulators = rows * 4                  # dK, dV in f32
+    q_sized = 2 * ((2 * lanes + lanes_v) * block_q * itemsize  # q, dq, dO
                    + 2 * block_q * 128 * 4)           # lse, delta: 1 lane
     dq_acc = block_q * lanes * 4
     # s, p, dp, ds in f32, p and ds again in the operand dtype, and the
@@ -218,13 +230,17 @@ def _vmem_capacity() -> int:
 
 
 def _plan(layout: str, B: int, T: int, Tk: int, heads: int, d: int,
-          itemsize: int, causal: bool) -> _Plan:
+          itemsize: int, causal: bool, d_v: Optional[int] = None) -> _Plan:
     """The one place that turns a call's (per-shard) shapes into kernels
     and block sizes.  ``layout`` is "stacked" ((3, B, T, heads*d)) or
     "folded" ((B*heads, T, d)); a stacked call whose shape the stacked
-    kernels do not take gets the folded plan it then runs split."""
+    kernels do not take gets the folded plan it then runs split.  ``d``
+    is the head size of q and k, ``d_v`` that of v and the output where
+    it differs (latent attention: 192 over 128); the resident pair alone
+    takes such a call, every other regime hands it to the XLA math."""
     if not _kernels_apply(T, Tk, causal):
         return _Plan("xla")
+    unequal = d_v is not None and d_v != d
     interpret = not on_tpu()
     # G, the rows (batch rows, batch-heads) a grid step takes, is at most
     # what VMEM holds at this T — so many tiles of 512 x 512 scores —
@@ -239,6 +255,8 @@ def _plan(layout: str, B: int, T: int, Tk: int, heads: int, d: int,
     # the stream regime owns anything longer.
     small = Tk <= SMALL_T_MAX and T <= SMALL_T_MAX
     mid = not small and Tk <= MID_T_MAX and T <= MID_T_MAX
+    if unequal and (small or mid):
+        return _Plan("xla")
 
     if layout == "stacked" and (small or mid) and T <= 2048 \
             and d in (32, 64, 128) and heads % max(1, 128 // d) == 0:
@@ -322,13 +340,15 @@ def _plan(layout: str, B: int, T: int, Tk: int, heads: int, d: int,
     # backward has no such pass and is flat from 512 x 512 (41.7 ms) to
     # 1024 x 1024 (42.3); it takes the smaller tiles for their VMEM.
     bwd = (_dividing(T, 512), _dividing(Tk, 512), 1)
-    need = _resident_vmem_bytes(Tk, d, itemsize, *bwd[:2])
+    need = _resident_vmem_bytes(Tk, d, itemsize, *bwd[:2], d_v)
     # a quarter on top for what Mosaic allocates beside the operands
     limit = need + need // 4
     if limit <= _RESIDENT_VMEM_SHARE * _vmem_capacity():
         return _Plan("stream_resident", interpret,
                      fwd=(_dividing(T, 1024), _dividing(Tk, 1024), 1),
                      bwd=bwd, vmem_limit=limit)
+    if unequal:
+        return _Plan("xla")
     return _Plan("stream", interpret,
                  fwd=(_block(T, 256), _block(Tk, 512), 1),
                  bwd=(_block(T, 256), _block(Tk, 256), 1))
@@ -739,10 +759,11 @@ def _resident_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _stream_flash_fwd(q, k, v, scale: float, causal: bool, plan: _Plan):
-    """q/k/v: (BH, T, d) -> (out (BH, T, d), lse (BH, T, 1) f32), the K/V
-    rows resident or their blocks on the grid, as the plan says."""
+    """q/k: (BH, T, d), v: (BH, Tk, d_v) -> (out (BH, T, d_v), lse (BH,
+    T, 1) f32), the K/V rows resident or their blocks on the grid, as the
+    plan says (``d_v`` other than ``d``: the resident form alone)."""
     BH, T, d = q.shape
-    Tk = k.shape[1]
+    Tk, d_v = k.shape[1], v.shape[2]
     block_q, block_k, _ = plan.fwd
     nk = Tk // block_k
     params = dict(scale=scale, causal=causal, block_q=block_q, nk=nk,
@@ -753,26 +774,27 @@ def _stream_flash_fwd(q, k, v, scale: float, causal: bool, plan: _Plan):
         grid, semantics = (BH, T // block_q), ("parallel", "arbitrary")
         q_map = lambda b, i: (b, i, 0)                          # noqa: E731
         k_spec = pl.BlockSpec((1, Tk, d), lambda b, i: (b, 0, 0))
+        v_spec = pl.BlockSpec((1, Tk, d_v), lambda b, i: (b, 0, 0))
     else:
         kernel = functools.partial(_fwd_kernel_pipelined, block_k=block_k,
                                    **params)
         grid = (BH, T // block_q, nk)
         semantics = ("parallel", "parallel", "arbitrary")
         q_map = lambda b, i, j: (b, i, 0)                       # noqa: E731
-        k_spec = pl.BlockSpec(
+        v_spec = k_spec = pl.BlockSpec(
             (1, block_k, d),
             _clamped_k_map(block_q, block_k, Tk - T, nk, causal))
-    q_spec = pl.BlockSpec((1, block_q, d), q_map)
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[q_spec, k_spec, k_spec],
-        out_specs=[q_spec, pl.BlockSpec((1, block_q, 1), q_map)],
-        out_shape=[jax.ShapeDtypeStruct((BH, T, d), q.dtype),
+        in_specs=[pl.BlockSpec((1, block_q, d), q_map), k_spec, v_spec],
+        out_specs=[pl.BlockSpec((1, block_q, d_v), q_map),
+                   pl.BlockSpec((1, block_q, 1), q_map)],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, d_v), q.dtype),
                    jax.ShapeDtypeStruct((BH, T, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, d), jnp.float32)],
+                        pltpu.VMEM((block_q, d_v), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics, vmem_limit_bytes=plan.vmem_limit),
         interpret=plan.interpret,
@@ -781,26 +803,29 @@ def _stream_flash_fwd(q, k, v, scale: float, causal: bool, plan: _Plan):
 
 def _resident_flash_bwd(q, k, v, o, lse, do, scale: float, causal: bool,
                         plan: _Plan):
-    """-> (dq, dk, dv), each (BH, ., d), from one kernel."""
+    """-> (dq, dk (BH, ., d), dv (BH, Tk, d_v)), from one kernel; v, o
+    and do are ``d_v`` wide."""
     BH, T, d = q.shape
-    Tk = k.shape[1]
+    Tk, d_v = k.shape[1], v.shape[2]
     block_q, chunk, _ = plan.bwd
     nq = T // block_q
     delta = _delta(do, o)
     qs = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
+    dos = pl.BlockSpec((1, block_q, d_v), lambda b, i: (b, i, 0))
     ks = pl.BlockSpec((1, Tk, d), lambda b, i: (b, 0, 0))
+    vs = pl.BlockSpec((1, Tk, d_v), lambda b, i: (b, 0, 0))
     rs = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0))
     return pl.pallas_call(
         functools.partial(_resident_bwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, chunk=chunk, nq=nq,
                           nk=Tk // chunk, offset=Tk - T),
         grid=(BH, nq),
-        in_specs=[qs, ks, ks, qs, rs, rs],
-        out_specs=[qs, ks, ks],
+        in_specs=[qs, ks, vs, dos, rs, rs],
+        out_specs=[qs, ks, vs],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
                         pltpu.VMEM((Tk, d), jnp.float32),
-                        pltpu.VMEM((Tk, d), jnp.float32)],
+                        pltpu.VMEM((Tk, d_v), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=plan.vmem_limit),
@@ -1332,7 +1357,11 @@ def flash_attention_stacked(qkv, num_heads: int, *, causal: bool = False,
 
 def flash_attention(q, k, v, *, causal: bool = False, scale=None,
                     mesh=None, batch_axes=(), head_axes=()):
-    """q/k/v: (B, S, H, D) paddle layout -> (B, S, H, D).
+    """q/k: (B, S, H, D), v: (B, S, H, Dv) paddle layout -> (B, S, H,
+    Dv).  ``Dv`` may differ from ``D`` (latent attention attends with a
+    192-wide q/k head over a 128-wide v head): the stream regime's
+    resident pair takes such a call, every other shape runs it as XLA
+    math (``flash_attention.xla``).
 
     All kernels go through the folded (B*H, T, d) layout — TPU tiling
     forbids blocking the head dim of (B, T, H, d) directly (the last
@@ -1350,7 +1379,8 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
 
     def local(q, k, v):
         b, _, h, _ = q.shape
-        plan = _plan("folded", b, T, Tk, h, D, q.dtype.itemsize, causal)
+        plan = _plan("folded", b, T, Tk, h, D, q.dtype.itemsize, causal,
+                     v.shape[-1])
         return _attend(q, k, v, s, causal, plan)
 
     if not _kernels_apply(T, Tk, causal):

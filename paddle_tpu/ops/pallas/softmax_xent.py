@@ -182,18 +182,23 @@ def softmax_xent_dlogits(x, w, labels, lse, gscale,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def softmax_xent_loss(x, w, labels, interpret=False):
+def softmax_xent_loss(x, w, labels, interpret=False, row_weight=None):
     """mean softmax cross-entropy of ``x @ w`` against ``labels`` —
     the whole LM loss head as two fused kernels + two XLA matmuls,
     with no (N, V) logits tensor in the forward and a single bf16
-    dlogits tensor in the backward."""
-    lse, at = softmax_xent_fwd(x, w, labels, interpret=interpret)
-    return jnp.sum(lse - at) / x.shape[0]
+    dlogits tensor in the backward.  ``row_weight`` (N,), where given,
+    makes it the weighted mean ``sum_i w_i ce_i / sum_i w_i`` (a row with
+    no target carries 0); it takes no gradient."""
+    return _sxl_fwd(x, w, labels, interpret, row_weight)[0]
 
 
-def _sxl_fwd(x, w, labels, interpret):
+def _sxl_fwd(x, w, labels, interpret, row_weight):
     lse, at = softmax_xent_fwd(x, w, labels, interpret=interpret)
-    return jnp.sum(lse - at) / x.shape[0], (x, w, labels, lse)
+    if row_weight is None:
+        return jnp.sum(lse - at) / x.shape[0], (x, w, labels, lse, None)
+    scale = row_weight.astype(jnp.float32)
+    scale = scale / jnp.sum(scale)
+    return jnp.sum((lse - at) * scale), (x, w, labels, lse, scale)
 
 
 def _sxl_bwd(interpret, res, g):
@@ -203,8 +208,9 @@ def _sxl_bwd(interpret, res, g):
     matmuls), emit dx and accumulate dW.  Measured r5: this beats a
     pallas dlogits-kernel variant by ~14 ms/step on the flagship — the
     XLA emitters win once the separate stat passes are gone, which the
-    saved lse provides."""
-    x, w, labels, lse = res
+    saved lse provides.  With row weights the scale is a row's own,
+    ``g w_i / sum(w)``, and rides the chunks beside lse."""
+    x, w, labels, lse, scale = res
     N, D = x.shape
     V = w.shape[1]
     C = min(4096, N)
@@ -212,15 +218,18 @@ def _sxl_bwd(interpret, res, g):
         C //= 2
     nc = N // C
     gs = (g / N).astype(jnp.float32)
+    rows = (x.reshape(nc, C, D), labels.reshape(nc, C), lse.reshape(nc, C))
+    if scale is not None:
+        rows += ((g * scale).astype(jnp.float32).reshape(nc, C, 1),)
 
     def body(dw_acc, args):
-        xc, lc, lsec = args
+        xc, lc, lsec, *own = args
         logits = jax.lax.dot_general(
             xc, w, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # (C, V)
         p = jnp.exp(logits - lsec[:, None])
         onehot = jax.nn.one_hot(lc, V, dtype=jnp.float32)
-        pb = ((p - onehot) * gs).astype(x.dtype)
+        pb = ((p - onehot) * (own[0] if own else gs)).astype(x.dtype)
         dx_c = jax.lax.dot_general(
             pb, w, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32).astype(x.dtype)
@@ -229,11 +238,8 @@ def _sxl_bwd(interpret, res, g):
             preferred_element_type=jnp.float32)
         return dw_acc, dx_c
 
-    dw, dx = jax.lax.scan(
-        body, jnp.zeros((D, V), jnp.float32),
-        (x.reshape(nc, C, D), labels.reshape(nc, C),
-         lse.reshape(nc, C)))
-    return dx.reshape(N, D), dw.astype(w.dtype), None
+    dw, dx = jax.lax.scan(body, jnp.zeros((D, V), jnp.float32), rows)
+    return dx.reshape(N, D), dw.astype(w.dtype), None, None
 
 
 softmax_xent_loss.defvjp(_sxl_fwd, _sxl_bwd)

@@ -981,3 +981,133 @@ def test_sdpa_registry_flip(force_pallas):
         np.testing.assert_allclose(o1.numpy(), o2.numpy(), atol=2e-5)
     finally:
         flags.set_flags({"FLAGS_use_pallas": 1})
+
+
+# ---------------------------------------------------------------------------
+# latent attention's heads: q and k of one size, v and the output of another
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("bq,ck,causal,tq,tk,d,dv,dtype", [
+    (128, 128, True, 512, 512, 192, 128, "float32"),
+    (256, 128, True, 512, 512, 192, 128, "float32"),
+    (128, 256, True, 256, 512, 192, 128, "float32"),
+    (128, 128, False, 256, 384, 192, 128, "float32"),
+    (128, 128, True, 384, 384, 192, 128, "bfloat16"),
+    (128, 128, True, 256, 256, 48, 80, "float32"),
+], ids=["blocks-128", "q-block-256", "chunk-256-decode-offset",
+        "not-causal", "bfloat16", "v-wider-than-q"])
+def test_resident_pair_at_unequal_head_sizes_vs_xla(bq, ck, causal, tq, tk,
+                                                    d, dv, dtype):
+    """The resident pair at q/k head size ``d`` over v head size ``dv``
+    (JoyAI-LLM-Flash: 192 over 128), interpreted: out, lse and all three
+    gradients against the XLA math and its ``jax.vjp``; T a multiple of
+    the blocks and longer than one chunk.  The softmax scale is the
+    q/k size's."""
+    rs = np.random.RandomState(7)
+    dt = jnp.dtype(dtype)
+    q = jnp.asarray(rs.randn(2, tq, d), dt)
+    k = jnp.asarray(rs.randn(2, tk, d), dt)
+    v = jnp.asarray(rs.randn(2, tk, dv), dt)
+    g = jnp.asarray(rs.randn(2, tq, dv), dt)
+    scale = 1.0 / np.sqrt(d)
+    plan = _stream_plan("resident", bq, ck)
+    out, lse = fa._stream_flash_fwd(q, k, v, scale, causal, plan)
+    grads = fa._resident_flash_bwd(q, k, v, out, lse, g, scale, causal, plan)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    ref, vjp = jax.vjp(
+        lambda a, b, c: fa._xla_attention(a, b, c, scale, causal), *f32)
+    tol = 3e-5 if dt == jnp.float32 else 4e-2
+    assert out.shape == (2, tq, dv) and lse.shape == (2, tq, 1)
+    for name, got, want in zip(("out", "dq", "dk", "dv"), (out, *grads),
+                               (ref, *vjp(g.astype(jnp.float32)))):
+        assert got.dtype == dt and got.shape == want.shape, name
+        np.testing.assert_allclose(
+            np.asarray(got.astype(jnp.float32)), np.asarray(want),
+            atol=tol, rtol=tol, err_msg=name)
+
+
+def test_unequal_head_sizes_through_the_public_entry(force_pallas,
+                                                     monkeypatch):
+    """``flash_attention`` with a v head narrower than q's: the stream
+    regime (thresholds lowered so that 256 rows select it) takes the
+    resident pair, values and gradients agree with the XLA math; at a
+    length the whole-row regimes own the call is XLA math and counted as
+    such."""
+    from paddle_tpu.ops import pallas
+    rs = np.random.RandomState(8)
+    q, k = (jnp.asarray(rs.randn(1, 256, 2, 192), jnp.float32)
+            for _ in range(2))
+    v, g = (jnp.asarray(rs.randn(1, 256, 2, 128), jnp.float32)
+            for _ in range(2))
+
+    def attend(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True)
+
+    def ref(q, k, v):
+        out = fa._xla_attention(fa._fold(q), fa._fold(k), fa._fold(v),
+                                192 ** -0.5, True)
+        return fa._unfold(out, 1)
+
+    want, ref_vjp = jax.vjp(ref, q, k, v)
+    before = pallas.selections()
+    np.testing.assert_allclose(attend(q, k, v), want, atol=2e-5)
+    took = {n for n, c in pallas.selections().items()
+            if c != before.get(n, 0)}
+    assert took == {"flash_attention.xla"}
+    monkeypatch.setattr(fa, "SMALL_T_MAX", 0)
+    monkeypatch.setattr(fa, "MID_T_MAX", 0)
+    before = pallas.selections()
+    out, vjp = jax.vjp(attend, q, k, v)
+    took = {n for n, c in pallas.selections().items()
+            if c != before.get(n, 0)}
+    assert took == {"flash_attention.stream.interpret",
+                    "flash_attention.stream_resident.interpret"}
+    assert out.shape == (1, 256, 2, 128)
+    np.testing.assert_allclose(out, want, atol=2e-5)
+    for got, exp in zip(vjp(g), ref_vjp(g)):
+        np.testing.assert_allclose(got, exp, atol=5e-5)
+
+
+@pytest.mark.parametrize("T,capacity,name,vmem_limit", [
+    (8192, 128 << 20, "stream_resident", 61276160),
+    (8192, 64 << 20, "xla", None), (2048, 128 << 20, "xla", None)],
+    ids=["the-cell", "a-chip-too-small", "a-whole-row-length"])
+def test_plan_at_unequal_head_sizes(force_pallas, monkeypatch, T, capacity,
+                                    name, vmem_limit):
+    """192 over 128 at the JoyAI cell's shape plans the resident pair
+    with the blocks the equal-sized cells have and a budget from both
+    sizes (K rows padded to 256 lanes, V rows 128); where the budget does
+    not fit, or a whole-row regime owns the length, the call is XLA's."""
+    monkeypatch.setattr(fa, "_vmem_capacity", lambda: capacity)
+    plan = fa._plan("folded", 2, T, T, 32, 192, 2, True, 128)
+    assert (plan.name, plan.vmem_limit) == (name, vmem_limit)
+    if name == "stream_resident":
+        assert (plan.fwd, plan.bwd) == ((1024, 1024, 1), (512, 512, 1))
+        need = fa._resident_vmem_bytes(T, 192, 2, 512, 512, 128)
+        assert need < fa._resident_vmem_bytes(T, 256, 2, 512, 512)
+        assert need > fa._resident_vmem_bytes(T, 128, 2, 512, 512)
+    # equal sizes named twice plan as they do named once
+    assert fa._plan("folded", 2, T, T, 32, 128, 2, True, 128) \
+        == fa._plan("folded", 2, T, T, 32, 128, 2, True)
+
+
+@pytest.mark.parametrize("shape,d_v,want", [
+    (("stacked", 32, 1024, 1024, 16, 64), (),
+     ("packed_mid", (512, 512, 1), (512, 512, 1), None)),
+    (("folded", 4, 8192, 8192, 32, 64), (64,),
+     ("stream_resident", (1024, 1024, 1), (512, 512, 1), 44564480)),
+    (("folded", 4, 8192, 8192, 16, 256), (256,),
+     ("stream_resident", (1024, 1024, 1), (512, 512, 1), 77332480)),
+    (("folded", 2, 8192, 8192, 32, 192), (128,),
+     ("stream_resident", (1024, 1024, 1), (512, 512, 1), 61276160))],
+    ids=["gpt2-medium", "lfm2-24b-a2b", "qwen3-next-80b-a3b",
+         "joyai-llm-flash"])
+def test_the_cells_plans(force_pallas, monkeypatch, shape, d_v, want):
+    """The record ``_plan`` hands each benchmark cell's attention call on
+    a v5e (128 MiB of VMEM a core), as the public entries ask for it —
+    ``flash_attention`` names the v head size, the stacked entry does
+    not.  A change to ``_plan`` or to the budget that moves one of these
+    moves a measured cell."""
+    monkeypatch.setattr(fa, "_vmem_capacity", lambda: 128 << 20)
+    plan = fa._plan(*shape, 2, True, *d_v)
+    assert plan == fa._Plan(want[0], jax.default_backend() != "tpu",
+                            *want[1:])

@@ -574,3 +574,178 @@ def test_every_qwen3_next_mosaic_call_is_one_the_benchmark_finds(
     # LFM2's generic stream pattern would find these two calls as well:
     # each cell lists its own metric, so neither reads the other's step
     assert not any(_patterns("attn_roofline")[0].search(c) for c in mosaic)
+
+
+# ---------------------------------------------------------------------------
+# the JoyAI-LLM-Flash step: its own scopes, its own name, its own kernels
+# ---------------------------------------------------------------------------
+JOYAI_SCOPES = (
+    "embed", "mla_q", "mla_kv", "mla_out", "dense_ffn", "shared_expert",
+    "moe_route", "moe_dispatch", "moe_experts", "moe_combine", "mtp_in",
+    "mtp", "final_norm")
+
+
+@pytest.fixture(scope="module")
+def tiny_joyai_step_text():
+    from paddle_tpu.models import JoyAIFlashConfig
+    cfg = JoyAIFlashConfig(
+        vocab_size=128, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_hidden_layers=2,
+        num_attention_heads=4, q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+        n_routed_experts=8, num_experts_per_tok=2, num_experts_held=4)
+    return _lower_lfm2(cfg, build_mesh({"dp": 1}), 2, 16,
+                       remat_policy="ctx").compile().as_text()
+
+
+def test_the_joyai_step_has_its_own_name(tiny_joyai_step_text):
+    assert "HloModule jit_joyai_flash_spmd_train_step" \
+        in tiny_joyai_step_text
+    names = _op_names(tiny_joyai_step_text)
+    assert any("/optimizer/" in n and "transpose(" not in n for n in names)
+    # the other models' scopes are theirs
+    assert not any(_under(n, BLOCK + (
+        "unstack", "final_ln", "short_conv", "gqa_qkv", "gqa_out",
+        "gdn_in", "gdn_scan", "gattn_qkv", "gattn_out")) for n in names)
+
+
+@pytest.mark.parametrize("scope", JOYAI_SCOPES)
+def test_a_joyai_scope_forward_and_backward(tiny_joyai_step_text, scope):
+    names = _op_names(tiny_joyai_step_text)
+    assert any(f"jvp({scope})" in n for n in names), scope
+    assert any("transpose(" in n and _under(n, [scope]) for n in names), \
+        scope
+
+
+def test_the_module_s_block_carries_the_trunk_s_scopes_under_its_own(
+        tiny_joyai_step_text):
+    """The MTP module's block sits under the parent scope ``mtp`` and
+    keeps the names of its parts: the reader gives an operation to the
+    innermost scope a metric lists, so ``mla_proj_ms`` reads the module's
+    projections with the trunk's and ``mtp_ms`` the module whole."""
+    names = _op_names(tiny_joyai_step_text)
+    mine = set(JOYAI_SCOPES)
+    inside = [n for n in names if _under(n, ["mtp"])]
+    for part in ("mla_q", "mla_kv", "mla_out", "moe_route", "moe_dispatch",
+                 "moe_experts", "moe_combine", "shared_expert"):
+        nested = [n for n in inside if _under(n, [part])]
+        assert nested, part
+        # (what of a part is run again under ``ctx`` is the compiler's
+        # to choose; the norm and projections in front of attention
+        # always are)
+        for phase in ("forward", "backward") + (
+                ("recompute",) if part == "mla_q" else ()):
+            assert any(classify(n, mine) == (part, phase)
+                       for n in nested), (part, phase)
+        assert all(classify(n, {"mtp", "mtp_in"})[0] == "mtp"
+                   for n in nested)
+        # and the trunk's own stand outside it
+        assert any(_under(n, [part]) and not _under(n, ["mtp"])
+                   for n in names), part
+    # neither the dense layer nor the heads are the module's
+    assert not any(_under(n, ["dense_ffn"]) for n in inside)
+
+
+def test_joyai_matmuls_sit_under_a_scope(tiny_joyai_step_text):
+    """Every dot_general but attention's own (a sibling of its block's
+    scopes; the module's under ``mtp`` alone) and the loss head's sits
+    under one of the model's scopes."""
+    names = _op_names(tiny_joyai_step_text)
+    dots = [n for n in names if n.endswith("dot_general")]
+    attention = [n for n in dots if "bqd,bkd->bqk" in n or "bqk,bkd->bqd" in n]
+    parts = tuple(s for s in JOYAI_SCOPES if s != "mtp")
+    assert attention and not any(_under(n, parts) for n in attention)
+    assert any(_under(n, ["mtp"]) for n in attention)
+    rest = [n for n in dots
+            if n not in attention and not _under(n, ["loss_head"])]
+    assert rest and all(_under(n, parts) for n in rest), \
+        [n for n in rest if not _under(n, parts)]
+    for scope in ("mla_q", "mla_kv", "mla_out", "dense_ffn", "mtp_in",
+                  "moe_experts", "shared_expert"):
+        assert any(_under(n, [scope]) for n in rest), scope
+    # off the chip the head is the chunked one, under its scope
+    assert any(_under(n, ["loss_head"]) for n in dots)
+
+
+@pytest.fixture(scope="module")
+def joyai_real_width_hlo(v5e):
+    """The leading dense layer, one expert layer and the MTP module at
+    the published widths, the cell's share (8 of 256 experts, V = 16 160)
+    and the cell's B=2 x T=8192, for one v5e chip.  A v5e reports 128 MiB
+    of VMEM, a described one nothing: the capacity is steered here."""
+    from paddle_tpu.models import JoyAIFlashConfig
+    from paddle_tpu.ops import pallas
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+    mp = pytest.MonkeyPatch()
+    for mod in (pallas, fa):
+        mp.setattr(mod, "on_tpu", lambda: True)
+    mp.setattr(fa, "_vmem_capacity", lambda: 128 << 20)
+    try:
+        cfg = JoyAIFlashConfig(vocab_size=16160, num_hidden_layers=2,
+                               num_experts_held=8, moe_rows_factor=4.0)
+        mesh = Mesh(np.asarray(v5e[:1]), ("dp",))
+        return _lower_lfm2(cfg, mesh, 2, 8192,
+                           remat_policy="ctx").compile().as_text()
+    finally:
+        mp.undo()
+
+
+def test_every_joyai_mosaic_call_is_one_the_benchmark_finds(
+        joyai_real_width_hlo):
+    """The resident flash pair at q/k head size 192 over v head size 128
+    (one forward and one fused backward a block, the module's too, none
+    run again), the compiler's grouped expert matmuls and the fused loss
+    head at V = 16 160, twice: each matches a pattern of exactly one of
+    the cell's metric files, and no pattern of another cell's attention
+    or loss-head file claims one.  The compile is also the proof that
+    the pair fits VMEM at 1.5 lane tiles."""
+    mosaic = _mosaic_calls(joyai_real_width_hlo)
+    groups = {m: _patterns(m) for m in (
+        "mla_attn_roofline", "joyai_moe_experts_roofline",
+        "joyai_loss_head_events")}
+    hits = {m: [c for c in mosaic if any(r.search(c) for r in rx)]
+            for m, rx in groups.items()}
+    attention = hits["mla_attn_roofline"]
+    forward = [c for c in attention if groups["mla_attn_roofline"][0]
+               .search(c)]
+    backward = [c for c in attention if groups["mla_attn_roofline"][1]
+                .search(c)]
+    assert (len(forward), len(backward)) == (3, 3), \
+        [c[:100] for c in attention]
+    assert all("bf16[64,8192,128]" in c and "f32[64,8192,1]" in c
+               for c in forward)
+    assert all(c.count("bf16[64,8192,192]") >= 2 for c in backward)
+    # the scope around the module renames its forward call; the patterns
+    # go by the result shapes and find it all the same
+    assert sorted(c.split(".")[0] for c in forward) == [
+        "%jvp__", "%jvp__", "%jvp_mtp_"]
+    assert not any(c.startswith("%rematted_computation")
+                   for c in attention)
+    # two expert layers (one block, the module): three grouped matmuls
+    # forward, three recomputed and six backward each
+    experts = hits["joyai_moe_experts_roofline"]
+    assert sum(not c.startswith("%ragged-dot-metadata")
+               for c in experts) == 24
+    assert len(hits["joyai_loss_head_events"]) == 2
+    assert sum(map(len, hits.values())) == len(mosaic), \
+        [c[:100] for c in mosaic
+         if not any(c in h for h in hits.values())]
+    loop = groups["joyai_loss_head_events"][1]
+    assert sum(bool(loop.search(i))
+               for i in _instructions(joyai_real_width_hlo)) == 2
+    # Of the other cells' files, those that name their shapes claim
+    # nothing here: Qwen3-Next's attention (head size 256), GPT's forward
+    # (one result, no lse) and every other loss head's loop (its own V).
+    # GPT's backward and LFM2's stream patterns are generic in their
+    # shapes and would find this pair too, and every loss head's forward
+    # is the one call: each metric lists its own cell, so none reads
+    # another cell's step
+    for rx in (*_patterns("gattn_roofline"), _patterns("attn_roofline")[0]):
+        assert not any(rx.search(c) for c in mosaic), rx.pattern
+    for other in ("xent_head_roofline", "lfm2_loss_head_events",
+                  "qwen3next_loss_head_events"):
+        assert not any(_patterns(other)[1].search(i)
+                       for i in _instructions(joyai_real_width_hlo)), other
+    # and this cell's attention patterns find nothing in another's step
+    for c in mosaic:
+        assert "bf16[64,8192,256]" not in c and "bf16[128,8192,64]" not in c

@@ -261,3 +261,87 @@ def test_gated_delta_rule_at_the_qwen3_next_cell_s_size_compiles(v5e):
     assert pallas.selections()["gated_delta_rule.mosaic"] == before + 1
     assert _mosaic_calls(low) == 3
     low.compile()
+
+
+# ---------------------------------------------------------------------------
+# the JoyAI-LLM-Flash cell: the kernels at 192 over 128, and the whole step
+# at the cell's size against the chip's memory
+# ---------------------------------------------------------------------------
+def test_flash_attention_at_unequal_head_sizes_compiles(v5e, monkeypatch):
+    """(B x H, T) = (64, 8192), q and k 192 wide (1.5 lane tiles), v 128,
+    bf16 causal, forward and backward: the resident pair takes it under a
+    requested VMEM limit of 61 MB (K rows padded to 256 lanes, V rows
+    128), a forward and a fused backward."""
+    import sys
+    from paddle_tpu.ops import pallas
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+    monkeypatch.setattr(fa, "_vmem_capacity", lambda: 128 << 20)
+    S = _on(v5e[0])
+
+    @jax.jit
+    def fwd_bwd(q, k, v, g):
+        out, vjp = jax.vjp(functools.partial(flash_attention, causal=True),
+                           q, k, v)
+        return out, vjp(g)
+
+    before = pallas.selections().get(
+        "flash_attention.stream_resident.mosaic", 0)
+    qk = S((2, 8192, 32, 192), jnp.bfloat16)
+    v = S((2, 8192, 32, 128), jnp.bfloat16)
+    low = fwd_bwd.lower(qk, qk, v, v)
+    assert pallas.selections()[
+        "flash_attention.stream_resident.mosaic"] > before
+    assert "flash_attention.xla" not in pallas.selections()
+    assert _mosaic_calls(low) == 2
+    low.compile()
+
+
+def test_the_joyai_step_fits_the_chip_at_the_cell_s_size(v5e, monkeypatch):
+    """The whole step of ``joyai-llm-flash.train-t8192`` — the
+    configuration file's model (491.7 M parameters: five layers and the
+    MTP module), B = 2 x T = 8192, bf16 over fp32 masters, ``ctx`` remat —
+    compiled for one v5e: 6 + 6 flash calls and the loss head twice, and
+    arguments + temporaries + the 4 B a parameter the benchmark's check
+    keeps beside the step (a copy of the initial weights, through the
+    first three steps) stay under the chip's ``bytes_limit`` of 16.91 GB
+    with gigabytes to spare (11.42 + 1.97 = 13.38 GB, PR 37)."""
+    import json
+    import os
+    import sys
+    from benchmark.drivers.joyai_train import model_config
+    from paddle_tpu.models.gpt_spmd import build_spmd_train_step
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+    monkeypatch.setattr(fa, "_vmem_capacity", lambda: 128 << 20)
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "configs", "joyai-llm-flash.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(
+            bench, "workloads", "joyai-llm-flash.train-t8192.json")) as f:
+        traffic = json.load(f)["traffic"]
+    cfg = model_config(config)
+    mesh = Mesh(np.asarray(v5e[:1]), ("dp",))
+    step, _ = build_spmd_train_step(
+        cfg, mesh, compute_dtype=jnp.bfloat16,
+        remat_policy=config["assumed"]["remat_policy"])
+    parts = cfg.spmd_parts(mesh)
+    shapes = jax.eval_shape(parts.init, jax.random.PRNGKey(0))
+    n_params = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
+    assert n_params == 491697408
+    params = jax.tree.map(
+        lambda s, ns: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=ns),
+        shapes, parts.shardings)
+    rep = NamedSharding(mesh, P())
+    opt = {"m": params, "v": params,
+           "step": jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)}
+    ids = jax.ShapeDtypeStruct((traffic["batch"], traffic["seq_len"]),
+                               jnp.int32, sharding=rep)
+    compiled = step.lower(params, opt, ids, ids).compile()
+    text = compiled.as_text()
+    assert text.count("bf16[64,8192,192]{2,1,0:T(8,128)(2,1)}, "
+                      "bf16[64,8192,192]") >= 6       # the fused backwards
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + 4 * n_params
+    assert 11e9 < held < 15.9e9, held
