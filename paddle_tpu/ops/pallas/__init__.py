@@ -9,8 +9,10 @@ The kernels: ``flash_attention`` (eleven attention kernels behind one
 plan; the resident pair also at a v head size other than q's and k's:
 latent attention's 192 over 128), ``fused_ln``, ``softmax_xent`` (the
 fused loss head's forward, with an optional weight a row) and
-``gated_delta_rule`` (the linear-attention recurrence over chunks, the
-state in VMEM: forward, state pass, reverse pass).
+``gated_delta_rule`` (the linear-attention recurrence in chunks: the prep
+of a chunk with its triangular inverse in VMEM and its reverse pass; the
+loop over chunks with the state in VMEM: forward, state pass, reverse
+pass).
 
 Every place that chooses between a Mosaic kernel and XLA math asks this
 module, and records what it chose:

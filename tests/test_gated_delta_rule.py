@@ -9,8 +9,12 @@ Every case runs twice: at a small shape that no kernel tiles (the loop
 over chunks is the ``lax.scan``), and at head size 128 with
 ``PADDLE_PALLAS_FORCE=1``, where the loop is the Pallas kernels of
 ``ops/pallas/gated_delta_rule.py`` in interpret mode — the forward, the
-state pass and the hand-written reverse pass.
+state pass and the hand-written reverse pass — and the prep in front of
+it the kernel pair ``delta_rule_prep`` / ``delta_rule_prep_bwd``, held
+here to ``_prep`` (XLA ops, ``solve_triangular``) and its ``jax.vjp``.
 """
+import functools
+
 from typing import NamedTuple
 
 import numpy as np
@@ -217,21 +221,156 @@ def test_the_reverse_pass_is_the_vjp_of_the_scan_form(dtype, tol):
             atol=tol * float(np.abs(f32(b)).max()), err_msg=name)
 
 
+def _padded_row(seed, g_min, T, dtype, chunk=None, heads_k=None):
+    """Row 0 of ``_inputs`` at the kernels' shape, q, k, v in ``dtype``,
+    padded to whole chunks as ``gated_delta_rule`` pads."""
+    shape = SHAPES["kernels"]
+    chunk = chunk or shape.chunk
+    q, k, v, g, beta = (a[0] for a in _inputs(seed, g_min, shape, T=T,
+                                              heads_k=heads_k))
+    pad = lambda a: jnp.pad(   # noqa: E731
+        a, ((0, -T % chunk),) + ((0, 0),) * (a.ndim - 1))
+    return tuple(map(pad, (q.astype(dtype), k.astype(dtype),
+                           v.astype(dtype), g, beta)))
+
+
+def _prep_plan(x, chunk):
+    q, _, v = x[:3]
+    return kernels.plan(v.shape[0] // chunk, v.shape[1], chunk, q.shape[-1],
+                        v.shape[-1], interpret=True, H_k=q.shape[1])
+
+
+OPERANDS = ("w_k", "w_v", "attn", "q_dec", "k_dec", "last")
+PREP_CASES = pytest.mark.parametrize(
+    "T,dtype,tol", [(56, jnp.float32, 1e-5), (64, jnp.float32, 1e-5),
+                    (56, jnp.bfloat16, 2e-2), (64, jnp.bfloat16, 2e-2)],
+    ids=["padded-float32", "whole-float32", "padded-bfloat16",
+         "whole-bfloat16"])
+
+
+def _close(got, want, tol, name):
+    f32 = lambda a: np.asarray(a, np.float32)   # noqa: E731
+    assert got.shape == want.shape and got.dtype == want.dtype, name
+    np.testing.assert_allclose(f32(got), f32(want), rtol=tol,
+                               atol=tol * float(np.abs(f32(want)).max()),
+                               err_msg=name)
+
+
+@PREP_CASES
+def test_the_prep_kernel_makes_the_operands_of_the_xla_prep(T, dtype, tol):
+    """``delta_rule_prep`` (the gates in front of it XLA's) against
+    ``_prep``: the same six operands, the same cast points."""
+    chunk = SHAPES["kernels"].chunk
+    x = _padded_row(8, -2.0, T, dtype)
+    want = rule_module._prep(*x, chunk)
+    got, inv = rule_module._operands(
+        *x[:3], rule_module._gates(*x[3:], chunk), _prep_plan(x, chunk))
+    assert inv is None
+    for name, a, b in zip(OPERANDS, got, want):
+        _close(a[0], b, tol, name)
+
+
+@PREP_CASES
+def test_the_prep_s_reverse_kernel_is_the_vjp_of_the_xla_prep(T, dtype,
+                                                                tol):
+    """``delta_rule_prep_bwd`` against ``jax.vjp`` of ``_prep`` on random
+    cotangents of all six operands; dq and dk come summed over the value
+    heads a key head serves.  In bfloat16 the kernel rounds a cotangent
+    where it enters a product and sums a key head's shares in float32,
+    autodiff in bfloat16."""
+    chunk = SHAPES["kernels"].chunk
+    x = _padded_row(9, -2.0, T, dtype)
+    want, vjp = jax.vjp(functools.partial(rule_module._prep, chunk=chunk),
+                        *x)
+    cotangents = tuple(
+        jax.random.normal(key, w.shape).astype(w.dtype)
+        for key, w in zip(jax.random.split(jax.random.PRNGKey(10), 6),
+                          want))
+    plan = _prep_plan(x, chunk)
+    gates, gates_vjp = jax.vjp(
+        functools.partial(rule_module._gates, chunk=chunk), *x[3:])
+    _, inv = rule_module._operands(*x[:3], gates, plan, with_inverse=True)
+    dq, dk, dv, dG, d_beta = kernels.prep_vjp(
+        *(a[None] for a in (*x[:3], *gates[:2])), inv,
+        *(c[None] for c in cotangents[:5]), plan=plan)
+    got = (dq[0], dk[0], dv[0],
+           *gates_vjp((dG[0], d_beta[0], cotangents[5])))
+    for name, a, b in zip(NAMES, got, vjp(cotangents)):
+        _close(a, b, tol, name)
+
+
+def _corner(chunk):
+    """beta -> 1 over near-parallel keys with hardly any decay: ``I + A``
+    is near the all-ones triangle, whose inverse cancels row against
+    row."""
+    shape = SHAPES["kernels"]
+    T = 2 * chunk
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    k = jax.random.normal(keys[0], (1, shape.H_k, shape.dk)) \
+        + 0.05 * jax.random.normal(keys[1], (T, shape.H_k, shape.dk))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(keys[2], (T, shape.H, shape.dv))
+    g = -1e-3 * jax.random.uniform(keys[3], (T, shape.H))
+    return k, k, v, g, jnp.full((T, shape.H), 0.999)
+
+
+@pytest.mark.parametrize("chunk", [16, 64], ids=["chunk-16", "chunk-64"])
+@pytest.mark.parametrize("case", ["ill-conditioned", "strong-gate"])
+def test_the_inverse_in_vmem_is_solve_triangular_s(case, chunk):
+    """The kernel's float32 inverse of ``I + A`` — forward substitution on
+    the diagonal blocks, then blocks doubled by float32 products (chunk
+    64: two levels) — against ``solve_triangular`` at float32 tolerance:
+    in the ill-conditioned corner, and under the gate down to -20 a
+    token, where the exponent's mask must keep every entry finite."""
+    x = _corner(chunk) if case == "ill-conditioned" \
+        else _padded_row(0, -20.0, 2 * chunk, jnp.float32, chunk)
+    q, k, v, g, beta = x
+    G, b, _ = gates = rule_module._gates(g, beta, chunk)
+    _, inv = rule_module._operands(q, k, v, gates, _prep_plan(x, chunk),
+                                   with_inverse=True)
+    n, H, C = G.shape
+    kk = jnp.repeat(jnp.moveaxis(k.reshape(n, C, *k.shape[1:]), 2, 1),
+                    H // k.shape[1], axis=1)
+    lower = np.tril(np.ones((C, C), bool), -1)
+    with np.errstate(over="ignore"):
+        decay = np.exp(np.where(lower, np.asarray(G)[..., :, None]
+                                - np.asarray(G)[..., None, :], -np.inf))
+    A = np.asarray(b)[..., None] * decay \
+        * np.asarray(jnp.einsum("nhcd,nhsd->nhcs", kk, kk))
+    want = jax.scipy.linalg.solve_triangular(
+        jnp.asarray(A) + jnp.eye(C), jnp.broadcast_to(jnp.eye(C), A.shape),
+        lower=True, unit_diagonal=True)
+    inv = np.asarray(inv[0])
+    assert np.isfinite(inv).all()
+    if case == "ill-conditioned":
+        # rows cancel: the inverse's entries are of order one while
+        # A's below the diagonal are all near one
+        assert A[lower[None, None] & np.ones_like(A, bool)].min() > 0.9
+    else:
+        assert float(np.asarray(G).min()) < -89
+    np.testing.assert_allclose(inv, want, rtol=1e-5, atol=1e-5)
+    assert (inv[..., ~np.tril(np.ones((C, C), bool))] == 0).all()
+
+
 @pytest.mark.parametrize("dk,dv,chunk", [(64, 128, 16), (128, 96, 16),
-                                         (128, 128, 8)],
+                                         (128, 128, 8), (128, 128, 24)],
                          ids=["narrow-keys", "narrow-values",
-                              "chunk-under-a-bf16-tile"])
+                              "chunk-under-a-bf16-tile",
+                              "chunk-of-one-and-a-half-tiles"])
 def test_a_shape_that_does_not_tile_takes_the_xla_loop(monkeypatch, dk, dv,
                                                        chunk):
-    """Which loop runs is one function of the backend and the shapes
-    (``kernels.plan``): with the kernels forced on, a head size that does
-    not fill whole lanes or a chunk under the 16-bit sublane tile still
-    takes the ``lax.scan``, and is counted so."""
+    """Which prep and loop run is one function of the backend and the
+    shapes (``kernels.plan``): with the kernels forced on, a head size
+    that does not fill whole lanes or a chunk that is not whole 16-bit
+    sublane tiles still takes ``_prep`` and the ``lax.scan`` — no kernel
+    of either — and is counted so."""
     monkeypatch.setenv("PADDLE_PALLAS_FORCE", "1")
-    shape = Shape(32, 2, 4, dk, dv, chunk, False)
+    shape = Shape(48 if chunk == 24 else 32, 2, 4, dk, dv, chunk, False)
     assert kernels.plan(shape.T // chunk, shape.H, chunk, dk, dv,
-                        interpret=True) is None
+                        interpret=True, H_k=shape.H_k) is None
     x = _inputs(6, -1.0, shape)
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        functools.partial(gated_delta_rule, chunk=chunk))(*x))
     before = pallas.selections()
     o = gated_delta_rule(*x, chunk=chunk)
     after = pallas.selections()

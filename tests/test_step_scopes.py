@@ -522,35 +522,46 @@ def test_every_qwen3_next_mosaic_call_is_one_the_benchmark_finds(
     """The resident flash pair at head size 256 (one forward, one fused
     backward, nothing run again), the compiler's grouped expert matmuls
     and the fused loss head at V = 18 992: each matches a pattern of
-    exactly one of the cell's metric files.  The rule's own three kernels
-    a delta-rule layer — the forward, and in the backward the state pass
-    and the reverse pass; the forward is not run again, its output is
-    saved by name — match none of those patterns: ``gdn_scan_roofline``
-    finds them by the scope on their path, which is what the device
-    trace's ``tf_op`` holds.  The compile is also the proof that the
-    kernels fit VMEM at the cell's shapes."""
+    exactly one of the cell's metric files.  The rule's own kernels a
+    delta-rule layer — the prep and the loop's forward; in the backward
+    the prep again (it writes the inverse this time), the state pass, the
+    loop's reverse pass and the prep's; the forward is not run again, its
+    output is saved by name — match none of those patterns:
+    ``gdn_scan_roofline`` finds them by the scope on their path, which is
+    what the device trace's ``tf_op`` holds.  The compile is also the
+    proof that the kernels fit VMEM at the cell's shapes."""
     mosaic = _mosaic_calls(qwen3_next_real_width_hlo)
     groups = {m: _patterns(m) for m in (
         "gattn_roofline", "qwen3next_moe_experts_roofline",
         "qwen3next_loss_head_events")}
     rule = [c for c in mosaic if c.startswith("%delta_rule_")]
-    assert sorted(c.split(".")[0] for c in rule) == [
-        "%delta_rule_bwd", "%delta_rule_fwd", "%delta_rule_states"]
+    phases = sorted(
+        (c.split(".")[0],
+         classify(re.search(r'op_name="([^"]*)"', c).group(1),
+                  set(QWEN3_NEXT_SCOPES)))
+        for c in rule)
+    assert phases == [
+        ("%delta_rule_bwd", ("gdn_scan", "backward")),
+        ("%delta_rule_fwd", ("gdn_scan", "forward")),
+        ("%delta_rule_prep", ("gdn_scan", "backward")),
+        ("%delta_rule_prep", ("gdn_scan", "forward")),
+        ("%delta_rule_prep_bwd", ("gdn_scan", "backward")),
+        ("%delta_rule_states", ("gdn_scan", "backward"))], phases
     for c in rule:
-        op_name = re.search(r'op_name="([^"]*)"', c).group(1)
-        scope, phase = classify(op_name, set(QWEN3_NEXT_SCOPES))
-        assert scope == "gdn_scan", op_name
-        assert phase == ("forward" if c.startswith("%delta_rule_fwd")
-                         else "backward"), op_name
         assert not any(r.search(c) for rx in groups.values() for r in rx)
     # the loop over chunks is the kernels' grid: what is left under the
     # scope loops over the batch rows at most (the tiny CPU step above
     # nests the chunks' loop in the rows': depth 2), and no operation of
-    # the scan's step — a product with the state — is XLA's any more
+    # the scan's step — a product with the state — is XLA's any more;
+    # nor is the prep: no inverse by ``solve_triangular``, no ``K K^T``
+    # or ``Q K^T`` over key heads repeated in HBM
     under = [n for n in _op_names(qwen3_next_real_width_hlo)
              if _under(n, ["gdn_scan"])]
     assert under and max(n.count("while/body") for n in under) <= 1
-    assert not any("hcd,hde->hce" in n for n in under)
+    assert not any("hcd,hde->hce" in n or "nhcd,nhsd->nhcs" in n
+                   or "triangular_solve" in n for n in under)
+    assert not any("triangular" in i.lower() or "InvertDiagBlocks" in i
+                   for i in _instructions(qwen3_next_real_width_hlo))
     mosaic = [c for c in mosaic if c not in rule]
     hits = {m: [c for c in mosaic if any(r.search(c) for r in rx)]
             for m, rx in groups.items()}

@@ -15,6 +15,7 @@ A compile proves lowering, tiling and VMEM fit; numerics need the chip
 (``python chip_smoke.py`` through the chip tool).
 """
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -240,9 +241,10 @@ def test_loss_head_at_the_qwen3_next_cell_s_size_compiles(v5e):
 
 
 def test_gated_delta_rule_at_the_qwen3_next_cell_s_size_compiles(v5e):
-    """(4, 8192, 16 key / 32 value heads, d 128), chunk 64, bf16: the
-    forward kernel; in the backward the state pass and the reverse pass
-    a row at a time — three Mosaic calls, each within VMEM."""
+    """(4, 8192, 16 key / 32 value heads, d 128), chunk 64, bf16, a row at
+    a time: the prep and the forward kernel; in the backward the prep
+    again, the state pass, the loop's reverse pass and the prep's — six
+    Mosaic calls, each within VMEM, and no inverse left to XLA."""
     from paddle_tpu.ops import pallas
     from paddle_tpu.ops.gated_delta_rule import gated_delta_rule
     S = _on(v5e[0])
@@ -259,8 +261,77 @@ def test_gated_delta_rule_at_the_qwen3_next_cell_s_size_compiles(v5e):
     gate = S((4, 8192, 32), jnp.float32)
     low = fwd_bwd.lower(qk, qk, v, gate, gate, v)
     assert pallas.selections()["gated_delta_rule.mosaic"] == before + 1
-    assert _mosaic_calls(low) == 3
-    low.compile()
+    assert _mosaic_calls(low) == 6
+    assert "triangular" not in low.compile().as_text().lower()
+
+
+def _cell_step(v5e, monkeypatch, driver: str, config: str, workload: str):
+    """The whole step of a benchmark cell — the configuration file's
+    model at the cell's batch, bf16 over fp32 masters, the file's remat
+    policy — compiled for one v5e.  -> (the compiled step, its number of
+    parameters)."""
+    import importlib
+    import json
+    import os
+    import sys
+    from paddle_tpu.models.gpt_spmd import build_spmd_train_step
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+    monkeypatch.setattr(fa, "_vmem_capacity", lambda: 128 << 20)
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "configs", f"{config}.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(bench, "workloads", f"{workload}.json")) as f:
+        traffic = json.load(f)["traffic"]
+    cfg = importlib.import_module(
+        f"benchmark.drivers.{driver}").model_config(config)
+    mesh = Mesh(np.asarray(v5e[:1]), ("dp",))
+    step, _ = build_spmd_train_step(
+        cfg, mesh, compute_dtype=jnp.bfloat16,
+        remat_policy=config["assumed"]["remat_policy"])
+    parts = cfg.spmd_parts(mesh)
+    shapes = jax.eval_shape(parts.init, jax.random.PRNGKey(0))
+    n_params = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
+    params = jax.tree.map(
+        lambda s, ns: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=ns),
+        shapes, parts.shardings)
+    rep = NamedSharding(mesh, P())
+    opt = {"m": params, "v": params,
+           "step": jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)}
+    ids = jax.ShapeDtypeStruct((traffic["batch"], traffic["seq_len"]),
+                               jnp.int32, sharding=rep)
+    return step.lower(params, opt, ids, ids).compile(), n_params
+
+
+def _held(compiled, n_params: int) -> int:
+    """Arguments + temporaries + the 4 B a parameter the benchmark's check
+    keeps beside the step (a copy of the initial weights, through the
+    first three steps)."""
+    mem = compiled.memory_analysis()
+    return mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + 4 * n_params
+
+
+def test_the_qwen3_next_step_fits_the_chip_at_the_cell_s_size(
+        v5e, monkeypatch, capsys):
+    """The whole step of ``qwen3-next-80b-a3b.train-t8192`` (424.3 M
+    parameters, B = 4 x T = 8192) compiled for one v5e: six delta-rule
+    kernels a layer in three layers, no ``triangular_solve``, and what the
+    step holds stays under the chip's ``bytes_limit`` of 16.91 GB and is
+    no more than with XLA's prep (PR 37's step, compiled the same way:
+    14.502 GB against 13.31; autodiff's float32 ``(n, H, C, C)`` residuals of a row
+    went)."""
+    compiled, n_params = _cell_step(
+        v5e, monkeypatch, "qwen3_next_train", "qwen3-next-80b-a3b",
+        "qwen3-next-80b-a3b.train-t8192")
+    text = compiled.as_text()
+    assert len(set(re.findall(r"%delta_rule_\w+\.\d+ = ", text))) == 18
+    assert "triangular" not in text.lower()
+    held = _held(compiled, n_params)
+    with capsys.disabled():
+        print(f"\nqwen3-next step, chip-free: {held / 1e9:.3f} GB held "
+              f"(the parent's, with XLA's prep: 14.502 GB)")
+    assert 11e9 < held < 15.9e9, held
 
 
 # ---------------------------------------------------------------------------
@@ -302,46 +373,15 @@ def test_the_joyai_step_fits_the_chip_at_the_cell_s_size(v5e, monkeypatch):
     configuration file's model (491.7 M parameters: five layers and the
     MTP module), B = 2 x T = 8192, bf16 over fp32 masters, ``ctx`` remat —
     compiled for one v5e: 6 + 6 flash calls and the loss head twice, and
-    arguments + temporaries + the 4 B a parameter the benchmark's check
-    keeps beside the step (a copy of the initial weights, through the
-    first three steps) stay under the chip's ``bytes_limit`` of 16.91 GB
-    with gigabytes to spare (11.42 + 1.97 = 13.38 GB, PR 37)."""
-    import json
-    import os
-    import sys
-    from benchmark.drivers.joyai_train import model_config
-    from paddle_tpu.models.gpt_spmd import build_spmd_train_step
-    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
-    monkeypatch.setattr(fa, "_vmem_capacity", lambda: 128 << 20)
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark")
-    with open(os.path.join(bench, "configs", "joyai-llm-flash.json")) as f:
-        config = json.load(f)
-    with open(os.path.join(
-            bench, "workloads", "joyai-llm-flash.train-t8192.json")) as f:
-        traffic = json.load(f)["traffic"]
-    cfg = model_config(config)
-    mesh = Mesh(np.asarray(v5e[:1]), ("dp",))
-    step, _ = build_spmd_train_step(
-        cfg, mesh, compute_dtype=jnp.bfloat16,
-        remat_policy=config["assumed"]["remat_policy"])
-    parts = cfg.spmd_parts(mesh)
-    shapes = jax.eval_shape(parts.init, jax.random.PRNGKey(0))
-    n_params = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
+    what the step holds (``_held``) stays under the chip's ``bytes_limit``
+    of 16.91 GB with gigabytes to spare (11.42 + 1.97 = 13.38 GB, PR
+    37)."""
+    compiled, n_params = _cell_step(
+        v5e, monkeypatch, "joyai_train", "joyai-llm-flash",
+        "joyai-llm-flash.train-t8192")
     assert n_params == 491697408
-    params = jax.tree.map(
-        lambda s, ns: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=ns),
-        shapes, parts.shardings)
-    rep = NamedSharding(mesh, P())
-    opt = {"m": params, "v": params,
-           "step": jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)}
-    ids = jax.ShapeDtypeStruct((traffic["batch"], traffic["seq_len"]),
-                               jnp.int32, sharding=rep)
-    compiled = step.lower(params, opt, ids, ids).compile()
     text = compiled.as_text()
     assert text.count("bf16[64,8192,192]{2,1,0:T(8,128)(2,1)}, "
                       "bf16[64,8192,192]") >= 6       # the fused backwards
-    mem = compiled.memory_analysis()
-    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
-        + 4 * n_params
+    held = _held(compiled, n_params)
     assert 11e9 < held < 15.9e9, held
