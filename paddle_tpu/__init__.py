@@ -14,6 +14,10 @@ The public namespace mirrors ``paddle.*`` so reference users can switch.
 """
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_START_NS = _time.time_ns()   # the launch record's ``import`` span
+
 __version__ = "0.1.0"
 
 # core surface
@@ -100,3 +104,7 @@ def is_compiled_with_xpu() -> bool:
 
 def is_compiled_with_npu() -> bool:
     return False
+
+
+profiler_mod.tracer.record_launch("import", _IMPORT_START_NS,
+                                  _time.time_ns(), fun="paddle_tpu")

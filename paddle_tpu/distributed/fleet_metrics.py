@@ -8,10 +8,8 @@ whole gang — is where the fleet view belongs.  Three pieces:
   :func:`publish` at the same cadence as the supervise heartbeat,
   putting a JSON registry snapshot under a generation-prefixed Store
   key (``/paddle/fleetmetrics/<job>/g<gen>/<rank>``).  The payload
-  carries a ``clock`` pair (``perf_ns``, ``unix``) so per-rank
-  chrome traces — whose timestamps are process-local
-  ``perf_counter_ns`` values — can be aligned onto one wall-clock
-  axis later.
+  carries a ``clock`` pair (``perf_ns``, ``unix``): when it was taken,
+  on both of the process's clocks.
 - **aggregate** (supervisor side): :func:`collect` +
   :func:`aggregate_prometheus` merge the per-rank snapshots into one
   Prometheus text document where every series carries a ``rank``
@@ -22,9 +20,10 @@ whole gang — is where the fleet view belongs.  Three pieces:
   ``PADDLE_FLEET_METRICS_PORT`` is set.
 - **trace merge**: :func:`merge_chrome_traces` folds per-rank chrome
   traces (written by :func:`write_rank_trace`) into one rank-laned
-  timeline — each rank becomes a ``pid`` lane, and the heartbeat
-  clock pairs shift every rank's timestamps onto the shared unix
-  axis, so a cross-rank stall reads as the horizontal gap it is.
+  timeline — each rank becomes a ``pid`` lane.  An exported trace's
+  ``ts`` is already on the Unix-epoch clock (``profiler/tracer.py``),
+  the one axis every host shares, so the lanes need no shift and a
+  cross-rank stall reads as the horizontal gap it is.
 """
 from __future__ import annotations
 
@@ -50,9 +49,10 @@ def metrics_key(job: str, generation, rank) -> str:
 
 def clock_pair() -> Dict[str, float]:
     """A ``(perf_ns, unix)`` sample of this process's two clocks.
-    Tracer span timestamps are ``perf_counter_ns`` values with a
-    process-local epoch; the pair lets a merger map them onto the
-    shared unix axis: ``unix_at(ts) = unix + (ts - perf_ns) / 1e9``."""
+    ``tracer.events()`` timestamps are ``perf_counter_ns`` values with
+    a process-local epoch; the pair maps them onto the shared unix
+    axis: ``unix_at(ts) = unix + (ts - perf_ns) / 1e9``.  (An exported
+    trace needs no pair: its ``ts`` is on the unix axis already.)"""
     return {"perf_ns": time.perf_counter_ns(), "unix": time.time()}
 
 
@@ -146,7 +146,9 @@ def aggregate_prometheus(per_rank: Dict[str, dict]) -> str:
 def write_rank_trace(path: str, rank=None,
                      events: Optional[list] = None) -> str:
     """Export this process's tracer ring as a chrome trace carrying
-    the rank + clock metadata :func:`merge_chrome_traces` aligns on."""
+    the rank and the clock its ``ts`` is on (``"unix"``: what
+    ``tracer.chrome_trace_dict`` writes), which
+    :func:`merge_chrome_traces` checks before it calls lanes aligned."""
     import os
 
     from ..profiler import tracer as _tracer
@@ -154,7 +156,7 @@ def write_rank_trace(path: str, rank=None,
     doc["metadata"] = {
         "rank": str(rank if rank is not None
                     else os.environ.get("PADDLE_TRAINER_ID", "0")),
-        "clock": clock_pair(),
+        "clock": "unix",
     }
     d = os.path.dirname(os.path.abspath(path))
     os.makedirs(d, exist_ok=True)
@@ -167,34 +169,23 @@ def merge_chrome_traces(docs: List[dict]) -> dict:
     """One rank-laned timeline from per-rank chrome traces.
 
     Every input doc (as written by :func:`write_rank_trace`) becomes
-    one ``pid`` lane named ``rank <r>``; each event's process-local
-    ``perf_counter`` timestamp is shifted onto the shared unix axis
-    via the doc's clock pair, then the whole timeline is rebased so
-    t=0 is the earliest event (keeps Perfetto's axis readable).  Docs
-    without clock metadata keep their own timebase (lane still
-    separate, alignment impossible — better partial than dropped)."""
+    one ``pid`` lane named ``rank <r>``.  The docs' ``ts`` are on the
+    Unix-epoch clock, shared across hosts, so no lane is shifted
+    against another; the whole timeline is rebased so t=0 is the
+    earliest event (keeps Perfetto's axis readable).  A doc that does
+    not say its clock is ``"unix"`` keeps its lane and its own
+    timebase, and the result says ``aligned: False`` (better partial
+    than dropped)."""
     lanes = []
     for i, doc in enumerate(docs):
         meta = doc.get("metadata") or {}
-        rank = str(meta.get("rank", i))
-        clock = meta.get("clock") or {}
-        # unix time (in us) of this process's perf_counter epoch
-        off_us = None
-        if "perf_ns" in clock and "unix" in clock:
-            off_us = float(clock["unix"]) * 1e6 \
-                - float(clock["perf_ns"]) / 1e3
-        lanes.append((rank, off_us, doc.get("traceEvents") or []))
-    base = None
-    for _rank, off_us, evs in lanes:
-        for e in evs:
-            if e.get("ph") != "X":
-                continue
-            t = float(e.get("ts", 0.0)) + (off_us or 0.0)
-            if base is None or t < base:
-                base = t
-    base = base or 0.0
+        lanes.append((str(meta.get("rank", i)), meta.get("clock") == "unix",
+                      [e for e in doc.get("traceEvents") or []
+                       if e.get("ph") == "X"]))
+    base = min((float(e.get("ts", 0.0)) for _r, _u, evs in lanes
+                for e in evs), default=0.0)
     merged = []
-    for li, (rank, off_us, evs) in enumerate(lanes):
+    for li, (rank, _unix, evs) in enumerate(lanes):
         try:
             pid = int(rank)
         except ValueError:
@@ -202,16 +193,13 @@ def merge_chrome_traces(docs: List[dict]) -> dict:
         merged.append({"ph": "M", "name": "process_name", "pid": pid,
                        "tid": 0, "args": {"name": f"rank {rank}"}})
         for e in evs:
-            if e.get("ph") != "X":
-                continue
             e2 = dict(e)
             e2["pid"] = pid
-            e2["ts"] = float(e.get("ts", 0.0)) + (off_us or 0.0) - base
+            e2["ts"] = float(e.get("ts", 0.0)) - base
             merged.append(e2)
     return {"traceEvents": merged, "displayTimeUnit": "ms",
-            "metadata": {"ranks": [r for r, _o, _e in lanes],
-                         "aligned": all(o is not None
-                                        for _r, o, _e in lanes)}}
+            "metadata": {"ranks": [r for r, _u, _e in lanes],
+                         "aligned": all(u for _r, u, _e in lanes)}}
 
 
 class FleetMetricsServer:

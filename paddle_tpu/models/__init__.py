@@ -15,6 +15,10 @@ shared expert, and a multi-token-prediction module scored by the one loss
 head a second time.  ``sparse_blocks`` holds what the three sparse models
 share.
 """
+import time as _time
+
+_IMPORT_START_NS = _time.time_ns()   # the launch record's ``import`` span
+
 from .gpt import GPTConfig, GPT, GPTBlock  # noqa: F401
 from .gpt_spmd import (init_gpt_params, build_spmd_train_step,  # noqa: F401
                        gpt_param_shardings)
@@ -26,3 +30,8 @@ from .qwen3_next import (Qwen3NextConfig,  # noqa: F401
 from .joyai_flash import (JoyAIFlashConfig,  # noqa: F401
                           init_joyai_flash_params,
                           joyai_flash_param_shardings)
+from ..profiler import tracer as _tracer  # noqa: E402
+
+# the step builders bring jax.experimental.pallas in, most of this span
+_tracer.record_launch("import", _IMPORT_START_NS, _time.time_ns(),
+                      fun="paddle_tpu.models")

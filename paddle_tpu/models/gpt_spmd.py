@@ -638,3 +638,29 @@ def build_spmd_train_step(cfg, mesh: Mesh,
     # host-memory inputs onto device-memory outputs, so skip its donation
     donate = (0,) if offload else (0, 1)
     return jax.jit(train_step, donate_argnums=donate), init_fn
+
+
+def _with_build_span(build):
+    """``build`` with its call, entry to return, in the launch record
+    (``profiler/tracer.py``) as the ``build`` span of the step it
+    returns.  Below the builder and with its imports inside, so that no
+    line above moves: the Mosaic kernel bodies carry the line numbers of
+    their call sites in this file, and the persistent compile cache's key
+    hashes those bodies."""
+    import functools
+    import time
+
+    from ..profiler import tracer
+
+    @functools.wraps(build)
+    def build_and_record(*args, **kwargs):
+        start_ns = time.time_ns()
+        step, init_fn = build(*args, **kwargs)
+        tracer.record_launch("build", start_ns, time.time_ns(),
+                             fun=step.__name__)
+        return step, init_fn
+
+    return build_and_record
+
+
+build_spmd_train_step = _with_build_span(build_spmd_train_step)

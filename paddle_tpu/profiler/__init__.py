@@ -10,6 +10,23 @@ TensorBoard/Perfetto; host spans are collected by the pure-Python
 :mod:`.tracer` (always available) and, when the optional native ``.so``
 is loaded, the C++ ring buffer as well.  Metrics (counters / gauges /
 histograms fed by the instrumented hot paths) live in :mod:`.metrics`.
+
+**Always on:** the launch record (:mod:`.tracer`: a ``cat="launch"``
+span for every trace, lowering and backend compile or persistent-cache
+fetch JAX makes, for the package's import and a train step's build;
+``tracer.launch_report()``; the counters ``compile.backend``,
+``compile.cache_hit``, ``compile.cache_miss``, ``compile.recompiles``,
+``launch.dropped``) and the flight recorder (:mod:`.flight`, which also
+gets a ``mem`` / ``compile`` note for every recompile of a step the
+program built).  Importing this
+package registers the only ``jax.monitoring`` listeners of
+``paddle_tpu``.  **Behind ``tracer.active``** (``enable_host_tracer``,
+a ``Profiler`` window): every other host span and the dispatch,
+collective, dataloader and step metrics.  Behind flags of their own:
+:mod:`.memscope` (``FLAGS_mem_accounting``; its compile ledger is the
+call sites' annotation of what the launch spans time from inside) and
+:mod:`.rtrace`.  ``export_chrome_tracing`` writes all of it on one clock,
+the Unix epoch's.
 """
 from __future__ import annotations
 
@@ -167,13 +184,18 @@ def disable_host_tracer():
 
 
 def _native_trace_events():
-    """traceEvents recorded by the native collector (merged on export)."""
+    """traceEvents recorded by the native collector (merged on export),
+    taken from its own clock (``steady_clock``) to the Unix epoch's like
+    every other exported span."""
     NP = _native["cls"]
     if NP is None:
         return []
     try:
         if not NP.event_count():
             return []
+        # read once, like ``tracer.EPOCH_OFFSET_NS``: two exports agree
+        off_us = _native.setdefault(
+            "epoch_offset_ns", time.time_ns() - NP.now_ns()) / 1e3
         import tempfile
         fd, tmp = tempfile.mkstemp(suffix=".json")
         os.close(fd)
@@ -184,6 +206,8 @@ def _native_trace_events():
             evs = data.get("traceEvents", [])
             for e in evs:
                 e.setdefault("cat", "native")
+                if "ts" in e:
+                    e["ts"] += off_us
             return evs
         finally:
             os.unlink(tmp)

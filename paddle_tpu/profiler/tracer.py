@@ -2,11 +2,32 @@
 
 Reference parity: ``platform/profiler.h:216`` (RecordEvent host events,
 bounded event buffer, chrome-trace report).  This is the always-available
-collector — no native ``.so``, no jax import — so every layer of the
+collector — no native ``.so``, nothing of jax but its ``monitoring``
+registry — so every layer of the
 framework can be instrumented unconditionally and the whole thing still
 works in a bare interpreter.  Device-side traces remain jax.profiler's
 job (TensorBoard/Perfetto); the file this module exports can be loaded
 into the same Perfetto UI alongside them.
+
+Two records live here.  **Always on: the launch ring.**  Every trace,
+lowering and backend compile (or persistent-cache fetch) that JAX makes
+is one ``cat="launch"`` span, fed by ``jax.monitoring``'s own listeners
+(registered once, below) and by two spans of the program's own
+(``import`` of the package, ``build`` of a train step).  No switch: a
+launch is over before anyone could turn tracing on, and the listeners
+run only when JAX traces, lowers or compiles — a steady step makes no
+call into them.  :func:`launch_report` reduces the ring to seconds a
+phase and a function.  **Behind ``active``: everything else** (dispatch,
+collective, dataloader, hapi, serving, rtrace and ``RecordEvent``
+spans).
+
+Clocks: durations are measured on ``now_ns`` (``perf_counter_ns``);
+launch spans arrive on the Unix-epoch clock ``jax.monitoring`` hands
+over.  ``EPOCH_OFFSET_NS``, read once at import, takes the first to the
+second, and everything that leaves the process (``chrome_trace_dict``,
+``export_chrome_tracing``, ``launch_report``) is on the epoch clock —
+the clock a device trace's events are on once its ``Task Environment``
+plane's ``profile_start_time`` is added to them (PERF.md section 3).
 
 Hot-path contract: ``active`` is a module-level bool.  Instrumented code
 does ONE predicate read when tracing is off::
@@ -32,12 +53,15 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from ..utils import concurrency as _conc
 from ..utils import flags as _flags
+from . import flight as _flight
 from . import metrics as _metrics
 
 __all__ = ["active", "enable", "disable", "is_enabled", "clear", "events",
            "drain", "record", "now_ns", "chrome_trace_dict",
            "export_chrome_tracing", "summarize", "op_table",
-           "op_phase", "phase_shares", "OP_PHASES"]
+           "op_phase", "phase_shares", "OP_PHASES",
+           "EPOCH_OFFSET_NS", "LAUNCH_ID", "record_launch",
+           "launch_events", "launch_spans", "launch_report"]
 
 # module-level fast predicate — the single check hot paths gate on
 active = False
@@ -49,6 +73,10 @@ _events: collections.deque = collections.deque(maxlen=1 << 20)
 _Event = Tuple[str, int, int, int, str, Optional[dict]]
 
 now_ns = time.perf_counter_ns
+
+# now_ns() + EPOCH_OFFSET_NS is the Unix-epoch clock: taken once, so two
+# exports of one process agree
+EPOCH_OFFSET_NS = time.time_ns() - time.perf_counter_ns()
 
 
 def enable(capacity: Optional[int] = None):
@@ -93,6 +121,222 @@ def record(name: str, start_ns: int, end_ns: int, tid: Optional[int] = None,
     _events.append((name, start_ns, end_ns,
                     tid if tid is not None
                     else threading.get_ident() % (1 << 31), cat, args))
+
+
+# ---------------------------------------------------------------------------
+# the launch record — always on (see the module docstring)
+# ---------------------------------------------------------------------------
+
+# one identifier for every launch span of this process
+LAUNCH_ID = f"{os.getpid()}-{time.time_ns()}"
+
+# spans on the Unix-epoch nanosecond clock, same tuple layout as above
+# with cat="launch".  The launches of the benchmark's cells record 2.0 k
+# (GPT) to 7.4 k spans (Qwen3-Next; PERF.md section 3), 11.0 k by the
+# process's end with the benchmark's float32 reference, so the largest
+# fits four times over and its whole process three times; eager mode
+# compiles many small functions, hence the bound (about 11 MB when full).
+_launch: collections.deque = collections.deque(maxlen=1 << 15)
+
+_JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+# what the persistent cache said inside the backend span now open on
+# this thread: its events arrive before the span that wraps them
+_cache_said = threading.local()
+# the functions the program named with a ``build`` span -> how often each
+# has reached the backend since.  Only these have an identity: JAX hands
+# over a name, and ``<lambda>`` or an eager op's is shared by many
+# functions, each compiled once
+_built: Dict[str, int] = {}
+# compiles run on any thread; a plain lock (the instrumented ones feed
+# the metrics registry) for the ring's drop count and the backend counts
+_launch_lock = threading.Lock()
+
+
+def record_launch(name: str, start_ns: int, end_ns: int, fun: str,
+                  **fields):
+    """Append one launch span.  Timestamps are ``time.time_ns()``
+    values; ``fun`` is the function the span belongs to.  A ``build``
+    span names ``fun`` as a function of the program's own, new with this
+    build: from then on its second arrival at the backend is a
+    recompile."""
+    event = (name, int(start_ns), int(end_ns),
+             threading.get_ident() % (1 << 31), "launch",
+             {"fun": fun, **fields})
+    with _launch_lock:
+        if name == "build":
+            _built[fun] = 0
+        if len(_launch) == _launch.maxlen:
+            _metrics.counter(
+                "launch.dropped", "launch spans the full ring dropped "
+                "(oldest first)").inc()
+        _launch.append(event)
+
+
+def _on_jax_event(event, **_):
+    if event == "/jax/compilation_cache/compile_requests_use_cache":
+        _cache_said.cache = "miss"      # until a hit says otherwise
+    elif event == "/jax/compilation_cache/cache_hits":
+        _cache_said.cache = "hit"
+
+
+def _on_jax_duration(event, secs, **_):
+    if event == "/jax/compilation_cache/cache_retrieval_time_sec":
+        _cache_said.retrieval_s = secs
+
+
+def _on_jax_time_span(event, start, end, fun_name="", **_):
+    phase = _JAX_PHASES.get(event)
+    if phase is None:
+        return
+    # the trace is named ``f``, lowering and backend ``jit(f)``
+    fun = fun_name[4:-1] if fun_name.startswith("jit(") \
+        and fun_name.endswith(")") else fun_name
+    start_ns, end_ns = int(start * 1e9), int(end * 1e9)
+    if phase != "backend":
+        record_launch(phase, start_ns, end_ns, fun)
+        return
+    cache = _cache_said.__dict__.pop("cache", "off")
+    fields = {"cache": cache}
+    if cache == "hit":
+        fields["retrieval_s"] = _cache_said.__dict__.pop("retrieval_s", 0.0)
+    record_launch(phase, start_ns, end_ns, fun, **fields)
+    _metrics.counter("compile.backend", "functions that reached the "
+                     "backend: a compile, or a fetch from the persistent "
+                     "cache").inc()
+    if cache != "off":
+        _metrics.counter(f"compile.cache_{cache}").inc()
+    with _launch_lock:
+        before = _built.get(fun)
+        if before is not None:
+            _built[fun] = before + 1
+    if before:
+        _metrics.counter("compile.recompiles", "backend compiles of a "
+                         "function the program built (a `build` span) "
+                         "that had reached the backend since").inc()
+        if _flight.active:
+            _flight.note("mem", "compile", site=fun, cause="retrace",
+                         provenance="jit", cache=cache,
+                         wall_ms=round((end - start) * 1e3, 1))
+
+
+def _listen_to_jax():
+    try:
+        from jax import monitoring
+    except ImportError:         # a bare interpreter: only record_launch
+        return
+    monitoring.register_event_listener(_on_jax_event)
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    monitoring.register_event_time_span_listener(_on_jax_time_span)
+
+
+_listen_to_jax()
+
+
+def launch_events() -> List[_Event]:
+    return list(_launch)
+
+
+def _union_ns(intervals) -> int:
+    """Nanoseconds covered by any of the (start, end) intervals."""
+    total, reach = 0, None
+    for a, b in sorted(intervals):
+        if reach is None or a > reach:
+            total, reach = total + b - a, b
+        elif b > reach:
+            total, reach = total + b - reach, b
+    return total
+
+
+def launch_spans(evs: Optional[List[_Event]] = None) -> List[dict]:
+    """The launch spans as dicts, oldest first, each with its ``id``,
+    the ``parent`` that caused it — the innermost launch span of the
+    same thread that contains it, ``None`` for a root — the ``root`` it
+    lies under (itself, for a root) and its ``self_ns``: its duration
+    less what its children cover."""
+    evs = launch_events() if evs is None else evs
+    spans = [{"id": i, "name": name, "start_ns": t0, "end_ns": t1,
+              "tid": tid, "parent": None, "root": i, **args}
+             for i, (name, t0, t1, tid, _cat, args) in enumerate(evs)]
+    covered: Dict[int, list] = {}
+    open_on: Dict[int, list] = {}
+    # the spans arrive children first; by start, a parent comes first
+    for s in sorted(spans, key=lambda s: (s["start_ns"], -s["end_ns"])):
+        stack = open_on.setdefault(s["tid"], [])
+        while stack and stack[-1]["end_ns"] < s["end_ns"]:
+            stack.pop()
+        if stack:
+            s["parent"], s["root"] = stack[-1]["id"], stack[-1]["root"]
+            covered.setdefault(s["parent"], []).append(
+                (s["start_ns"], s["end_ns"]))
+        stack.append(s)
+    for s in spans:
+        s["self_ns"] = s["end_ns"] - s["start_ns"] \
+            - _union_ns(covered.get(s["id"], ()))
+    return spans
+
+
+def launch_report(evs: Optional[List[_Event]] = None) -> dict:
+    """The launch by root function (a span with no parent belongs to
+    the function it names)::
+
+        {"launch": LAUNCH_ID, "spans": n, "dropped": n,
+         "functions": {fun: {
+             "seconds": {"trace": s, "lower": s, "backend": s, ...},
+             "self_seconds": {"trace": s, ...},
+             "cache": {"hit": n, "miss": n, "off": n},
+             "retrieval_s": s, "compiles": n,
+             "children": {fun: {"self_s": s, "total_s": s, "spans": n}}}}}
+
+    ``seconds`` is the union of a phase's root intervals, so a nested
+    inner jit's trace is not added to its caller's twice, and
+    ``self_seconds`` what of it no child covers; ``children`` are all
+    spans below the function's roots, by the function they name, with
+    their self time and the union of their intervals (``total_s``: a
+    launcher with the kernel body it traces); ``compiles`` is how often
+    a function of that name reached the backend — of a step the program
+    built, more than 1 is a recompile (``compile.recompiles``, a flight
+    note); of ``<lambda>`` or an eager op it is as many functions, or
+    shapes of one."""
+    return _report_of(launch_spans(evs))
+
+
+def _report_of(spans: List[dict]) -> dict:
+    functions: Dict[str, dict] = {}
+    phase_ivs: Dict[tuple, list] = {}       # (root function, phase)
+    child_ivs: Dict[tuple, list] = {}       # (root function, child)
+    for s in spans:
+        root = spans[s["root"]]
+        f = functions.setdefault(root["fun"], {
+            "seconds": {}, "self_seconds": {},
+            "cache": {"hit": 0, "miss": 0, "off": 0}, "retrieval_s": 0.0,
+            "compiles": 0, "children": {}})
+        interval = (s["start_ns"], s["end_ns"])
+        if s is not root:
+            child = f["children"].setdefault(
+                s["fun"], {"self_s": 0.0, "total_s": 0.0, "spans": 0})
+            child["self_s"] += s["self_ns"] / 1e9
+            child["spans"] += 1
+            child_ivs.setdefault((root["fun"], s["fun"]), []).append(interval)
+            continue
+        f["self_seconds"][s["name"]] = \
+            f["self_seconds"].get(s["name"], 0.0) + s["self_ns"] / 1e9
+        phase_ivs.setdefault((s["fun"], s["name"]), []).append(interval)
+        if s["name"] == "backend":
+            f["compiles"] += 1
+            f["cache"][s["cache"]] += 1
+            f["retrieval_s"] += s.get("retrieval_s", 0.0)
+    for (fun, phase), ivs in phase_ivs.items():
+        functions[fun]["seconds"][phase] = _union_ns(ivs) / 1e9
+    for (fun, child), ivs in child_ivs.items():
+        functions[fun]["children"][child]["total_s"] = _union_ns(ivs) / 1e9
+    dropped = _metrics.get("launch.dropped")
+    return {"launch": LAUNCH_ID, "spans": len(spans),
+            "dropped": dropped.value if dropped else 0,
+            "functions": functions}
 
 
 # ---------------------------------------------------------------------------
@@ -214,20 +458,37 @@ def on_hapi_step(start_ns: int, num_samples: int = 0, mode: str = "train"):
 # ---------------------------------------------------------------------------
 
 def chrome_trace_dict(evs: Optional[List[_Event]] = None) -> dict:
-    """chrome://tracing document ('X' complete events; ts/dur in us).
-    Overlapping spans on one tid render nested in Perfetto/chrome."""
-    if evs is None:
-        evs = events()
+    """chrome://tracing document ('X' complete events; ts/dur in us,
+    ts on the Unix-epoch clock).  Overlapping spans on one tid render
+    nested in Perfetto/chrome.  With no ``evs`` given the document is
+    the whole process: the buffered spans, the launch spans (each with
+    its ``id`` and ``parent``) and, under ``launchReport``,
+    :func:`launch_report`."""
     pid = os.getpid()
-    tevs = []
-    for name, t0, t1, tid, cat, args in evs:
+
+    def doc_event(name, ts_ns, dur_ns, tid, cat, args):
         e = {"name": name, "cat": cat or "host", "ph": "X",
-             "ts": t0 / 1e3, "dur": (t1 - t0) / 1e3,
-             "pid": pid, "tid": tid}
+             "ts": ts_ns / 1e3, "dur": dur_ns / 1e3, "pid": pid, "tid": tid}
         if args:
             e["args"] = dict(args)
-        tevs.append(e)
-    return {"traceEvents": tevs, "displayTimeUnit": "ms"}
+        return e
+
+    whole = evs is None
+    tevs = [doc_event(name, t0 + EPOCH_OFFSET_NS, t1 - t0, tid, cat, args)
+            for name, t0, t1, tid, cat, args in (events() if whole else evs)]
+    doc = {"traceEvents": tevs, "displayTimeUnit": "ms"}
+    if whole:
+        spans = launch_spans()
+        tevs.extend(
+            doc_event(s["name"], s["start_ns"], s["end_ns"] - s["start_ns"],
+                      s["tid"], "launch",
+                      {k: s[k] for k in s if k not in
+                       ("name", "start_ns", "end_ns", "tid", "root",
+                        "self_ns")}
+                      | {"launch": LAUNCH_ID})
+            for s in spans)
+        doc["launchReport"] = _report_of(spans)
+    return doc
 
 
 def export_chrome_tracing(path: str,

@@ -50,7 +50,7 @@ import pickle
 import time
 from typing import Optional, Tuple
 
-import jax.monitoring
+import jax
 
 from . import concurrency as _conc
 from . import flags as _flags
@@ -61,17 +61,12 @@ __all__ = ["ArtifactStore", "active", "configure", "aot_compile",
 _MAGIC = b"PTAOT1\n"
 _METRIC_PREFIX = "aot_store"
 
-# compiles that JAX's own persistent cache served (utils/compile_cache.py)
-_jax_cache_hits = 0
 
-
-def _on_jax_event(event, **_):
-    global _jax_cache_hits
-    if event == "/jax/compilation_cache/cache_hits":
-        _jax_cache_hits += 1
-
-
-jax.monitoring.register_event_listener(_on_jax_event)
+def _jax_cache_hits() -> int:
+    """Compiles that JAX's own persistent cache served
+    (utils/compile_cache.py), as the launch record counts them."""
+    from ..profiler import metrics as _metrics
+    return _metrics.counter("compile.cache_hit").value
 
 
 def _m(name: str):
@@ -334,13 +329,13 @@ class ArtifactStore:
                     provenance="store-hit", cause="cached")
             return exe
         _m("miss").inc()
-        hits0 = _jax_cache_hits
+        hits0 = _jax_cache_hits()
         compiled = lowered.compile()
         if _memscope.active:
             _memscope.compile_record(
                 label or "aot", fp, time.perf_counter() - t0,
                 provenance="store-miss")
-        if _jax_cache_hits != hits0:
+        if _jax_cache_hits() != hits0:
             # JAX's persistent cache served this compile: it is already
             # persistent there, and XLA:CPU cannot re-serialize an
             # executable it loaded from that cache (the blob would fail

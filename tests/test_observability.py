@@ -464,42 +464,74 @@ def test_fleet_publish_collect_roundtrip():
 def test_merge_chrome_traces_rank_lanes_and_alignment():
     from paddle_tpu.distributed import fleet_metrics as fm
 
-    def doc(rank, perf_ns, unix, ts_us):
+    def doc(rank, ts_us, clock="unix"):
         return {"traceEvents": [
             {"name": f"step_r{rank}", "ph": "X", "ts": ts_us,
              "dur": 5.0, "pid": 4242, "tid": 1, "cat": "hapi"}],
             "displayTimeUnit": "ms",
-            "metadata": {"rank": str(rank),
-                         "clock": {"perf_ns": perf_ns, "unix": unix}}}
+            "metadata": {"rank": str(rank), "clock": clock}}
 
-    # rank 0's perf epoch is 1000s behind rank 1's, but both events
-    # happened at the same wall-clock instant: unix - perf/1e9 differ
-    merged = fm.merge_chrome_traces([
-        doc(0, int(2000e9), 5000.0, 100.0),
-        doc(1, int(1000e9), 4000.0, 100.0)])
+    # exported ``ts`` is on the unix axis already: no lane is shifted
+    merged = fm.merge_chrome_traces([doc(0, 5e15 + 100.0),
+                                     doc(1, 5e15 + 130.0)])
     evs = [e for e in merged["traceEvents"] if e.get("ph") == "X"]
     pids = {e["pid"] for e in evs}
     assert pids == {0, 1}                     # one lane per rank
     lanes = {e["pid"]: e["ts"] for e in evs}
-    assert abs(lanes[0] - lanes[1]) < 1e-6    # clock-aligned
+    assert lanes == {0: 0.0, 1: 30.0}         # rebased, order and gap kept
     names = {e["args"]["name"] for e in merged["traceEvents"]
              if e.get("ph") == "M"}
     assert names == {"rank 0", "rank 1"}
     assert merged["metadata"]["aligned"] is True
+    # a doc on some other clock (a perf_counter pair from before the
+    # export moved to the epoch) keeps its lane, and the result says so
+    old = doc(2, 100.0, clock={"perf_ns": 1, "unix": 2.0})
+    merged = fm.merge_chrome_traces([doc(0, 5e15), old])
+    assert {e["pid"] for e in merged["traceEvents"]} == {0, 2}
+    assert merged["metadata"]["aligned"] is False
 
 
 def test_write_rank_trace_carries_clock(tmp_path):
     from paddle_tpu.distributed import fleet_metrics as fm
     tracer.enable()
     t0 = tracer.now_ns()
+    before = time.time()
     tracer.record("obs::probe", t0, t0 + 1000)
     path = fm.write_rank_trace(str(tmp_path / "t.json"), rank=3)
     tracer.disable()
     tracer.clear()
     doc = json.loads(open(path).read())
-    assert doc["metadata"]["rank"] == "3"
-    assert {"perf_ns", "unix"} <= set(doc["metadata"]["clock"])
-    assert any(e["name"] == "obs::probe" for e in doc["traceEvents"])
+    assert doc["metadata"] == {"rank": "3", "clock": "unix"}
+    probe, = [e for e in doc["traceEvents"] if e["name"] == "obs::probe"]
+    assert abs(probe["ts"] / 1e6 - before) < 5.0      # us since the epoch
+
+
+def test_rank_traces_of_two_hosts_merge_aligned(tmp_path, monkeypatch):
+    """Two hosts' ``perf_counter`` origins differ by their uptimes; what
+    ``write_rank_trace`` exports is on the epoch clock, so spans of one
+    wall-clock instant land at one ``ts`` after the merge, and launch
+    spans (epoch from the start) beside them."""
+    from paddle_tpu.distributed import fleet_metrics as fm
+    docs = []
+    t0 = tracer.now_ns()
+    # rank 1's host has been up 1000 s longer: the same instant reads
+    # 1000 s more on its perf_counter, and its offset to the epoch is
+    # 1000 s less
+    for rank, uptime_ns in ((0, 0), (1, int(1000e9))):
+        monkeypatch.setattr(tracer, "EPOCH_OFFSET_NS",
+                            tracer.EPOCH_OFFSET_NS - uptime_ns)
+        path = fm.write_rank_trace(
+            str(tmp_path / f"r{rank}.json"), rank=rank,
+            events=[("step", t0 + uptime_ns + rank * 7000,
+                     t0 + uptime_ns + rank * 7000 + 5000, 1, "hapi", None)])
+        monkeypatch.undo()
+        docs.append(json.loads(open(path).read()))
+    merged = fm.merge_chrome_traces(docs)
+    ts = {e["pid"]: e["ts"] for e in merged["traceEvents"]
+          if e.get("ph") == "X"}
+    # float64 us at 1.7e15 resolve to a quarter of a microsecond
+    assert ts[0] == 0.0 and abs(ts[1] - 7.0) < 0.5
+    assert merged["metadata"]["aligned"] is True
 
 
 def test_fleet_metrics_server_end_to_end():

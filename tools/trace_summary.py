@@ -10,6 +10,7 @@ call count, total/avg/max duration and share of the traced wall time.
     python tools/trace_summary.py trace.json -n 20 --sort avg --cat dispatch
     python tools/trace_summary.py trace.json --request <trace-or-request-id>
     python tools/trace_summary.py trace.json --compiles
+    python tools/trace_summary.py trace.json --launch
     python tools/trace_summary.py trace.json --request <id> \\
         --flight /tmp/flight/flight.r0.g0.json
 
@@ -26,6 +27,13 @@ verdicts (kv_shed, exhaustion, retire reason) line up with the spans.
 ``--compiles`` prints the memscope compile-ledger view off the
 ``cat="compile"`` spans: per site x cause x provenance, how many
 compiles and how much wall they burned.
+
+``--launch`` prints the launch record (``cat="launch"`` spans, reduced
+by the tracer to the ``launchReport`` the export carries): a row a root
+function — seconds of import / build / trace / lower / backend, what the
+persistent cache said, how often the function reached the backend — and
+under it the five functions below it with most self time.  The op table
+leaves the launch spans out (``--cat launch`` lists them raw).
 
 ``--memplan`` treats the positional argument as a static memory plan
 JSON (``MemoryPlan.to_doc()`` from static/passes/memory_plan.py, e.g.
@@ -48,7 +56,9 @@ def aggregate(events, cat=None):
     for e in events:
         if e.get("ph") != "X":
             continue
-        if cat and e.get("cat") != cat:
+        # one category when asked; else all but the launch spans, which
+        # have a table of their own (--launch)
+        if (e.get("cat") != cat) if cat else (e.get("cat") == "launch"):
             continue
         dur = float(e.get("dur", 0.0))
         s = stats.setdefault(e.get("name", "?"),
@@ -166,14 +176,54 @@ def compile_table(events):
     return "\n".join(lines)
 
 
+LAUNCH_PHASES = ("import", "build", "trace", "lower", "backend")
+
+
+def launch_table(report, top=None, children=5):
+    """The tracer's ``launch_report()`` as a table: root functions by
+    their seconds, each followed by its costliest children."""
+    funs = (report or {}).get("functions") or {}
+    if not funs:
+        return "(no launch record in trace — written by " \
+               "paddle_tpu.profiler.export_chrome_tracing with no " \
+               "events given?)"
+    rows = sorted(funs.items(), reverse=True,
+                  key=lambda kv: sum(kv[1]["seconds"].values()))
+    hidden = len(rows) - len(rows[:top])
+    rows = rows[:top]
+    name_w = max([len(n) for n, _ in rows] + [10])
+    head = f"{'function':<{name_w}} " + " ".join(
+        f"{p + '_s':>9}" for p in LAUNCH_PHASES) + \
+        f" {'cache':<14} {'compiles':>8}"
+    lines = [f"launch {report.get('launch')}: {report.get('spans')} "
+             f"spans, {report.get('dropped')} dropped", head,
+             "-" * len(head)]
+    for fun, f in rows:
+        cache = " ".join(f"{k}:{n}" for k, n in f["cache"].items() if n)
+        lines.append(
+            f"{fun:<{name_w}} " + " ".join(
+                f"{f['seconds'][p]:>9.3f}" if p in f["seconds"]
+                else f"{'-':>9}" for p in LAUNCH_PHASES)
+            + f" {cache or '-':<14} {f['compiles']:>8}")
+        below = sorted(f["children"].items(), reverse=True,
+                       key=lambda kv: kv[1]["self_s"])
+        for name, c in below[:children]:
+            lines.append(f"    {c['self_s']:>9.3f} s self {c['total_s']:>9.3f}"
+                         f" s in all {c['spans']:>5} spans  {name}")
+    if hidden:
+        lines.append(f"... and {hidden} more root functions")
+    return "\n".join(lines)
+
+
 def flight_events_for(paths, ident):
     """Events from flight-recorder dump files whose ``request_id``
     field matches ``ident`` (or a >=8-char prefix), as synthetic
     zero-duration rtrace spans the waterfall can interleave.  Flight
-    timestamps are unix seconds; rtrace spans are perf_counter_ns —
-    different clocks — so folded events sort by their own time among
-    themselves and render with an ``[flight]`` marker instead of an
-    offset."""
+    timestamps are unix seconds, and so is an exported trace's ``ts``
+    (in us) since the launch record; a trace from before it is on
+    ``perf_counter_ns``, so folded events still sort by their own time
+    among themselves and render with an ``[flight]`` marker instead of
+    an offset."""
     out = []
     for path in paths:
         try:
@@ -275,6 +325,9 @@ def main(argv=None):
     ap.add_argument("--compiles", action="store_true",
                     help="print the compile-ledger table "
                          "(cat='compile' spans: site/cause/provenance)")
+    ap.add_argument("--launch", action="store_true",
+                    help="print the launch record (cat='launch' spans: "
+                         "trace / lower / backend seconds a function)")
     ap.add_argument("--memplan", action="store_true",
                     help="treat the positional arg as a static memory "
                          "plan JSON (MemoryPlan.to_doc()) and render "
@@ -288,6 +341,11 @@ def main(argv=None):
     events = doc.get("traceEvents", doc if isinstance(doc, list) else [])
     if args.compiles:
         print(compile_table(events))
+        return 0
+    if args.launch:
+        print(launch_table(doc.get("launchReport")
+                           if isinstance(doc, dict) else None,
+                           top=args.top))
         return 0
     if args.request:
         print(format_waterfall(request_spans(events, args.request),
