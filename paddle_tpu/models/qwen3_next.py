@@ -207,22 +207,20 @@ def _gated_attention(p, x, cfg, mesh, batch_axes):
         return x + y.reshape(B, T, H * hd) @ p["o_w"]
 
 
-def _causal_conv(s, w):
-    """Depth-wise, causal within a row: ``c_t = sum_j w_j * s_{t-j}``,
-    ``s_{<0} = 0``.  s: (B, T, C); w: (taps, C)."""
-    taps, T = w.shape[0], s.shape[1]
-    padded = jnp.pad(s, ((0, 0), (taps - 1, 0), (0, 0)))
-    return sum(w[j] * lax.slice_in_dim(
-        padded, taps - 1 - j, taps - 1 - j + T, axis=1)
-        for j in range(taps))
-
-
-def _l2norm(x):
-    xf = x.astype(jnp.float32)
-    return xf * lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + 1e-6)
+def _by_heads(x, heads: int):
+    """Token-major (B, T, heads d) -> (B, T / 8, heads, 8, d): a head's
+    ``d`` columns last, to reduce over, the axes in the order a TPU tiles
+    the token-major array in — 8 tokens by 128 lanes a tile, a head a
+    tile of the row — so that the view costs nothing there.  (B, T,
+    heads, d) is another tiling, 8 heads of one token a tile: every byte
+    copied (PERF.md section 7)."""
+    B, T, _ = x.shape
+    rows = 8 if T % 8 == 0 else 1
+    return jnp.swapaxes(x.reshape(B, T // rows, rows, heads, -1), 2, 3)
 
 
 def _gated_delta_net(p, x, cfg, mesh, batch_axes):
+    from ..ops.causal_conv import gated_causal_conv
     from ..ops.gated_delta_rule import gated_delta_rule
     B, T, _ = x.shape
     Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
@@ -236,29 +234,30 @@ def _gated_delta_net(p, x, cfg, mesh, batch_axes):
         gz = z @ p["qkvz_w"][:, 2 * n_qk + n_v:]
         ba = z @ p["ba_w"]
     with jax.named_scope("gdn_conv"):
-        c = jax.nn.silu(_causal_conv(qkv, p["conv_w"]))
-        q = c[..., :n_qk].reshape(B, T, Hk, dk)
-        k = c[..., n_qk:2 * n_qk].reshape(B, T, Hk, dk)
-        v = c[..., 2 * n_qk:].reshape(B, T, Hv, dv)
+        # convolution, SiLU and the q / k L2 norms: on a TPU a kernel pair
+        # (``%gdn_conv_fwd``, ``%gdn_conv_bwd``) inside the scope, found
+        # by the path as the rule's are; q, k, v come as three arrays,
+        # token-major (B, T, heads x d) — as the rule's kernels read them
+        q, k, v = gated_causal_conv(qkv, p["conv_w"], n_qk=n_qk, head=dk,
+                                    mesh=mesh, batch_axes=batch_axes)
         beta = jax.nn.sigmoid(ba[..., :Hv].astype(jnp.float32))
         g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
             ba[..., Hv:].astype(jnp.float32)
             + p["dt_bias"].astype(jnp.float32))
-        q = (_l2norm(q) * dk ** -0.5).astype(x.dtype)
-        k = _l2norm(k).astype(x.dtype)
     with jax.named_scope("gdn_scan"):
         # the rule repeats the key heads to the value heads; its kernels
         # (``%delta_rule_*``) sit inside the scope, which is how
         # ``gdn_scan_roofline`` finds them: by the path, not by a name
         o = gated_delta_rule(q, k, v, g, beta, chunk=cfg.gdn_chunk,
-                             mesh=mesh, batch_axes=batch_axes)
+                             key_heads=Hk, mesh=mesh, batch_axes=batch_axes)
     with jax.named_scope("gdn_out"):
-        of = o.astype(jnp.float32)
+        of = _by_heads(o, Hv).astype(jnp.float32)
         y = of * lax.rsqrt(jnp.mean(of * of, axis=-1, keepdims=True)
                            + cfg.rms_norm_eps)
         y = y * p["gdn_norm"].astype(jnp.float32) \
-            * jax.nn.silu(gz.reshape(B, T, Hv, dv).astype(jnp.float32))
-        return x + y.astype(x.dtype).reshape(B, T, n_v) @ p["out_w"]
+            * jax.nn.silu(_by_heads(gz, Hv).astype(jnp.float32))
+        y = jnp.swapaxes(y.astype(x.dtype), 2, 3).reshape(B, T, n_v)
+        return x + y @ p["out_w"]
 
 
 def _expert_ffn(p, x, cfg, mesh, batch_axes):

@@ -189,8 +189,9 @@ def _gates(g, beta, chunk: int):
 
 def _operands(q, k, v, gates, plan, with_inverse=False):
     """A row's prep as the kernels make and take it, a batch of one, from
-    its q, k, v and its ``_gates``.  -> (the loop's six operands, the
-    float32 inverse for ``prep_vjp`` or None)."""
+    its token-major q, k (T, H_k d_k), v (T, H d_v) and its ``_gates``.
+    -> (the loop's six operands, the float32 inverse for ``prep_vjp`` or
+    None)."""
     G, beta, last = gates
     operands, inv = _kernels.prep(
         q[None], k[None], v[None], G[None], beta[None], plan=plan,
@@ -200,10 +201,11 @@ def _operands(q, k, v, gates, plan, with_inverse=False):
 
 @_traced_once(5, 6)
 def _rule_kernels(q, k, v, g, beta, chunk: int, plan):
-    """The rule in the kernels, a batch row at a time: the gates (XLA
-    ops), ``prep``, then ``chunk_scan``.  (An inline jit, like the
-    backward: a model's layers call with the same shapes, and the second
-    finds the first one's jaxpr — kernel bodies traced once, ``setup_s``.)"""
+    """The rule in the kernels, a batch row at a time, q, k, v and the
+    output token-major: the gates (XLA ops), ``prep``, then
+    ``chunk_scan``.  (An inline jit, like the backward: a model's layers
+    call with the same shapes, and the second finds the first one's jaxpr
+    — kernel bodies traced once, ``setup_s``.)"""
     def row(x):
         q, k, v, g, beta = x
         operands, _ = _operands(q, k, v, _gates(g, beta, chunk), plan)
@@ -245,8 +247,8 @@ def _rule_bwd(chunk, plan, inputs, do):
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
-def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, mesh=None,
-                     batch_axes=()):
+def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, key_heads=None,
+                     mesh=None, batch_axes=()):
     """The gated delta rule in chunks of ``chunk`` tokens (any T: the
     tail is padded with tokens that neither decay, write nor read).
 
@@ -256,26 +258,47 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, mesh=None,
     v: (B, T, H, d_v); g: (B, T, H) log-decay a token, <= 0; beta:
     (B, T, H) the write strength.  -> o (B, T, H, d_v) in v's dtype,
     named ``RESIDUAL_NAMES[0]`` for a remat policy to save.
-    Differentiable in all five.  Under a mesh of more than one device
-    ``batch_axes`` names the axes that shard B: the kernels run per
-    shard (``ops.pallas.shard_kernel``)."""
-    B, T, H, dv = v.shape
+    Differentiable in all five.
+
+    Or *token-major*, the heads folded into the columns: q, k (B, T,
+    H_k d_k) with ``key_heads = H_k`` (a shape, which three axes no
+    longer say; H is g's), v (B, T, H d_v) -> o (B, T, H d_v).  That is
+    the form the kernels read and write — and the convolution's in front
+    of them (``ops/causal_conv.py``) —, so on a TPU nothing is copied
+    between them: there ``(B, T, H, d)`` tiles 16 heads of one token
+    where ``(B, T, H d)`` tiles 16 tokens of one head, and a reshape
+    from one to the other moves every byte (PERF.md section 7).
+
+    Under a mesh of more than one device ``batch_axes`` names the axes
+    that shard B: the kernels run per shard
+    (``ops.pallas.shard_kernel``)."""
+    B, T, H = g.shape
+    folded = v.ndim == 3
+    if not folded:
+        key_heads = q.shape[2]
+        q, k, v = (x.reshape(B, T, -1) for x in (q, k, v))
+    elif key_heads is None:
+        raise ValueError("token-major q, k (B, T, H_k d_k) need key_heads")
+    dk, dv = q.shape[-1] // key_heads, v.shape[-1] // H
     pad = -T % chunk
     if pad:
-        q, k, v, g, beta = (
-            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-            for x in (q, k, v, g, beta))
-    plan = _kernels.plan((T + pad) // chunk, H, chunk, q.shape[-1], dv,
-                         interpret=not pallas.on_tpu(), H_k=q.shape[2]) \
+        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+                            for x in (q, k, v, g, beta))
+    plan = _kernels.plan((T + pad) // chunk, H, chunk, dk, dv,
+                         interpret=not pallas.on_tpu(), H_k=key_heads) \
         if pallas.enabled() else None
     pallas.note("gated_delta_rule", plan is not None)
     if plan is None:
+        # the XLA math goes by heads; off the TPU a reshape is free
         row = jax.checkpoint(functools.partial(_row, chunk=chunk))
-        o = lax.map(lambda x: row(*x), (q, k, v, g, beta))
+        o = lax.map(lambda x: row(*x), (
+            q.reshape(B, -1, key_heads, dk), k.reshape(B, -1, key_heads, dk),
+            v.reshape(B, -1, H, dv), g, beta)).reshape(B, -1, H * dv)
     else:
         b_ax = _axes_entry(mesh, batch_axes, B)
         spec = PartitionSpec(b_ax)
         o = pallas.shard_kernel(
             lambda *x: _rule(*x, chunk, plan), mesh, (spec,) * 5,
             spec)(q, k, v, g, beta)
-    return checkpoint_name(o[:, :T], RESIDUAL_NAMES[0])
+    o = checkpoint_name(o[:, :T], RESIDUAL_NAMES[0])
+    return o if folded else o.reshape(B, T, H, dv)

@@ -8,11 +8,11 @@ softmax-rescaling tricks beat what the compiler fuses on its own.
 The kernels: ``flash_attention`` (eleven attention kernels behind one
 plan; the resident pair also at a v head size other than q's and k's:
 latent attention's 192 over 128), ``fused_ln``, ``softmax_xent`` (the
-fused loss head's forward, with an optional weight a row) and
-``gated_delta_rule`` (the linear-attention recurrence in chunks: the prep
-of a chunk with its triangular inverse in VMEM and its reverse pass; the
-loop over chunks with the state in VMEM: forward, state pass, reverse
-pass).
+fused loss head's forward, an optional weight a row), ``gated_delta_rule``
+(the linear-attention recurrence in chunks: the prep with its triangular
+inverse in VMEM, the loop with the state in VMEM, and their reverse
+passes) and ``causal_conv`` (the convolution, SiLU and q / k L2 norms in
+front of that rule, token-major: a forward writing q, k, v, a backward).
 
 Every place that chooses between a Mosaic kernel and XLA math asks this
 module, and records what it chose:

@@ -587,11 +587,11 @@ def _lanes(last, dv: int):
 def chunk_scan(w_k, w_v, attn, q_dec, k_dec, last, *, out_dtype, plan: Plan):
     """w_k, q_dec, k_dec: (B, n, H, C, d_k); w_v: (B, n, H, C, d_v)
     float32; attn: (B, n, H, C, C); last: (B, n, H, 1, 1) float32.
-    -> o (B, n C, H, d_v) in ``out_dtype``."""
+    -> o token-major, (B, n C, H d_v), in ``out_dtype``."""
     B, n, H, C, dk = w_k.shape
     dv = w_v.shape[-1]
     pair, token_major = _specs(plan.fwd, n)
-    o = _call(
+    return _call(
         _delta_rule_fwd, plan.fwd, (B, n, H),
         (w_k, w_v, attn, q_dec, k_dec, _lanes(last, dv)),
         [pair(C, dk), pair(C, dv), pair(C, C), pair(C, dk), pair(C, dk),
@@ -599,7 +599,6 @@ def chunk_scan(w_k, w_v, attn, q_dec, k_dec, last, *, out_dtype, plan: Plan):
         token_major(C, dv),
         jax.ShapeDtypeStruct((B, n * C, H * dv), out_dtype),
         (dk, dv), plan.interpret)
-    return o.reshape(B, n * C, H, dv)
 
 
 def _chunk_states(w_k, w_v, k_dec, lanes, plan: Plan):
@@ -628,7 +627,7 @@ def _chunk_reverse(do, w_k, attn, q_dec, k_dec, lanes, S, u, plan: Plan):
             (lanes, jnp.float32)]
     return _call(
         _delta_rule_bwd, plan.bwd, (B, n, H),
-        (do.reshape(B, n * C, H * dv), w_k, attn, q_dec, k_dec, lanes, S, u),
+        (do, w_k, attn, q_dec, k_dec, lanes, S, u),
         [token_major(C, dv), pair(C, dk), pair(C, C), pair(C, dk),
          pair(C, dk), pair(1, dv), pair(dk, dv), pair(C, dv)],
         [pair(C, dk), pair(C, dv), pair(C, C), pair(C, dk), pair(C, dk),
@@ -639,7 +638,7 @@ def _chunk_reverse(do, w_k, attn, q_dec, k_dec, lanes, S, u, plan: Plan):
 
 def chunk_scan_vjp(w_k, w_v, attn, q_dec, k_dec, last, do, *, plan: Plan):
     """The cotangents of ``chunk_scan``'s six operands under ``do``
-    (B, n C, H, d_v), each in its operand's shape and dtype: the state
+    (B, n C, H d_v), each in its operand's shape and dtype: the state
     pass (every chunk's starting state and u~) and the reverse pass."""
     lanes = _lanes(last, w_v.shape[-1])
     S, u = _chunk_states(w_k, w_v, k_dec, lanes, plan)
@@ -648,15 +647,20 @@ def chunk_scan_vjp(w_k, w_v, attn, q_dec, k_dec, last, do, *, plan: Plan):
     return (*grads, jnp.sum(d_lanes, axis=-1, keepdims=True))
 
 
+def _head_sizes(q, v, H: int, plan: Plan):
+    """(d_k, d_v) of token-major q (…, H_k d_k) and v (…, H d_v)."""
+    return q.shape[-1] * plan.group // H, v.shape[-1] // H
+
+
 def prep(q, k, v, G, beta, *, plan: Plan, with_inverse: bool = False):
-    """The loop's operands from a row's inputs, in VMEM.  q, k: (B, T,
-    H_k, d_k); v: (B, T, H, d_v); G (the log-decay summed inside its
-    chunk) and beta: (B, n, H, C) float32.  -> (w_k, w_v, attn, q_dec,
-    k_dec as ``chunk_scan`` takes them; ``with_inverse``, the float32
-    inverse (B, n, H, C, C) for ``prep_vjp``, else None)."""
-    B, T, Hk, dk = q.shape
-    _, n, H, C = G.shape
-    dv = v.shape[-1]
+    """The loop's operands from a row's inputs, in VMEM.  q, k: token-
+    major, (B, T, H_k d_k) — the key heads are ``H / plan.group`` —; v:
+    (B, T, H d_v); G (the log-decay summed inside its chunk) and beta:
+    (B, n, H, C) float32.  -> (w_k, w_v, attn, q_dec, k_dec as
+    ``chunk_scan`` takes them; ``with_inverse``, the float32 inverse
+    (B, n, H, C, C) for ``prep_vjp``, else None)."""
+    B, n, H, C = G.shape
+    dk, dv = _head_sizes(q, v, H, plan)
     heads, _ = plan.prep
     pair, token_major = _specs(plan.prep, n)
     keys = token_major(C, dk, heads // plan.group)
@@ -664,9 +668,7 @@ def prep(q, k, v, G, beta, *, plan: Plan, with_inverse: bool = False):
               ((C, dk), q.dtype), ((C, dk), q.dtype)] \
         + [((C, C), jnp.float32)] * with_inverse
     out = _call(
-        _delta_rule_prep, plan.prep, (B, n, H),
-        (q.reshape(B, T, Hk * dk), k.reshape(B, T, Hk * dk),
-         v.reshape(B, T, H * dv), G, beta),
+        _delta_rule_prep, plan.prep, (B, n, H), (q, k, v, G, beta),
         [keys, keys, token_major(C, dv), pair(C), pair(C)],
         [pair(*tile) for tile, _ in shapes],
         [jax.ShapeDtypeStruct((B, n, H, *tile), dt) for tile, dt in shapes],
@@ -677,22 +679,18 @@ def prep(q, k, v, G, beta, *, plan: Plan, with_inverse: bool = False):
 def prep_vjp(q, k, v, G, beta, inv, d_wk, d_wv, d_attn, d_qd, d_kd, *,
              plan: Plan):
     """The cotangents of ``prep``'s q, k, v, G and beta under those of its
-    five operands; ``inv`` the inverse it wrote.  dq, dk: summed over the
-    value heads a key head serves."""
-    B, T, Hk, dk = q.shape
-    _, n, H, C = G.shape
-    dv = v.shape[-1]
+    five operands; ``inv`` the inverse it wrote.  dq, dk, dv token-major
+    as q, k, v; dq, dk summed over the value heads a key head serves."""
+    B, n, H, C = G.shape
+    dk, dv = _head_sizes(q, v, H, plan)
     heads, _ = plan.prep
     pair, token_major = _specs(plan.prep, n)
     keys = token_major(C, dk, heads // plan.group)
-    flat = [x.reshape(B, T, -1) for x in (q, k, v)]
-    dq, dk_, dv_, dG, dbeta = _call(
+    return _call(
         _delta_rule_prep_bwd, plan.prep, (B, n, H),
-        (*flat, G, beta, inv, d_wk, d_wv, d_attn, d_qd, d_kd),
+        (q, k, v, G, beta, inv, d_wk, d_wv, d_attn, d_qd, d_kd),
         [keys, keys, token_major(C, dv), pair(C), pair(C), pair(C, C),
          pair(C, dk), pair(C, dv), pair(C, C), pair(C, dk), pair(C, dk)],
         [keys, keys, token_major(C, dv), pair(C), pair(C)],
-        [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (*flat, G, beta)],
+        [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v, G, beta)],
         None, plan.interpret, group=plan.group)
-    return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
-            dG, dbeta)

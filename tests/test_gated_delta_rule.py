@@ -172,6 +172,76 @@ def test_key_heads_serve_groups_of_value_heads(path):
         np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-6)
 
 
+@pytest.fixture(scope="module",
+                params=[(p, hk) for p in PATHS for hk in ("fewer", "equal")],
+                ids=lambda x: f"{x[0]}-{x[1]}-key-heads")
+def folded(request):
+    """(output and five gradients through the token-major entry, the same
+    through the (B, T, H, d) entry, the path's shape): key heads 2 -> 4
+    value heads (the xla shape: 1 -> 3) and as many key heads as value
+    heads; T needs the padded tail."""
+    name, heads = request.param
+    shape = SHAPES[name]
+    H_k = shape.H if heads == "equal" else (shape.H_k if shape.kernels else 1)
+    x = _inputs(12, -2.0, shape, heads_k=H_k)
+    w = jax.random.normal(jax.random.PRNGKey(13),
+                          (B, shape.T, shape.H, shape.dv))
+
+    def fold(a):
+        return a.reshape(B, shape.T, -1)
+
+    def by_heads(q, k, v, g, beta):
+        o = gated_delta_rule(q, k, v, g, beta, chunk=shape.chunk)
+        return jnp.sum(o * w), o
+
+    def token_major(q, k, v, g, beta):
+        o = gated_delta_rule(q, k, v, g, beta, chunk=shape.chunk,
+                             key_heads=H_k)
+        assert o.shape == (B, shape.T, shape.H * shape.dv)
+        return jnp.sum(o * fold(w)), o
+
+    def run(fn, x):
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            fn, argnums=(0, 1, 2, 3, 4), has_aux=True))(*x)
+        return {"o": o, **dict(zip(NAMES, grads))}
+
+    with pytest.MonkeyPatch.context() as mp:
+        if shape.kernels:
+            mp.setenv("PADDLE_PALLAS_FORCE", "1")
+        want = run(by_heads, x)
+        got = run(token_major, (*map(fold, x[:3]), *x[3:]))
+    return got, want, shape
+
+
+@pytest.mark.parametrize("name", ("o", *NAMES))
+def test_the_token_major_entry_is_the_entry_by_heads(folded, name):
+    """q, k (B, T, H_k d_k) with ``key_heads`` and v (B, T, H d_v) give
+    what (B, T, H, d) gives, the output and the cotangents of q, k and v
+    in the form they came in: off the TPU bit for bit (the XLA math
+    reshapes for itself), and the interpreted kernels — which read and
+    write the token-major form either way — within the recurrence's
+    tolerances."""
+    got, want, shape = folded
+    got, want = got[name], want[name]
+    if name in ("o", "q", "k", "v"):
+        assert got.ndim == 3 and want.ndim == 4
+        got = got.reshape(want.shape)
+    if shape.kernels:
+        np.testing.assert_allclose(
+            got, want, rtol=1e-4,
+            atol=2e-6 * max(1.0, float(jnp.max(jnp.abs(want)))))
+    else:
+        np.testing.assert_array_equal(got, want)
+
+
+def test_the_token_major_entry_needs_the_key_heads():
+    """Three axes no longer say where a key head ends."""
+    q, k, v, g, beta = _inputs(14, -1.0, SHAPES["xla"], T=8)
+    with pytest.raises(ValueError, match="key_heads"):
+        gated_delta_rule(*(a.reshape(B, 8, -1) for a in (q, k, v)), g, beta,
+                         chunk=8)
+
+
 @pytest.mark.parametrize("path", PATHS, indirect=True)
 def test_bfloat16_operands_keep_the_state_in_float32(path):
     """A bf16 step hands bf16 q, k, v; g, the decays and the state stay
@@ -209,10 +279,13 @@ def test_the_reverse_pass_is_the_vjp_of_the_scan_form(dtype, tol):
     plan = kernels.plan(T // shape.chunk, shape.H, shape.chunk, shape.dk,
                         shape.dv, interpret=True)
     got_o = kernels.chunk_scan(*operands, out_dtype=dtype, plan=plan)
+    assert got_o.shape == (B, T, shape.H * shape.dv)    # token-major
     f32 = lambda a: np.asarray(a, np.float32)   # noqa: E731
-    np.testing.assert_allclose(f32(got_o), f32(want_o), rtol=tol,
+    np.testing.assert_allclose(f32(got_o).reshape(want_o.shape),
+                               f32(want_o), rtol=tol,
                                atol=tol * float(np.abs(f32(want_o)).max()))
-    got = kernels.chunk_scan_vjp(*operands, do, plan=plan)
+    got = kernels.chunk_scan_vjp(*operands, do.reshape(got_o.shape),
+                                 plan=plan)
     for name, a, b, x in zip(("w_k", "w_v", "attn", "q_dec", "k_dec",
                               "last"), got, vjp(do), operands):
         assert a.shape == x.shape and a.dtype == x.dtype, name
@@ -232,6 +305,12 @@ def _padded_row(seed, g_min, T, dtype, chunk=None, heads_k=None):
         a, ((0, -T % chunk),) + ((0, 0),) * (a.ndim - 1))
     return tuple(map(pad, (q.astype(dtype), k.astype(dtype),
                            v.astype(dtype), g, beta)))
+
+
+def _folded(*rows):
+    """Rows (T, heads, d) token-major, (T, heads d), as the kernels'
+    wrappers take q, k and v."""
+    return tuple(a.reshape(a.shape[0], -1) for a in rows)
 
 
 def _prep_plan(x, chunk):
@@ -264,7 +343,8 @@ def test_the_prep_kernel_makes_the_operands_of_the_xla_prep(T, dtype, tol):
     x = _padded_row(8, -2.0, T, dtype)
     want = rule_module._prep(*x, chunk)
     got, inv = rule_module._operands(
-        *x[:3], rule_module._gates(*x[3:], chunk), _prep_plan(x, chunk))
+        *_folded(*x[:3]), rule_module._gates(*x[3:], chunk),
+        _prep_plan(x, chunk))
     assert inv is None
     for name, a, b in zip(OPERANDS, got, want):
         _close(a[0], b, tol, name)
@@ -289,11 +369,14 @@ def test_the_prep_s_reverse_kernel_is_the_vjp_of_the_xla_prep(T, dtype,
     plan = _prep_plan(x, chunk)
     gates, gates_vjp = jax.vjp(
         functools.partial(rule_module._gates, chunk=chunk), *x[3:])
-    _, inv = rule_module._operands(*x[:3], gates, plan, with_inverse=True)
-    dq, dk, dv, dG, d_beta = kernels.prep_vjp(
-        *(a[None] for a in (*x[:3], *gates[:2])), inv,
+    flat = _folded(*x[:3])
+    _, inv = rule_module._operands(*flat, gates, plan, with_inverse=True)
+    *dqkv, dG, d_beta = kernels.prep_vjp(
+        *(a[None] for a in (*flat, *gates[:2])), inv,
         *(c[None] for c in cotangents[:5]), plan=plan)
-    got = (dq[0], dk[0], dv[0],
+    for d, a in zip(dqkv, flat):                        # token-major
+        assert d.shape == (1, *a.shape) and d.dtype == a.dtype
+    got = (*(d.reshape(a.shape) for d, a in zip(dqkv, x)),
            *gates_vjp((dG[0], d_beta[0], cotangents[5])))
     for name, a, b in zip(NAMES, got, vjp(cotangents)):
         _close(a, b, tol, name)
@@ -326,8 +409,8 @@ def test_the_inverse_in_vmem_is_solve_triangular_s(case, chunk):
         else _padded_row(0, -20.0, 2 * chunk, jnp.float32, chunk)
     q, k, v, g, beta = x
     G, b, _ = gates = rule_module._gates(g, beta, chunk)
-    _, inv = rule_module._operands(q, k, v, gates, _prep_plan(x, chunk),
-                                   with_inverse=True)
+    _, inv = rule_module._operands(*_folded(q, k, v), gates,
+                                   _prep_plan(x, chunk), with_inverse=True)
     n, H, C = G.shape
     kk = jnp.repeat(jnp.moveaxis(k.reshape(n, C, *k.shape[1:]), 2, 1),
                     H // k.shape[1], axis=1)

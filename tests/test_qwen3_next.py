@@ -221,6 +221,48 @@ def test_three_adamw_steps_match_the_reference(seeded):
 
 
 # ---------------------------------------------------------------------------
+# the mixer's kernels, interpreted: heads of 128 columns, where they tile
+# ---------------------------------------------------------------------------
+# one delta-rule layer and one gated-attention layer; a key head of 128
+# under two value heads; T = 32 is two chunks of 16 and two 16-row tiles
+WIDE = {**TINY, "num_hidden_layers": 2, "full_attention_interval": 2,
+        "linear_num_key_heads": 1, "linear_num_value_heads": 2,
+        "linear_key_head_dim": 128, "linear_value_head_dim": 128,
+        "assumed": {"optimizer": OPT, "gdn_chunk": 16}}
+WIDE_TRAFFIC = {"batch": 2, "seq_len": 32, "pool": 1, "check_steps": 1}
+
+
+@pytest.mark.parametrize("force,impl", [("0", "xla"), ("1", "interpret")],
+                         ids=["xla-math", "kernels-forced"])
+def test_loss_and_gradients_with_the_mixer_s_kernels(monkeypatch, force,
+                                                     impl):
+    """The step's loss and every gradient leaf against the reference, on
+    the XLA path and with ``PADDLE_PALLAS_FORCE=1`` — where the
+    convolution (``gdn_conv_fwd`` twice under ``ctx``, ``gdn_conv_bwd``)
+    and the rule run as their Pallas kernels, interpreted — within the
+    same tolerances."""
+    from paddle_tpu.ops import pallas
+    monkeypatch.setenv("PADDLE_PALLAS_FORCE", force)
+    params = ref.init_params(WIDE, 7)
+    ids, labels = map(jnp.asarray, ref.make_batches(WIDE, WIDE_TRAFFIC,
+                                                    7)[0])
+    want_loss, want_grads = jax.jit(jax.value_and_grad(
+        lambda p, i, l: ref.summed_loss(p, i, l, WIDE)))(params, ids, labels)
+    before = dict(pallas.selections())
+    loss, _p, opt_state, _ = build(WIDE, remat_policy="ctx")(
+        *fresh_state(params), ids, labels)
+    took = {k for k, v in pallas.selections().items()
+            if v != before.get(k, 0)}
+    assert {f"causal_conv.{impl}", f"gated_delta_rule.{impl}"} <= took, took
+    np.testing.assert_allclose(loss, want_loss / ids.size, rtol=1e-5)
+    grads = jax.tree.map(lambda m: m / (1 - OPT["beta1"]), opt_state["m"])
+    assert_trees_close(
+        grads, jax.tree.map(lambda g: g / ids.size, want_grads),
+        rtol=2e-3, atol=2e-7)
+    assert np.any(np.asarray(grads["layers"][0]["conv_w"]))
+
+
+# ---------------------------------------------------------------------------
 # partial RoPE and the output gate, by hand
 # ---------------------------------------------------------------------------
 def test_partial_rope_rotates_a_quarter_of_the_head():

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from benchmark.harness.scopes import classify
+from hlo_text import HANDOVER_SHAPES, handover_copies
 from paddle_tpu.distributed.topology import build_mesh
 from paddle_tpu.models import GPTConfig
 from paddle_tpu.models.gpt_spmd import (build_spmd_train_step,
@@ -517,6 +518,16 @@ def qwen3_next_real_width_hlo(v5e):
         mp.undo()
 
 
+def _scope_phases(calls):
+    """(name, (scope, phase)) of Mosaic calls, as the trace's reader
+    classifies their ``op_name`` paths."""
+    return sorted(
+        (c.split(".")[0],
+         classify(re.search(r'op_name="([^"]*)"', c).group(1),
+                  set(QWEN3_NEXT_SCOPES)))
+        for c in calls)
+
+
 def test_every_qwen3_next_mosaic_call_is_one_the_benchmark_finds(
         qwen3_next_real_width_hlo):
     """The resident flash pair at head size 256 (one forward, one fused
@@ -528,18 +539,17 @@ def test_every_qwen3_next_mosaic_call_is_one_the_benchmark_finds(
     loop's reverse pass and the prep's; the forward is not run again, its
     output is saved by name — match none of those patterns:
     ``gdn_scan_roofline`` finds them by the scope on their path, which is
-    what the device trace's ``tf_op`` holds.  The compile is also the
+    what the device trace's ``tf_op`` holds.  The convolution's kernel
+    pair sits under ``gdn_conv`` the same way: ``%gdn_conv_fwd`` in the
+    forward and again in the recompute (the block is run again under
+    ``ctx``), ``%gdn_conv_bwd`` in the backward.  The compile is also the
     proof that the kernels fit VMEM at the cell's shapes."""
     mosaic = _mosaic_calls(qwen3_next_real_width_hlo)
     groups = {m: _patterns(m) for m in (
         "gattn_roofline", "qwen3next_moe_experts_roofline",
         "qwen3next_loss_head_events")}
     rule = [c for c in mosaic if c.startswith("%delta_rule_")]
-    phases = sorted(
-        (c.split(".")[0],
-         classify(re.search(r'op_name="([^"]*)"', c).group(1),
-                  set(QWEN3_NEXT_SCOPES)))
-        for c in rule)
+    phases = _scope_phases(rule)
     assert phases == [
         ("%delta_rule_bwd", ("gdn_scan", "backward")),
         ("%delta_rule_fwd", ("gdn_scan", "forward")),
@@ -547,8 +557,20 @@ def test_every_qwen3_next_mosaic_call_is_one_the_benchmark_finds(
         ("%delta_rule_prep", ("gdn_scan", "forward")),
         ("%delta_rule_prep_bwd", ("gdn_scan", "backward")),
         ("%delta_rule_states", ("gdn_scan", "backward"))], phases
-    for c in rule:
+    conv = [c for c in mosaic if c.startswith("%gdn_conv_")]
+    assert _scope_phases(conv) == [
+        ("%gdn_conv_bwd", ("gdn_conv", "backward")),
+        ("%gdn_conv_fwd", ("gdn_conv", "forward")),
+        ("%gdn_conv_fwd", ("gdn_conv", "recompute"))], _scope_phases(conv)
+    for c in rule + conv:
         assert not any(r.search(c) for rx in groups.values() for r in rx)
+    # q, k and v leave the forward kernel as three arrays in the layout
+    # the rule's prep reads: (B, T, heads x 128), tokens on sublanes
+    for c in conv:
+        if c.startswith("%gdn_conv_fwd"):
+            results = c.split(" custom-call(")[0]
+            assert results.count("bf16[4,8192,2048]{2,1,0") == 2 \
+                and results.count("bf16[4,8192,4096]{2,1,0") == 1, results
     # the loop over chunks is the kernels' grid: what is left under the
     # scope loops over the batch rows at most (the tiny CPU step above
     # nests the chunks' loop in the rows': depth 2), and no operation of
@@ -562,7 +584,7 @@ def test_every_qwen3_next_mosaic_call_is_one_the_benchmark_finds(
                    or "triangular_solve" in n for n in under)
     assert not any("triangular" in i.lower() or "InvertDiagBlocks" in i
                    for i in _instructions(qwen3_next_real_width_hlo))
-    mosaic = [c for c in mosaic if c not in rule]
+    mosaic = [c for c in mosaic if c not in rule + conv]
     hits = {m: [c for c in mosaic if any(r.search(c) for r in rx)]
             for m, rx in groups.items()}
     attention = hits["gattn_roofline"]
@@ -760,3 +782,34 @@ def test_every_joyai_mosaic_call_is_one_the_benchmark_finds(
     # and this cell's attention patterns find nothing in another's step
     for c in mosaic:
         assert "bf16[64,8192,256]" not in c and "bf16[128,8192,64]" not in c
+
+
+@pytest.mark.parametrize("shape", HANDOVER_SHAPES)
+def test_nothing_is_copied_between_the_mixer_s_kernel_families(
+        qwen3_next_real_width_hlo, shape):
+    """The convolution's kernels write q, k and v token-major, (B, T,
+    heads x 128) — the form the rule's prep kernels read, the rule's loop
+    writes ``o`` in and ``gdn_out`` takes its norm in (``_by_heads``: the
+    axes in the tiles' order) — and the cotangents come back the same
+    way: the step compiled for a v5e holds no ``copy``, ``reshape`` or
+    ``transpose`` of its own of any of them.  With a ``(B, T, H, d)``
+    array anywhere in between it holds one a direction: that is another
+    tiling (with XLA's convolution, three of a row of ``o`` and ``do``
+    under ``gdn_scan`` and three float32 ones under ``gdn_out``; with the
+    convolution's kernels in front of a (B, T, H, d) entry, six more
+    under no name and three under ``gdn_conv``)."""
+    assert handover_copies(qwen3_next_real_width_hlo, shape) == []
+
+
+@pytest.mark.parametrize("fixture", [
+    "real_width_step_hlo", "lfm2_real_width_hlo", "joyai_real_width_hlo"],
+    ids=["gpt", "lfm2", "joyai"])
+def test_no_other_step_holds_the_delta_rule_mixer_s_kernels(request,
+                                                            fixture):
+    """``%gdn_conv_*`` and ``%delta_rule_*`` are the Qwen3-Next step's
+    alone: LFM2's ``_short_conv`` keeps its XLA convolution."""
+    hlo = request.getfixturevalue(fixture)
+    hlo = hlo[1] if isinstance(hlo, tuple) else hlo
+    assert not any(c.startswith(("%gdn_conv_", "%delta_rule_"))
+                   for c in _mosaic_calls(hlo))
+    assert "gdn_conv" not in hlo

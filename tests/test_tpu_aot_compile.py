@@ -26,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 import chip_smoke
+from hlo_text import HANDOVER_SHAPES, handover_copies
 
 pytestmark = pytest.mark.slow
 
@@ -265,6 +266,45 @@ def test_gated_delta_rule_at_the_qwen3_next_cell_s_size_compiles(v5e):
     assert "triangular" not in low.compile().as_text().lower()
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_the_mixer_s_convolution_at_the_qwen3_next_cell_s_size_compiles(
+        v5e, dtype):
+    """qkv (4, 8192, 8192), four taps, 128-wide heads (bfloat16 is the
+    cell's; float32 takes half the tile, the same bytes): the forward
+    kernel (three outputs, the halo block) and the backward kernel (the
+    carried ``dc``, the taps' gradient resident over the token tiles) —
+    two Mosaic calls, each within the scoped VMEM."""
+    from paddle_tpu.ops import pallas
+    from paddle_tpu.ops.causal_conv import gated_causal_conv
+    S = _on(v5e[0])
+
+    @jax.jit
+    def fwd_bwd(qkv, w, dq, dk, dv):
+        out, vjp = jax.vjp(functools.partial(
+            gated_causal_conv, n_qk=2048, head=128), qkv, w)
+        return out, vjp((dq, dk, dv))
+
+    before = pallas.selections().get("causal_conv.mosaic", 0)
+    qk = S((4, 8192, 2048), dtype)
+    low = fwd_bwd.lower(S((4, 8192, 8192), dtype), S((4, 8192), dtype),
+                        qk, qk, S((4, 8192, 4096), dtype))
+    assert pallas.selections()["causal_conv.mosaic"] == before + 1
+    assert "causal_conv.interpret" not in pallas.selections()
+    assert _mosaic_calls(low) == 2
+    calls = _custom_calls(low.compile().as_text())
+    # (outside a scope the compiler wraps the names: ``jvp_gdn_conv_fwd_``)
+    assert sum("gdn_conv_fwd" in c for c in calls) == 1, calls
+    assert sum("gdn_conv_bwd" in c for c in calls) == 1, calls
+
+
+def _custom_calls(text):
+    """The names of a compiled program's custom calls, less the trailing
+    number (by the opcode: XLA also names a fusion that takes a kernel's
+    result after the kernel)."""
+    return re.findall(r"%(\w+)\.\d+ = [^\n]*? custom-call\(", text)
+
+
 def _cell_step(v5e, monkeypatch, driver: str, config: str, workload: str):
     """The whole step of a benchmark cell — the configuration file's
     model at the cell's batch, bf16 over fp32 masters, the file's remat
@@ -316,22 +356,30 @@ def test_the_qwen3_next_step_fits_the_chip_at_the_cell_s_size(
         v5e, monkeypatch, capsys):
     """The whole step of ``qwen3-next-80b-a3b.train-t8192`` (424.3 M
     parameters, B = 4 x T = 8192) compiled for one v5e: six delta-rule
-    kernels a layer in three layers, no ``triangular_solve``, and what the
-    step holds stays under the chip's ``bytes_limit`` of 16.91 GB and is
-    no more than with XLA's prep (PR 37's step, compiled the same way:
-    14.502 GB against 13.31; autodiff's float32 ``(n, H, C, C)`` residuals of a row
-    went)."""
+    kernels a layer in three layers, no ``triangular_solve``; the
+    convolution's pair three times a layer (forward, the forward again in
+    the recompute, backward); and what the step holds stays under the
+    chip's ``bytes_limit`` of 16.91 GB and is no more than with XLA's
+    convolution (PR 39's step, compiled the same way: 13.31 GB; with
+    XLA's prep too, PR 37's, 14.502)."""
     compiled, n_params = _cell_step(
         v5e, monkeypatch, "qwen3_next_train", "qwen3-next-80b-a3b",
         "qwen3-next-80b-a3b.train-t8192")
     text = compiled.as_text()
-    assert len(set(re.findall(r"%delta_rule_\w+\.\d+ = ", text))) == 18
+    calls = _custom_calls(text)
+    assert sum(c.startswith("delta_rule_") for c in calls) == 18
+    assert sorted(c for c in calls if c.startswith("gdn_conv_")) \
+        == ["gdn_conv_bwd"] * 3 + ["gdn_conv_fwd"] * 6
     assert "triangular" not in text.lower()
+    # q, k, v, o and their cotangents go token-major from kernel to
+    # kernel and into ``gdn_out``: no relayout of any of them
+    for shape in HANDOVER_SHAPES:
+        assert handover_copies(text, shape) == [], shape
     held = _held(compiled, n_params)
     with capsys.disabled():
         print(f"\nqwen3-next step, chip-free: {held / 1e9:.3f} GB held "
-              f"(the parent's, with XLA's prep: 14.502 GB)")
-    assert 11e9 < held < 15.9e9, held
+              f"(the parent's, with XLA's convolution: 13.31 GB)")
+    assert 11e9 < held < 13.5e9, held
 
 
 # ---------------------------------------------------------------------------
