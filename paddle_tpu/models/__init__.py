@@ -12,8 +12,10 @@ attention, softmax-routed experts beside a gated shared expert.
 ``joyai_flash`` is a fourth: latent attention (MLA) with a 192-wide q/k
 head over a 128-wide v head, sigmoid-routed experts beside an ungated
 shared expert, and a multi-token-prediction module scored by the one loss
-head a second time.  ``sparse_blocks`` holds what the three sparse models
-share.
+head a second time.  ``mellum2`` is a fifth: grouped-query attention over
+a sliding window in three layers of four and over the whole prefix with
+YaRN RoPE in the fourth, softmax-routed experts.  ``sparse_blocks`` holds
+what the sparse models share.
 """
 import time as _time
 
@@ -30,6 +32,8 @@ from .qwen3_next import (Qwen3NextConfig,  # noqa: F401
 from .joyai_flash import (JoyAIFlashConfig,  # noqa: F401
                           init_joyai_flash_params,
                           joyai_flash_param_shardings)
+from .mellum2 import (Mellum2Config, init_mellum2_params,  # noqa: F401
+                      mellum2_param_shardings)
 from ..profiler import tracer as _tracer  # noqa: E402
 
 # the step builders bring jax.experimental.pallas in, most of this span

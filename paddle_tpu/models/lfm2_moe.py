@@ -34,8 +34,6 @@ from dataclasses import dataclass
 from types import SimpleNamespace
 from typing import Dict, Optional, Tuple
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -44,7 +42,7 @@ from jax.sharding import Mesh
 
 from .sparse_blocks import (batch_axes_of, dense_ffn as _dense_ffn,
                             held_experts, leaf_name, moe_counters,
-                            rms_norm as _rms, rope_angles)
+                            rms_norm as _rms, rope_angles, rope_rotate_half)
 
 __all__ = ["Lfm2MoeConfig", "init_lfm2_moe_params",
            "lfm2_moe_param_shardings"]
@@ -147,15 +145,7 @@ def lfm2_moe_param_shardings(mesh: Mesh, cfg: Lfm2MoeConfig) -> Dict:
 
 def _rope(x, theta):
     """Rotate-half RoPE over the whole head; x: (B, T, H, hd)."""
-    T, hd = x.shape[1], x.shape[-1]
-    ang = rope_angles(T, theta, hd)
-    cos = jnp.asarray(np.cos(np.concatenate([ang, ang], -1)), jnp.float32)
-    sin = jnp.asarray(np.sin(np.concatenate([ang, ang], -1)), jnp.float32)
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :hd // 2], xf[..., hd // 2:]
-    rot = jnp.concatenate([-x2, x1], axis=-1)
-    return (xf * cos[None, :, None, :]
-            + rot * sin[None, :, None, :]).astype(x.dtype)
+    return rope_rotate_half(x, rope_angles(x.shape[1], theta, x.shape[-1]))
 
 
 def _short_conv(p, x, eps):

@@ -1,11 +1,12 @@
 """What the sparse functional models share (``lfm2_moe``, ``qwen3_next``,
-``joyai_flash``): the pieces of a block that are the same mathematics
+``joyai_flash``, ``mellum2``): the pieces of a block that are the same mathematics
 under each of them, kept once.  Each model keeps what is its own — its
 mixers, its norm where that differs (Qwen3-Next's is zero-centred), its
 RoPE pairing — and the scopes it names its parts with.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import numpy as np
@@ -15,8 +16,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
 
-__all__ = ["rms_norm", "rope_angles", "swiglu", "dense_ffn", "held_experts",
-           "batch_axes_of", "moe_counters", "leaf_name"]
+__all__ = ["rms_norm", "rope_angles", "rope_rotate_half", "swiglu",
+           "dense_ffn", "held_experts", "batch_axes_of", "moe_counters",
+           "leaf_name"]
 
 
 def rms_norm(x, g, eps):
@@ -27,13 +29,49 @@ def rms_norm(x, g, eps):
     return y.astype(x.dtype) * g.astype(x.dtype)
 
 
-def rope_angles(T: int, theta: float, rotary: int):
+def rope_angles(T: int, theta: float, rotary: int, yarn=None):
     """(T, rotary / 2) float64 angles ``t * theta^(-2i / rotary)`` of
     RoPE over ``rotary`` components; which components pair up
-    (rotate-half, interleaved) is the model's."""
+    (rotate-half, interleaved) is the model's.
+
+    ``yarn``: (factor, original_max_position_embeddings, beta_fast,
+    beta_slow) — YaRN's frequencies (HF ``rope_type: yarn``, truncated
+    correction range): ``inv_i = inv_i / factor (1 - e_i) + inv_i e_i``
+    with ``e_i = 1 - clamp((i - lo) / (hi - lo), 0, 1)``, ``lo =
+    floor(c(beta_fast))``, ``hi = ceil(c(beta_slow))``, ``c(r) = rotary
+    ln(original / (2 pi r)) / (2 ln theta)``: the fast components keep
+    their frequency, the slow ones are interpolated by the factor.  The
+    attention factor that scales cos and sin is the caller's."""
     inv = 1.0 / (theta ** (np.arange(0, rotary, 2, dtype=np.float64)
                            / rotary))
+    if yarn is not None:
+        factor, original, beta_fast, beta_slow = yarn
+
+        def c(r):
+            return rotary * math.log(original / (2 * math.pi * r)) \
+                / (2 * math.log(theta))
+
+        lo = max(math.floor(c(beta_fast)), 0)
+        hi = min(math.ceil(c(beta_slow)), rotary - 1)
+        ramp = np.clip((np.arange(rotary // 2) - lo) / max(hi - lo, 1e-3),
+                       0, 1)
+        inv = inv / factor * ramp + inv * (1 - ramp)
     return np.arange(T, dtype=np.float64)[:, None] * inv[None, :]
+
+
+def rope_rotate_half(x, ang, scale: float = 1.0):
+    """Rotate-half RoPE over the whole head, pairs ``(i, i + hd / 2)``
+    turned by ``ang`` (T, hd / 2), cos and sin times ``scale`` (YaRN's
+    attention factor); in float32.  x: (B, T, H, hd)."""
+    hd = x.shape[-1]
+    both = np.concatenate([ang, ang], -1)
+    cos = jnp.asarray(scale * np.cos(both), jnp.float32)
+    sin = jnp.asarray(scale * np.sin(both), jnp.float32)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :hd // 2], xf[..., hd // 2:]
+    rot = jnp.concatenate([-x2, x1], axis=-1)
+    return (xf * cos[None, :, None, :]
+            + rot * sin[None, :, None, :]).astype(x.dtype)
 
 
 def swiglu(z, w1, w3, w2):

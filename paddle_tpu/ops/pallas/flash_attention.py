@@ -10,28 +10,33 @@ Which kernels a call runs is decided once, by ``_plan``, from the layout,
 the per-shard shapes and the chip's VMEM — never from an option.  The
 selection name is what ``ops.pallas.selections()`` counts:
 
-========================  ===============  ==============================  ====  ========
-layout and lengths        selection        kernels, forward / backward     cell  d_v != d
-========================  ===============  ==============================  ====  ========
-stacked, T <= 512         packed_small     _qkv_fwd_kernel /               none  (one d)
+========================  ===============  ========================  =====  ========  ==========
+layout and lengths        selection        kernels, forward /        cell   d_v != d  window
+                                           backward
+========================  ===============  ========================  =====  ========  ==========
+stacked, T <= 512         packed_small     _qkv_fwd_kernel /         none   (one d)   (none)
                                            _qkv_bwd_kernel
-stacked, T <= 2048        packed_mid       _qkv_fwd_kernel /               GPT   (one d)
+stacked, T <= 2048        packed_mid       _qkv_fwd_kernel /         GPT    (one d)   (none)
                                            _qkv_mid_bwd_kernel
 stacked, anything else    (split; then as the folded layout below)
-folded, T, Tk <= 1024     small            _small_fwd_kernel /             none  XLA math
-                                           _small_bwd_kernel (Tk <= 512),
-                                           _tiled_bwd_kernel (beyond)
-folded, T, Tk <= 4096     mid              _small_fwd_kernel /             none  XLA math
+folded, T, Tk <= 1024     small            _small_fwd_kernel /       none   XLA math  the
+                                           _small_bwd_kernel         resident pair
+                                           (Tk <= 512),                     below
                                            _tiled_bwd_kernel
-folded, longer, resident  stream and       _resident_fwd_kernel /          LFM2  kernels
-budget fits the chip      stream_resident  _resident_bwd_kernel            Q3N
-                                                                           JoyAI
-folded, longer, it does   stream           _fwd_kernel_pipelined /         none  XLA math
-not fit                                    _bwd_dq_kernel, _bwd_dkv_kernel
-a length no multiple of   (XLA math, both directions; counted as           none
+                                           (beyond)
+folded, T, Tk <= 4096     mid              _small_fwd_kernel /       none   XLA math  the
+                                           _tiled_bwd_kernel                resident pair
+folded, longer, resident  stream and       _resident_fwd_kernel /    LFM2   kernels   kernels,
+budget fits the chip      stream_resident  _resident_bwd_kernel      Q3N              the band
+                                                                     JoyAI            alone:
+                                                                     Mellum2          _window
+folded, longer, it does   stream           _fwd_kernel_pipelined /   none   XLA math  XLA math
+not fit                                    _bwd_dq_kernel,
+                                           _bwd_dkv_kernel
+a length no multiple of   (XLA math, both directions; counted as     none             the mask
 128, causal T > Tk, off   ``flash_attention.xla``)
 a TPU without FORCE
-========================  ===============  ==============================  ====  ========
+========================  ===============  ========================  =====  ========  ==========
 
 stacked is (3, B, T, H*d), folded (B*H, T, d).  GPT is the benchmark cell
 ``gpt2-medium.train-t1024``, LFM2 is ``lfm2-24b-a2b.train-t8192`` (d =
@@ -43,6 +48,22 @@ pair carries it — V rows, the output, dO, the output accumulator and the
 dV accumulator at ``d_v``, q, K, dq and dK at ``d``, the VMEM budget from
 both —, every other regime hands such a call to the XLA math, counted
 ``flash_attention.xla``; no cell depends on that.
+
+``window`` (a sliding window, causal only: query i sees key j where ``0
+<= i + Tk - T - j < window``) is an argument of the call.  A windowed
+call takes the resident pair at any length its budget fits — the small
+and mid regimes would take their whole rows — and runs each q block over
+the key chunks of its band alone: ``_live_chunks`` gives the lower edge
+beside the diagonal, the mask is built on the chunks either edge
+crosses, and dK/dV accumulate over the same band.  Where the pair does
+not fit, the XLA math takes the call with the same mask.  The selection
+is ``flash_attention.stream_resident_window`` and the two calls carry
+``name=`` (``flash_window_fwd``, ``flash_window_bwd``), which a call with
+no window does not: the device trace tells a window layer's pair from a
+full layer's, whose shapes are the same.  A window that reaches every
+key (``window >= Tk``) is causal attention, and the call is one.
+The Mellum2 cell is ``mellum2-12b-a2.5b.train-t8192`` (window 1024 in
+three layers of four, d = 128).
 "stacked" takes its kernels when the head size is 32, 64 or 128 and the
 heads fill 128-lane column blocks.  The regimes:
 
@@ -137,6 +158,10 @@ RESIDUAL_NAMES = ("flash_out", "flash_lse")
 # Share of the chip's VMEM the resident pair may ask for; the rest is
 # Mosaic's own (internal scratch, semaphores, spills).
 _RESIDENT_VMEM_SHARE = 0.75
+# The names a windowed call's forward and backward carry into the device
+# trace (a call with no window carries none: its names are the ones the
+# benchmark's patterns have found since PR 27)
+WINDOW_NAMES = ("flash_window_fwd", "flash_window_bwd")
 
 
 # ---------------------------------------------------------------------------
@@ -155,6 +180,7 @@ class _Plan(NamedTuple):
     fwd: tuple = (None, None, 1)
     bwd: tuple = (None, None, 1)
     vmem_limit: Optional[int] = None    # the resident pair's request
+    window: Optional[int] = None        # the call's sliding window
 
 
 def _kernels_apply(T: int, Tk: int, causal: bool) -> bool:
@@ -230,16 +256,18 @@ def _vmem_capacity() -> int:
 
 
 def _plan(layout: str, B: int, T: int, Tk: int, heads: int, d: int,
-          itemsize: int, causal: bool, d_v: Optional[int] = None) -> _Plan:
+          itemsize: int, causal: bool, d_v: Optional[int] = None,
+          window: Optional[int] = None) -> _Plan:
     """The one place that turns a call's (per-shard) shapes into kernels
     and block sizes.  ``layout`` is "stacked" ((3, B, T, heads*d)) or
     "folded" ((B*heads, T, d)); a stacked call whose shape the stacked
     kernels do not take gets the folded plan it then runs split.  ``d``
     is the head size of q and k, ``d_v`` that of v and the output where
     it differs (latent attention: 192 over 128); the resident pair alone
-    takes such a call, every other regime hands it to the XLA math."""
+    takes such a call, every other regime hands it to the XLA math.  A
+    ``window`` takes the resident pair at any length, or the XLA math."""
     if not _kernels_apply(T, Tk, causal):
-        return _Plan("xla")
+        return _Plan("xla", window=window)
     unequal = d_v is not None and d_v != d
     interpret = not on_tpu()
     # G, the rows (batch rows, batch-heads) a grid step takes, is at most
@@ -253,8 +281,9 @@ def _plan(layout: str, B: int, T: int, Tk: int, heads: int, d: int,
     # resident, G batch-heads per grid step, one fused backward) beats
     # it.  The mid kernels carry the same design to T<=MID_T_MAX (4096);
     # the stream regime owns anything longer.
-    small = Tk <= SMALL_T_MAX and T <= SMALL_T_MAX
-    mid = not small and Tk <= MID_T_MAX and T <= MID_T_MAX
+    small = Tk <= SMALL_T_MAX and T <= SMALL_T_MAX and window is None
+    mid = not small and Tk <= MID_T_MAX and T <= MID_T_MAX \
+        and window is None
     if unequal and (small or mid):
         return _Plan("xla")
 
@@ -346,9 +375,9 @@ def _plan(layout: str, B: int, T: int, Tk: int, heads: int, d: int,
     if limit <= _RESIDENT_VMEM_SHARE * _vmem_capacity():
         return _Plan("stream_resident", interpret,
                      fwd=(_dividing(T, 1024), _dividing(Tk, 1024), 1),
-                     bwd=bwd, vmem_limit=limit)
-    if unequal:
-        return _Plan("xla")
+                     bwd=bwd, vmem_limit=limit, window=window)
+    if unequal or window is not None:
+        return _Plan("xla", window=window)
     return _Plan("stream", interpret,
                  fwd=(_block(T, 256), _block(Tk, 512), 1),
                  bwd=(_block(T, 256), _block(Tk, 256), 1))
@@ -375,11 +404,12 @@ def _dot(a, b, dims):
 
 
 def _causal_mask(shape, qi, block_q: int, offset: int, j=None,
-                 chunk: int = 0):
+                 chunk: int = 0, window: Optional[int] = None):
     """True where a score tile's query row sees its key column: row r of
-    q block ``qi`` sees columns <= qi * block_q + r + offset.  The tile
-    is key chunk ``j`` of ``chunk`` columns, or (j None) starts at
-    column 0."""
+    q block ``qi`` sees columns <= qi * block_q + r + offset, and with a
+    ``window`` only the last ``window`` of them.  The tile is key chunk
+    ``j`` of ``chunk`` columns, or (j None) starts at column 0 (no
+    window there: the whole-row kernels take none)."""
     rows = lax.broadcasted_iota(jnp.int32, shape, 0)
     if j is None:
         # the whole-row kernels add the offset (a static 0 wherever
@@ -390,7 +420,10 @@ def _causal_mask(shape, qi, block_q: int, offset: int, j=None,
         return rows + qi * block_q + offset \
             >= lax.broadcasted_iota(jnp.int32, shape, 1)
     rows = rows + (qi * block_q + offset)
-    return rows >= lax.broadcasted_iota(jnp.int32, shape, 1) + j * chunk
+    cols = lax.broadcasted_iota(jnp.int32, shape, 1) + j * chunk
+    if window is None:
+        return rows >= cols
+    return (rows >= cols) & (rows - cols < window)
 
 
 def _mask_row(s, mask):
@@ -515,16 +548,26 @@ def _saved_lse_bwd_tile(q, k, v, do, lse, delta, mask, scale: float, *,
 # stream regime, grid-streamed form
 # ---------------------------------------------------------------------------
 def _live_chunks(qi, block_q: int, chunk: int, offset: int, nk: int,
-                 causal: bool = True):
-    """(n_full, n_live) for q block ``qi``: key chunks [0, n_full) hold
-    no masked score, chunks [n_full, n_live) are crossed by the diagonal
-    (row r sees columns <= r + offset), chunks from n_live on are dead.
-    ``qi`` may be a Python int or a traced scalar."""
+                 causal: bool = True, window: Optional[int] = None):
+    """(n_lo, n_clean, n_full, n_live) for q block ``qi``, whose row r
+    sees columns <= r + offset (and with a ``window`` only the last
+    ``window`` of them): key chunks [n_clean, n_full) hold no masked
+    score, chunks [n_full, n_live) are crossed by the diagonal, chunks
+    [n_lo, n_clean) by the window's lower edge (0 and 0 without a
+    window), chunks outside [n_lo, n_live) are dead.  ``qi`` may be a
+    Python int or a traced scalar."""
     if not causal:
-        return nk, nk
+        return 0, 0, nk, nk
     n_full = jnp.minimum(nk, (qi * block_q + offset + 1) // chunk)
     n_live = jnp.minimum(nk, ((qi + 1) * block_q - 1 + offset) // chunk + 1)
-    return n_full, n_live
+    if window is None:
+        return 0, 0, n_full, n_live
+    # the block's first row sees down to its diagonal less window - 1,
+    # its last row only its own: every chunk from there on is clean
+    n_lo = jnp.maximum(0, (qi * block_q + offset - window + 1) // chunk)
+    lowest = (qi + 1) * block_q + offset - window
+    n_clean = jnp.clip(-(-lowest // chunk), n_lo, n_live)
+    return n_lo, n_clean, jnp.maximum(n_full, n_clean), n_live
 
 
 def _grid_tile(qi, ki, causal: bool, block_q: int, block_k: int,
@@ -571,7 +614,7 @@ def _clamped_k_map(block_q, block_k, offset, nk, causal):
         return lambda b, i, j: (b, j, 0)
 
     def index(b, i, j):
-        _, n_live = _live_chunks(i, block_q, block_k, offset, nk)
+        n_live = _live_chunks(i, block_q, block_k, offset, nk)[-1]
         return b, jnp.minimum(j, n_live - 1), 0
     return index
 
@@ -690,29 +733,37 @@ def _flash_bwd(q, k, v, o, lse, do, scale: float, causal: bool,
 # mask leaves live, one fused backward
 # ---------------------------------------------------------------------------
 def _for_live_chunks(step, qi, live, causal: bool, block_q: int, chunk: int,
-                     offset: int):
+                     offset: int, window: Optional[int] = None):
     """``step(rows, mask)`` on every key chunk ``live`` (what
-    :func:`_live_chunks` gave for q block ``qi``) names: first the
-    chunks that hold no masked score, then, with their mask, those the
-    diagonal crosses.  ``rows`` slices the chunk out of a resident
-    (Tk, d) row block."""
-    n_full, n_live = live
+    :func:`_live_chunks` gave for q block ``qi``) names: with a window
+    first the chunks its lower edge crosses, with their mask; then the
+    chunks that hold no masked score; then, with their mask, those the
+    diagonal crosses.  ``rows`` slices the chunk out of a resident (Tk,
+    d) row block."""
+    n_lo, n_clean, n_full, n_live = live
 
     def run(j, masked):
         step(pl.ds(pl.multiple_of(j * chunk, chunk), chunk),
              (lambda shape: _causal_mask(shape, qi, block_q, offset, j,
-                                         chunk)) if masked else None)
+                                         chunk, window)) if masked else None)
 
-    lax.fori_loop(0, n_full, lambda j, c: run(j, False), None)
+    if window is not None:
+        lax.fori_loop(n_lo, n_clean, lambda j, c: run(j, True), None)
+    lax.fori_loop(n_clean, n_full, lambda j, c: run(j, False), None)
     if causal:
         lax.fori_loop(n_full, n_live, lambda j, c: run(j, True), None)
 
 
 def _resident_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                          acc_scr, *, scale: float, causal: bool,
-                         block_q: int, chunk: int, nk: int, offset: int):
+                         block_q: int, chunk: int, nk: int, offset: int,
+                         window: Optional[int] = None):
+    """The online softmax over the live key chunks of the resident rows.
+    A row wholly masked in the first chunk of a window's band adds 1s
+    there; its next chunk holds a live column, and the correction
+    exp(NEG_INF - m) = 0 takes them out again."""
     qi = pl.program_id(1)
-    live = _live_chunks(qi, block_q, chunk, offset, nk, causal)
+    live = _live_chunks(qi, block_q, chunk, offset, nk, causal, window)
     _online_softmax_init(m_scr, l_scr, acc_scr)
     q = q_ref[0]                                         # (bq, d)
 
@@ -720,20 +771,22 @@ def _resident_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         _online_softmax_step(q, k_ref[0, rows, :], v_ref[0, rows, :], mask,
                              scale, m_scr, l_scr, acc_scr)
 
-    _for_live_chunks(step, qi, live, causal, block_q, chunk, offset)
+    _for_live_chunks(step, qi, live, causal, block_q, chunk, offset, window)
     _online_softmax_finish(o_ref, lse_ref, m_scr, l_scr, acc_scr)
 
 
 def _resident_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
                          *, scale: float, causal: bool, block_q: int,
-                         chunk: int, nq: int, nk: int, offset: int):
+                         chunk: int, nq: int, nk: int, offset: int,
+                         window: Optional[int] = None):
     """q blocks ride the inner ('arbitrary') grid dim; for each, the
     live key chunks of the resident K/V rows: dq accumulated over the
     chunks and written per q block, dK/dV accumulated in f32 scratch
-    rows until the head's last q block."""
+    rows until the head's last q block (a window's band alone: rows
+    below it keep their zeros)."""
     qi = pl.program_id(1)
-    live = _live_chunks(qi, block_q, chunk, offset, nk, causal)
+    live = _live_chunks(qi, block_q, chunk, offset, nk, causal, window)
 
     _zero_on_first(qi, dk_scr, dv_scr)
 
@@ -749,7 +802,7 @@ def _resident_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                             dk=(dk_scr, (rows, slice(None))),
                             dv=(dv_scr, (rows, slice(None))))
 
-    _for_live_chunks(step, qi, live, causal, block_q, chunk, offset)
+    _for_live_chunks(step, qi, live, causal, block_q, chunk, offset, window)
     dq_ref[0] = (scale * dq_scr[...]).astype(dq_ref.dtype)
 
     @pl.when(qi == nq - 1)
@@ -770,7 +823,7 @@ def _stream_flash_fwd(q, k, v, scale: float, causal: bool, plan: _Plan):
                   offset=Tk - T)
     if plan.name == "stream_resident":
         kernel = functools.partial(_resident_fwd_kernel, chunk=block_k,
-                                   **params)
+                                   window=plan.window, **params)
         grid, semantics = (BH, T // block_q), ("parallel", "arbitrary")
         q_map = lambda b, i: (b, i, 0)                          # noqa: E731
         k_spec = pl.BlockSpec((1, Tk, d), lambda b, i: (b, 0, 0))
@@ -798,6 +851,7 @@ def _stream_flash_fwd(q, k, v, scale: float, causal: bool, plan: _Plan):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics, vmem_limit_bytes=plan.vmem_limit),
         interpret=plan.interpret,
+        name=WINDOW_NAMES[0] if plan.window else None,
     )(q, k, v)
 
 
@@ -818,7 +872,7 @@ def _resident_flash_bwd(q, k, v, o, lse, do, scale: float, causal: bool,
     return pl.pallas_call(
         functools.partial(_resident_bwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, chunk=chunk, nq=nq,
-                          nk=Tk // chunk, offset=Tk - T),
+                          nk=Tk // chunk, offset=Tk - T, window=plan.window),
         grid=(BH, nq),
         in_specs=[qs, ks, vs, dos, rs, rs],
         out_specs=[qs, ks, vs],
@@ -830,6 +884,7 @@ def _resident_flash_bwd(q, k, v, o, lse, do, scale: float, causal: bool,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=plan.vmem_limit),
         interpret=plan.interpret,
+        name=WINDOW_NAMES[1] if plan.window else None,
     )(q, k, v, do, lse, delta)
 
 
@@ -1174,13 +1229,16 @@ def _qkv_bwd(qkv, do, num_heads: int, scale: float, causal: bool,
 # XLA fallback + custom_vjp stitching: every rule reads the plan its public
 # entry made; the primal is the forward rule's first result
 # ---------------------------------------------------------------------------
-def _xla_attention(q, k, v, scale, causal):
+def _xla_attention(q, k, v, scale, causal, window=None):
     # (BH, T, d) reference math for the short-sequence / CPU path
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
         Tq, Tk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((Tq, Tk), bool), k=Tk - Tq)
+        if window is not None:
+            mask &= jnp.triu(jnp.ones((Tq, Tk), bool),
+                             k=Tk - Tq - window + 1)
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return jnp.einsum("bqk,bkd->bqd", p, v)
@@ -1215,8 +1273,8 @@ def _flash_vjp_fwd(q, k, v, scale, causal, plan):
     # residuals are the raw inputs: under remat they rebuild from the
     # (cheap) qkv projection, never by re-running the kernel
     if plan.name == "xla":
-        return _xla_attention(q, k, v, scale, causal).astype(q.dtype), \
-            (q, k, v)
+        return _xla_attention(q, k, v, scale, causal, plan.window) \
+            .astype(q.dtype), (q, k, v)
     return _small_flash_fwd(q, k, v, scale, causal, plan), (q, k, v)
 
 
@@ -1224,8 +1282,9 @@ def _flash_vjp_bwd(scale, causal, plan, res, g):
     q, k, v = res
     if plan.name == "xla":
         _, vjp = jax.vjp(
-            lambda q, k, v: _xla_attention(q, k, v, scale, causal)
-            .astype(q.dtype), q, k, v)
+            lambda q, k, v: _xla_attention(q, k, v, scale, causal,
+                                           plan.window).astype(q.dtype),
+            q, k, v)
         return vjp(g)
     return _row_flash_bwd(q, k, v, g, scale, causal, plan)
 
@@ -1287,7 +1346,8 @@ _flash_stream.defvjp(_flash_stream_vjp_fwd, _flash_stream_vjp_bwd)
 def _attend(q, k, v, scale: float, causal: bool, plan: _Plan):
     """(B, T, H, d) attention under a folded-layout plan; counts the
     selection.  A stream call counts as ``stream`` and, where the
-    resident pair runs it, as ``stream_resident`` too."""
+    resident pair runs it, as ``stream_resident`` too (a windowed one as
+    ``stream_resident_window``)."""
     kernels = plan.name != "xla"
     stream = plan.name.startswith("stream")
     name = "stream" if stream else plan.name
@@ -1295,7 +1355,8 @@ def _attend(q, k, v, scale: float, causal: bool, plan: _Plan):
          kernels)
     if stream:
         if plan.name == "stream_resident":
-            note("flash_attention.stream_resident", True)
+            note("flash_attention.stream_resident"
+                 + ("_window" if plan.window else ""), True)
         return _flash_stream(q, k, v, scale, causal, plan)
     out = _flash(_fold(q), _fold(k), _fold(v), scale, causal, plan)
     return _unfold(out, q.shape[0])
@@ -1356,12 +1417,19 @@ def flash_attention_stacked(qkv, num_heads: int, *, causal: bool = False,
 
 
 def flash_attention(q, k, v, *, causal: bool = False, scale=None,
-                    mesh=None, batch_axes=(), head_axes=()):
+                    window: Optional[int] = None, mesh=None, batch_axes=(),
+                    head_axes=()):
     """q/k: (B, S, H, D), v: (B, S, H, Dv) paddle layout -> (B, S, H,
     Dv).  ``Dv`` may differ from ``D`` (latent attention attends with a
     192-wide q/k head over a 128-wide v head): the stream regime's
     resident pair takes such a call, every other shape runs it as XLA
     math (``flash_attention.xla``).
+
+    ``window``: a sliding window over a causal call — query i sees key j
+    where ``0 <= i + Tk - S - j < window`` (a query sees ``window`` keys,
+    itself included, as HF's ``sliding_window``).  The resident pair
+    takes it at any length and computes the band alone; where the pair
+    does not fit, XLA math with the same mask.
 
     All kernels go through the folded (B*H, T, d) layout — TPU tiling
     forbids blocking the head dim of (B, T, H, d) directly (the last
@@ -1376,11 +1444,17 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
     B, T, H, D = q.shape
     Tk = k.shape[1]
     s = float(scale) if scale is not None else float(1.0 / np.sqrt(D))
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"a window of {window} over a call with "
+                             f"causal={causal}: a window is >= 1 and causal")
+        if window >= Tk:        # every key in reach: causal attention
+            window = None
 
     def local(q, k, v):
         b, _, h, _ = q.shape
         plan = _plan("folded", b, T, Tk, h, D, q.dtype.itemsize, causal,
-                     v.shape[-1])
+                     v.shape[-1], window)
         return _attend(q, k, v, s, causal, plan)
 
     if not _kernels_apply(T, Tk, causal):

@@ -92,8 +92,10 @@ def softmax_xent_fwd(x, w, labels, block_rows: int = 1024,
     # the resident x block is double-buffered: past D = 1024 fewer rows
     # keep it at the 1024 x 1024 elements the 16 MB of scoped VMEM hold
     # beside the W tiles and the f32 logits tile (D = 2048 at 1024 rows
-    # asked for 21 MB, compiled chip-free for a v5e)
-    block_rows = min(block_rows, N, max(128, (1 << 20) // D))
+    # asked for 21 MB, compiled chip-free for a v5e); a power of two, so
+    # that halving it finds a divisor of N (D = 2304: 455 halved to 1)
+    fits = (1 << 20) // D
+    block_rows = min(block_rows, N, max(128, 1 << (fits.bit_length() - 1)))
     while N % block_rows:
         block_rows //= 2
     w, V, Vp = _pad_vocab(w, block_v)

@@ -39,15 +39,16 @@ def unfused_instructions(hlo_text):
     return tuple(out)
 
 
-def handover_copies(hlo_text, shape: str):
+def handover_copies(hlo_text, shape: str, scopes=MIXER_SCOPES):
     """The ``copy``, ``reshape`` and ``transpose`` operations — each a
     pass over every byte; a reshape that changes nothing in memory is a
-    ``bitcast`` by now — whose result is ``shape``, under one of the
-    mixer's scopes or under no name, where the compiler puts a copy it
-    makes for a layout of its own choosing."""
+    ``bitcast`` by now — whose result is ``shape``, under one of
+    ``scopes`` (the delta-rule mixer's unless named) or under no name,
+    where the compiler puts a copy it makes for a layout of its own
+    choosing."""
     return [(name, result, op) for name, result, opcode, op
             in unfused_instructions(hlo_text)
             if opcode in ("copy", "reshape", "transpose")
             and result.startswith(shape + "{")
             and (not op or any(f"{s}/" in op or f"({s})" in op
-                               for s in MIXER_SCOPES))]
+                               for s in scopes))]

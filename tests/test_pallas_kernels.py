@@ -781,8 +781,10 @@ def test_live_chunks_against_the_mask():
                     nk = seq_k // chunk
                     mask = np.tril(np.ones((seq_q, seq_k), bool), k=offset)
                     for qi in range(nq):
-                        n_full, n_live = (int(n) for n in fa._live_chunks(
-                            qi, block_q, chunk, offset, nk))
+                        n_lo, n_clean, n_full, n_live = (
+                            int(n) for n in fa._live_chunks(
+                                qi, block_q, chunk, offset, nk))
+                        assert n_lo == n_clean == 0     # no window
                         rows = mask[qi * block_q:(qi + 1) * block_q]
                         tiles = [rows[:, j * chunk:(j + 1) * chunk]
                                  for j in range(nk)]
@@ -794,7 +796,7 @@ def test_live_chunks_against_the_mask():
                                    for t in tiles[n_full:n_live])
                         checked += 1
     assert checked > 300
-    assert fa._live_chunks(3, 4, 2, 0, 7, causal=False) == (7, 7)
+    assert fa._live_chunks(3, 4, 2, 0, 7, causal=False) == (0, 0, 7, 7)
 
 
 @pytest.mark.parametrize("offset", [0, 256])

@@ -813,3 +813,134 @@ def test_no_other_step_holds_the_delta_rule_mixer_s_kernels(request,
     assert not any(c.startswith(("%gdn_conv_", "%delta_rule_"))
                    for c in _mosaic_calls(hlo))
     assert "gdn_conv" not in hlo
+
+
+# ---------------------------------------------------------------------------
+# the Mellum2 step: its own scopes, its own name, its windowed kernels
+# ---------------------------------------------------------------------------
+MELLUM2_SCOPES = ("embed", "mellum_qkv", "mellum_out", "moe_route",
+                  "moe_dispatch", "moe_experts", "moe_combine",
+                  "final_norm")
+
+
+def _mellum2(**kw):
+    from paddle_tpu.models import Mellum2Config
+    return Mellum2Config(**{
+        "num_hidden_layers": 2,
+        "layer_types": ("sliding_attention", "full_attention"), **kw})
+
+
+@pytest.fixture(scope="module")
+def tiny_mellum2_step_text():
+    cfg = _mellum2(vocab_size=128, hidden_size=32, num_attention_heads=4,
+                   num_key_value_heads=2, head_dim=8, sliding_window=4,
+                   num_experts=8, num_experts_per_tok=2, num_experts_held=4,
+                   moe_intermediate_size=16)
+    return _lower_lfm2(cfg, build_mesh({"dp": 1}), 2, 16,
+                       remat_policy="ctx").compile().as_text()
+
+
+def test_the_mellum2_step_has_its_own_name(tiny_mellum2_step_text):
+    assert "HloModule jit_mellum2_spmd_train_step" in tiny_mellum2_step_text
+    names = _op_names(tiny_mellum2_step_text)
+    assert any("/optimizer/" in n and "transpose(" not in n for n in names)
+    # the other models' scopes are theirs
+    assert not any(_under(n, BLOCK + (
+        "unstack", "final_ln", "short_conv", "gqa_qkv", "gqa_out",
+        "dense_ffn", "gdn_in", "gattn_qkv", "mla_q", "shared_expert"))
+        for n in names)
+
+
+@pytest.mark.parametrize("scope", MELLUM2_SCOPES)
+def test_a_mellum2_scope_forward_and_backward(tiny_mellum2_step_text,
+                                              scope):
+    names = _op_names(tiny_mellum2_step_text)
+    assert any(f"jvp({scope})" in n for n in names), scope
+    assert any("transpose(" in n and _under(n, [scope]) for n in names), \
+        scope
+
+
+def test_mellum2_matmuls_sit_under_a_scope(tiny_mellum2_step_text):
+    """Every dot_general but attention's own (XLA math on the CPU, a
+    sibling of the scopes) and the loss head's sits under one of the
+    model's scopes."""
+    names = _op_names(tiny_mellum2_step_text)
+    dots = [n for n in names if n.endswith("dot_general")]
+    attention = [n for n in dots if "bqd,bkd->bqk" in n or "bqk,bkd->bqd" in n]
+    assert attention and not any(_under(n, MELLUM2_SCOPES)
+                                 for n in attention)
+    rest = [n for n in dots
+            if n not in attention and not _under(n, ["loss_head"])]
+    assert rest and all(_under(n, MELLUM2_SCOPES) for n in rest), \
+        [n for n in rest if not _under(n, MELLUM2_SCOPES)]
+
+
+@pytest.fixture(scope="module")
+def mellum2_real_width_hlo(v5e):
+    """A window layer and the full layer at the published widths, the
+    cell's share (8 of 64 experts, V = 12 288) and the cell's B=4 x
+    T=8192, for one v5e chip.  A v5e reports 128 MiB of VMEM, a described
+    one nothing: the capacity is steered here."""
+    from paddle_tpu.ops import pallas
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+    mp = pytest.MonkeyPatch()
+    for mod in (pallas, fa):
+        mp.setattr(mod, "on_tpu", lambda: True)
+    mp.setattr(fa, "_vmem_capacity", lambda: 128 << 20)
+    try:
+        cfg = _mellum2(vocab_size=12288, num_experts_held=8,
+                       moe_rows_factor=2.0)
+        mesh = Mesh(np.asarray(v5e[:1]), ("dp",))
+        return _lower_lfm2(cfg, mesh, 4, 8192,
+                           remat_policy="ctx").compile().as_text()
+    finally:
+        mp.undo()
+
+
+def test_every_mellum2_mosaic_call_is_one_the_benchmark_finds(
+        mellum2_real_width_hlo):
+    """The window layer's resident pair carries the names the kernels'
+    ``name=`` gives a windowed call (``flash_window_fwd``, wrapped
+    ``jvp_..._`` by the forward's jvp, and ``flash_window_bwd``), the full
+    layer's the names every cell's flash calls carry (``jvp__``,
+    ``checkpoint``): both at (bf16[128, 8192, 128], f32[128, 8192, 1]),
+    each pair matched by its own metric's patterns and not by the other's,
+    nothing run again; with the grouped expert matmuls and the loss head
+    at V = 12 288 every Mosaic call is some file's.  The compile is also
+    the proof that the windowed pair fits VMEM at the cell's shapes."""
+    mosaic = _mosaic_calls(mellum2_real_width_hlo)
+    groups = {m: _patterns(m) for m in (
+        "swa_attn_roofline", "mellum_full_attn_roofline",
+        "mellum_moe_experts_roofline", "mellum2_loss_head_events")}
+    hits = {m: [c for c in mosaic if any(r.search(c) for r in rx)]
+            for m, rx in groups.items()}
+    window, full = hits["swa_attn_roofline"], hits["mellum_full_attn_roofline"]
+    assert sorted(c.split(".")[0] for c in window) == [
+        "%flash_window_bwd", "%jvp_flash_window_fwd_"], window
+    assert sorted(c.split(".")[0] for c in full) == ["%checkpoint", "%jvp__"]
+    assert all("bf16[128,8192,128]" in c for c in window + full)
+    assert not any(c.startswith("%rematted_computation")
+                   for c in window + full)
+    experts = hits["mellum_moe_experts_roofline"]
+    assert sum(not c.startswith("%ragged-dot-metadata")
+               for c in experts) == 24
+    assert len(hits["mellum2_loss_head_events"]) == 1
+    assert sum(map(len, hits.values())) == len(mosaic), \
+        [c[:100] for c in mosaic
+         if not any(c in h for h in hits.values())]
+    loop = groups["mellum2_loss_head_events"][1]
+    assert sum(bool(loop.search(i))
+               for i in _instructions(mellum2_real_width_hlo)) == 1
+
+
+@pytest.mark.parametrize("fixture", [
+    "real_width_step_hlo", "lfm2_real_width_hlo",
+    "qwen3_next_real_width_hlo", "joyai_real_width_hlo"],
+    ids=["gpt", "lfm2", "qwen3-next", "joyai"])
+def test_no_other_step_holds_a_windowed_flash_call(request, fixture):
+    """``flash_window_*`` is the Mellum2 step's alone: the other cells'
+    flash calls carry no window and keep the names their metrics find
+    them by (each cell's test above holds them)."""
+    hlo = request.getfixturevalue(fixture)
+    hlo = hlo[1] if isinstance(hlo, tuple) else hlo
+    assert "flash_window" not in hlo

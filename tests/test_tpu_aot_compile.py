@@ -305,11 +305,12 @@ def _custom_calls(text):
     return re.findall(r"%(\w+)\.\d+ = [^\n]*? custom-call\(", text)
 
 
-def _cell_step(v5e, monkeypatch, driver: str, config: str, workload: str):
+def _cell_step(v5e, monkeypatch, driver: str, config: str, workload: str,
+               **changes):
     """The whole step of a benchmark cell — the configuration file's
-    model at the cell's batch, bf16 over fp32 masters, the file's remat
-    policy — compiled for one v5e.  -> (the compiled step, its number of
-    parameters)."""
+    model (with ``changes`` to its keys) at the cell's batch, bf16 over
+    fp32 masters, the file's remat policy — compiled for one v5e.  -> (the
+    compiled step, its number of parameters)."""
     import importlib
     import json
     import os
@@ -320,7 +321,7 @@ def _cell_step(v5e, monkeypatch, driver: str, config: str, workload: str):
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark")
     with open(os.path.join(bench, "configs", f"{config}.json")) as f:
-        config = json.load(f)
+        config = dict(json.load(f), **changes)
     with open(os.path.join(bench, "workloads", f"{workload}.json")) as f:
         traffic = json.load(f)["traffic"]
     cfg = importlib.import_module(
@@ -433,3 +434,60 @@ def test_the_joyai_step_fits_the_chip_at_the_cell_s_size(v5e, monkeypatch):
                       "bf16[64,8192,192]") >= 6       # the fused backwards
     held = _held(compiled, n_params)
     assert 11e9 < held < 15.9e9, held
+
+
+# ---------------------------------------------------------------------------
+# the Mellum2 cell: three window layers and a full one, the whole step at
+# the cell's size against the chip's memory
+# ---------------------------------------------------------------------------
+MELLUM2 = ("mellum2_train", "mellum2-12b-a2.5b",
+           "mellum2-12b-a2.5b.train-t8192")
+# q (B, T, 32, 128) and the repeated K / V, before the fold; the 4 KV
+# heads; the folded (B x H, T, 128) operands of the kernels
+MELLUM2_QKV_SHAPES = ("bf16[4,8192,32,128]", "f32[4,8192,32,128]",
+                      "bf16[4,8192,4,128]", "f32[4,8192,4,128]",
+                      "bf16[128,8192,128]", "f32[128,8192,1]")
+
+
+def test_the_mellum2_step_fits_the_chip_at_the_cell_s_size(
+        v5e, monkeypatch, capsys):
+    """The whole step of ``mellum2-12b-a2.5b.train-t8192`` (340.3 M
+    parameters, B = 4 x T = 8192) compiled for one v5e: three windowed
+    flash pairs by their names (``flash_window_fwd`` under the forward's
+    jvp, ``flash_window_bwd``) and one full pair by the names every other
+    cell's carry (``jvp__``, ``checkpoint``); no flash call run again; and
+    what the step holds stays under the chip's ``bytes_limit`` of 16.91
+    GB (4.085 + 7.373 + 1.361 = 12.819 GB, PR 42)."""
+    compiled, n_params = _cell_step(v5e, monkeypatch, *MELLUM2)
+    assert n_params == 340349184
+    text = compiled.as_text()
+    calls = _custom_calls(text)
+    flash = sorted(c for c in calls
+                   if "flash_window" in c or c in ("jvp__", "checkpoint"))
+    # (the loss head's forward is a jvp__ call too)
+    assert flash == ["checkpoint"] + ["flash_window_bwd"] * 3 \
+        + ["jvp__"] * 2 + ["jvp_flash_window_fwd_"] * 3, flash
+    assert text.count("bf16[128,8192,128]{2,1,0:T(8,128)(2,1)}, "
+                      "f32[128,8192,1]") == 4           # the forwards
+    held = _held(compiled, n_params)
+    with capsys.disabled():
+        print(f"\nmellum2 step, chip-free: {held / 1e9:.3f} GB held")
+    assert 11e9 < held < 14e9, held
+
+
+def test_the_window_adds_no_relayout_around_the_kernels(v5e, monkeypatch):
+    """The cell's step against the same step with every layer full: the
+    same ``copy`` / ``reshape`` / ``transpose`` of every q-, k- and
+    v-sized array under the attention's scopes or under no name — the
+    windowed kernels take and give their operands as the full pair does,
+    and the window costs no hand-over of its own."""
+    windowed, _ = _cell_step(v5e, monkeypatch, *MELLUM2)
+    full, _ = _cell_step(v5e, monkeypatch, *MELLUM2,
+                         layer_types=["full_attention"] * 4)
+    assert not any("flash_window" in c
+                   for c in _custom_calls(full.as_text()))
+    for shape in MELLUM2_QKV_SHAPES:
+        found = [len(handover_copies(step.as_text(), shape,
+                                     ("mellum_qkv", "mellum_out")))
+                 for step in (windowed, full)]
+        assert found[0] == found[1], (shape, found)
