@@ -40,9 +40,10 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
+from ..ops.rope import rope_rotate_half
 from .sparse_blocks import (batch_axes_of, dense_ffn as _dense_ffn,
                             held_experts, leaf_name, moe_counters,
-                            rms_norm as _rms, rope_angles, rope_rotate_half)
+                            rms_norm as _rms, rope_angles)
 
 __all__ = ["Lfm2MoeConfig", "init_lfm2_moe_params",
            "lfm2_moe_param_shardings"]

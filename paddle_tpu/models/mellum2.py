@@ -26,9 +26,11 @@ position table.  Layer ``l``: ``h = x + Attn_l(RMS(x; op_norm))``, ``x'
 
 The model holds ``num_experts_held`` experts of each layer from
 ``first_expert`` on — one chip's share of a deployment; routing runs
-over all ``num_experts``.  A window layer's attention is the flash
-kernels' ``window=`` (``ops/pallas/flash_attention.py``: the resident
-pair over the band of key chunks alone).  ``build_spmd_train_step``
+over all ``num_experts``.  RoPE is ``ops/rope.py``'s ``rope_to_heads``:
+on a TPU a Pallas pair that takes q and k from the projections and hands
+them to the flash kernels head-major.  A window layer's attention is the
+flash kernels' ``window=`` (``ops/pallas/flash_attention.py``: the
+resident pair over the band of key chunks alone).  ``build_spmd_train_step``
 asks ``spmd_parts(mesh)`` for the model's own; cast, remat, loss head,
 AdamW and the jit are the builder's.  One dict of parameters a layer.
 """
@@ -44,9 +46,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
+from ..ops.rope import rope_to_heads
 from .sparse_blocks import (batch_axes_of, held_experts, leaf_name,
-                            moe_counters, rms_norm as _rms, rope_angles,
-                            rope_rotate_half)
+                            moe_counters, rms_norm as _rms, rope_angles)
 
 __all__ = ["Mellum2Config", "init_mellum2_params", "mellum2_param_shardings"]
 
@@ -141,15 +143,14 @@ def mellum2_param_shardings(mesh: Mesh, cfg: Mellum2Config) -> Dict:
         lambda: init_mellum2_params(cfg, jax.random.PRNGKey(0))))
 
 
-def _rope(x, cfg: Mellum2Config, window: Optional[int]):
-    """The layer kind's RoPE over the whole head; x: (B, T, H, hd)."""
-    T, hd = x.shape[1], x.shape[-1]
+def _rope(cfg: Mellum2Config, T: int, window: Optional[int]):
+    """The layer kind's RoPE over the whole head: (angles, the factor on
+    cos and sin)."""
+    hd = cfg.head_dim
     if window is not None:
-        return rope_rotate_half(x, rope_angles(T, cfg.rope_theta_sliding,
-                                               hd))
-    return rope_rotate_half(
-        x, rope_angles(T, cfg.rope_theta_full, hd, cfg.yarn),
-        cfg.yarn_attention_factor)
+        return rope_angles(T, cfg.rope_theta_sliding, hd), 1.0
+    return (rope_angles(T, cfg.rope_theta_full, hd, cfg.yarn),
+            cfg.yarn_attention_factor)
 
 
 def _attention(p, x, cfg, mesh, batch_axes, window):
@@ -158,16 +159,22 @@ def _attention(p, x, cfg, mesh, batch_axes, window):
     H, K, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     with jax.named_scope("mellum_qkv"):
         z = _rms(x, p["op_norm"], cfg.rms_norm_eps)
-        q = _rope((z @ p["q_w"]).reshape(B, T, H, hd), cfg, window)
-        k = _rope((z @ p["k_w"]).reshape(B, T, K, hd), cfg, window)
+        # q and k turned and handed over head-major, (B, heads, T, hd):
+        # the layout the flash kernels fold to
+        q, k = rope_to_heads(z @ p["q_w"], z @ p["k_w"],
+                             *_rope(cfg, T, window), mesh=mesh,
+                             batch_axes=batch_axes)
         v = (z @ p["v_w"]).reshape(B, T, K, hd)
         # the kernels take equal head counts: the KV heads are repeated
-        k = jnp.repeat(k, H // K, axis=2)
+        k = jnp.repeat(k, H // K, axis=1)
         v = jnp.repeat(v, H // K, axis=2)
     # outside every scope, like the other models' attention: a scope
     # around a pallas_call renames the Mosaic custom call (a window
-    # layer's calls carry names of their own, the kernels' ``name=``)
-    ctx = flash_attention(q, k, v, causal=True, window=window, mesh=mesh,
+    # layer's calls carry names of their own, the kernels' ``name=``).
+    # The entry takes (B, T, H, hd) and folds it to (B H, T, hd): the two
+    # swaps of q and k cancel
+    ctx = flash_attention(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), v,
+                          causal=True, window=window, mesh=mesh,
                           batch_axes=batch_axes)
     ctx = checkpoint_name(ctx.reshape(B, T, H * hd), "attn_ctx")
     with jax.named_scope("mellum_out"):

@@ -16,9 +16,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
 
-__all__ = ["rms_norm", "rope_angles", "rope_rotate_half", "swiglu",
-           "dense_ffn", "held_experts", "batch_axes_of", "moe_counters",
-           "leaf_name"]
+__all__ = ["rms_norm", "rope_angles", "swiglu", "dense_ffn", "held_experts",
+           "batch_axes_of", "moe_counters", "leaf_name"]
 
 
 def rms_norm(x, g, eps):
@@ -31,8 +30,8 @@ def rms_norm(x, g, eps):
 
 def rope_angles(T: int, theta: float, rotary: int, yarn=None):
     """(T, rotary / 2) float64 angles ``t * theta^(-2i / rotary)`` of
-    RoPE over ``rotary`` components; which components pair up
-    (rotate-half, interleaved) is the model's.
+    RoPE over ``rotary`` components; which components pair up is the
+    caller's (rotate-half: ``ops/rope.py``; interleaved: JoyAI's own).
 
     ``yarn``: (factor, original_max_position_embeddings, beta_fast,
     beta_slow) — YaRN's frequencies (HF ``rope_type: yarn``, truncated
@@ -57,21 +56,6 @@ def rope_angles(T: int, theta: float, rotary: int, yarn=None):
                        0, 1)
         inv = inv / factor * ramp + inv * (1 - ramp)
     return np.arange(T, dtype=np.float64)[:, None] * inv[None, :]
-
-
-def rope_rotate_half(x, ang, scale: float = 1.0):
-    """Rotate-half RoPE over the whole head, pairs ``(i, i + hd / 2)``
-    turned by ``ang`` (T, hd / 2), cos and sin times ``scale`` (YaRN's
-    attention factor); in float32.  x: (B, T, H, hd)."""
-    hd = x.shape[-1]
-    both = np.concatenate([ang, ang], -1)
-    cos = jnp.asarray(scale * np.cos(both), jnp.float32)
-    sin = jnp.asarray(scale * np.sin(both), jnp.float32)
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :hd // 2], xf[..., hd // 2:]
-    rot = jnp.concatenate([-x2, x1], axis=-1)
-    return (xf * cos[None, :, None, :]
-            + rot * sin[None, :, None, :]).astype(x.dtype)
 
 
 def swiglu(z, w1, w3, w2):

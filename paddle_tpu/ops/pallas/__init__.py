@@ -12,8 +12,10 @@ of key chunks alone), ``fused_ln``, ``softmax_xent`` (the
 fused loss head's forward, an optional weight a row), ``gated_delta_rule``
 (the linear-attention recurrence in chunks: the prep with its triangular
 inverse in VMEM, the loop with the state in VMEM, and their reverse
-passes) and ``causal_conv`` (the convolution, SiLU and q / k L2 norms in
-front of that rule, token-major: a forward writing q, k, v, a backward).
+passes), ``causal_conv`` (the convolution, SiLU and q / k L2 norms in
+front of that rule, token-major: a forward writing q, k, v, a backward)
+and ``rope`` (rotate-half RoPE of q and k, token-major in and head-major
+out for the flash kernels, and its transpose).
 
 Every place that chooses between a Mosaic kernel and XLA math asks this
 module, and records what it chose:

@@ -42,6 +42,7 @@ from paddle_tpu.models import Mellum2Config
 from paddle_tpu.models import mellum2 as model
 from paddle_tpu.models.gpt_spmd import build_spmd_train_step
 from paddle_tpu.models.sparse_blocks import rope_angles
+from paddle_tpu.ops.rope import rope_to_heads
 
 import paddle_tpu.ops.pallas  # noqa: F401  (the module, not the function)
 fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
@@ -307,21 +308,22 @@ def test_yarn_frequencies_and_factor_against_the_formula():
                               "full_attention")
     np.testing.assert_allclose(inv, want, rtol=1e-12)
     assert scale == pytest.approx(0.1 * math.log(16) + 1, rel=1e-15)
-    # the model's rotation: x (1, T, 1, hd) one-hot in component 0 turns
-    # into (cos, 0 .., sin ..) times the factor
+    # the model's rotation: x (1, T, hd), one head of q and of k one-hot
+    # in component 0, turns into (cos, 0 .., sin ..) times the factor
     cfg = Mellum2Config()
-    x = jnp.zeros((1, 8192, 1, hd)).at[..., 0].set(1.0)
+    x = jnp.zeros((1, 8192, hd)).at[..., 0].set(1.0)
     for window, inv_i, f in ((1024, plain, 1.0), (None, want, scale)):
-        y = np.asarray(model._rope(x, cfg, window))[0, :, 0]
+        ang, factor = model._rope(cfg, 8192, window)
+        assert factor == f
+        y = np.asarray(rope_to_heads(x, x, ang, factor)[0])[0, 0]
         t = np.arange(8192)
         np.testing.assert_allclose(y[:, 0], f * np.cos(t * inv_i[0]),
                                    atol=2e-6)
         np.testing.assert_allclose(y[:, hd // 2], f * np.sin(t * inv_i[0]),
                                    atol=2e-6)
         assert not np.any(y[:, 1:hd // 2]) and not np.any(y[:, hd // 2 + 1:])
-        z = np.asarray(model._rope(
-            jnp.zeros((1, 8192, 1, hd)).at[..., 40].set(1.0), cfg,
-            window))[0, :, 0]
+        x40 = jnp.zeros((1, 8192, hd)).at[..., 40].set(1.0)
+        z = np.asarray(rope_to_heads(x40, x40, ang, factor)[0])[0, 0]
         np.testing.assert_allclose(z[:, 40], f * np.cos(t * inv_i[40]),
                                    atol=2e-6)
 
