@@ -32,6 +32,8 @@ from ....core.tensor import Tensor
 from ....nn.layer_base import Layer
 from ....nn import initializer as I
 from .... import nn
+from ....ops import moe_rows, pallas
+from ....ops.pallas import moe_rows as _row_kernels
 
 __all__ = ["top1_gating", "moe_dispatch", "moe_combine", "moe_alltoall",
            "moe_alltoall_inverse", "MoELayer", "sigmoid_topk_routing",
@@ -160,7 +162,10 @@ class MoELayer(Layer):
 # Routed experts with no dropped assignment: top-k routing over the router's
 # whole width (the caller's: a ``(z, router_w, bias) -> (idx, w)`` function,
 # two of which live here), dispatch by sort and segment offsets, grouped
-# matmuls (``lax.ragged_dot``) over the experts held here.  Functional:
+# matmuls (``lax.ragged_dot``) over the experts held here; the rows move
+# into expert order and back by the Pallas pair of ``ops/moe_rows.py`` on
+# a TPU, by XLA's gathers elsewhere (and between ranks, ``_exchange``,
+# everywhere).  Functional:
 # the SPMD model blocks call it (models/lfm2_moe.py, models/qwen3_next.py);
 # ``MoELayer`` above stays the Switch top-1 capacity layer of the Layer API.
 # ---------------------------------------------------------------------------
@@ -251,9 +256,11 @@ def _routing_plan(idx, first: int, held: int, ranks: int, cap: int):
     ``overflow`` — with ``cap = N * min(k, held)`` that cannot happen.
 
     -> dict: ``slot`` (R,) the flat assignment ``n * k + j`` each row
-    serves, ``row_valid`` (R,), ``pos`` / ``valid`` (N, k) each
-    assignment's row, ``sizes`` (ranks, held) rows per expert,
-    ``counts`` (ranks, held) assignments per expert, ``overflow`` ()."""
+    serves, ``row_valid`` (R,), ``kept`` (ranks,) the live rows of each
+    rank (a prefix of its ``cap``), ``pos`` / ``valid`` / ``group`` (N, k)
+    each assignment's row and expert (``ranks * held`` where it is not
+    held here), ``sizes`` (ranks, held) rows per expert, ``counts``
+    (ranks, held) assignments per expert, ``overflow`` ()."""
     N, k = idx.shape
     G = held * ranks
     g = idx.reshape(-1) - first
@@ -275,9 +282,9 @@ def _routing_plan(idx, first: int, held: int, ranks: int, cap: int):
     within = sorted_at - start[rank_of]
     valid = (key < G) & (within < cap)
     pos = jnp.where(valid, rank_of * cap + within, 0)
-    return {"slot": slot, "row_valid": row_valid,
+    return {"slot": slot, "row_valid": row_valid, "kept": kept,
             "pos": pos.reshape(N, k), "valid": valid.reshape(N, k),
-            "sizes": sizes, "counts": counts,
+            "group": key.reshape(N, k), "sizes": sizes, "counts": counts,
             "overflow": jnp.sum(per_rank - kept)}
 
 
@@ -307,6 +314,18 @@ _take_rows.defvjp(
 _spread_rows.defvjp(
     lambda y, *plan: (_spread_rows(y, *plan), plan),
     lambda plan, g: (_take_rows(g, *plan), None, None, None, None))
+
+
+def _rows_plan(N: int, k: int, D: int, cap: int, groups: int, dtype):
+    """The Pallas pair's tiles (``ops/pallas/moe_rows.py`` ``plan``) where
+    the backend takes kernels and the shapes tile, else None: the XLA
+    ``_take_rows`` / ``_spread_rows`` above.  Counted ``moe_rows.mosaic``
+    / ``.interpret`` / ``.xla``."""
+    plan = _row_kernels.plan(N, k, D, cap, groups, dtype,
+                             interpret=not pallas.on_tpu()) \
+        if pallas.enabled() else None
+    pallas.note("moe_rows", plan is not None)
+    return plan
 
 
 def _grouped_ffn(xs, w1, w3, w2, sizes):
@@ -401,11 +420,19 @@ def routed_experts(x, router_w, bias, w1, w3, w2, *, top_k: int,
         cap = rows if rows is not None else N * min(k, held)
         with jax.named_scope("moe_route"):
             idx, w = routing(z, router_w, bias)
+        rows_plan = _rows_plan(N, k, D, cap, ep * held, z.dtype)
         with jax.named_scope("moe_dispatch"):
             plan = _routing_plan(idx, first_expert, held, ep, cap)
-            to_rows = (plan["slot"] // k, plan["row_valid"],
-                       plan["pos"], plan["valid"])
-            xs = _take_rows(z, *to_rows)
+            if rows_plan is None:
+                to_rows = (plan["slot"] // k, plan["row_valid"],
+                           plan["pos"], plan["valid"])
+                xs = _take_rows(z, *to_rows)
+            else:
+                tiles = moe_rows.runs(plan["pos"], plan["valid"],
+                                      plan["group"], ep * held, rows_plan)
+                xs = moe_rows.dispatch(z, tiles, plan["kept"], R=ep * cap,
+                                       cap=cap, groups=ep * held,
+                                       plan=rows_plan)
             sizes = plan["sizes"]
             if ep > 1:
                 xs, sizes, back = _exchange(xs, sizes, ep_axis, ep, cap)
@@ -413,12 +440,17 @@ def routed_experts(x, router_w, bias, w1, w3, w2, *, top_k: int,
         with jax.named_scope("moe_combine"):
             if ep > 1:
                 out = back(out)
-            # each row's weight, by the same pair of gathers over the
-            # flat (N k, 1) weights
-            w_row = _take_rows(
-                w.reshape(-1, 1), plan["slot"], plan["row_valid"],
-                plan["pos"].reshape(-1, 1), plan["valid"].reshape(-1, 1))
-            y = _spread_rows(out * w_row.astype(out.dtype), *to_rows)
+            if rows_plan is None:
+                # each row's weight, by the same pair of gathers over the
+                # flat (N k, 1) weights
+                w_row = _take_rows(
+                    w.reshape(-1, 1), plan["slot"], plan["row_valid"],
+                    plan["pos"].reshape(-1, 1),
+                    plan["valid"].reshape(-1, 1))
+                y = _spread_rows(out * w_row.astype(out.dtype), *to_rows)
+            else:
+                y = moe_rows.combine(out, w, tiles, plan["kept"], cap=cap,
+                                     groups=ep * held, plan=rows_plan)
         counts, overflow = plan["counts"], plan["overflow"]
         if axes:
             counts, overflow = lax.psum((counts, overflow), axes)

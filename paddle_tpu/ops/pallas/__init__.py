@@ -13,9 +13,11 @@ fused loss head's forward, an optional weight a row), ``gated_delta_rule``
 (the linear-attention recurrence in chunks: the prep with its triangular
 inverse in VMEM, the loop with the state in VMEM, and their reverse
 passes), ``causal_conv`` (the convolution, SiLU and q / k L2 norms in
-front of that rule, token-major: a forward writing q, k, v, a backward)
-and ``rope`` (rotate-half RoPE of q and k, token-major in and head-major
-out for the flash kernels, and its transpose).
+front of that rule, token-major: a forward writing q, k, v, a backward),
+``rope`` (rotate-half RoPE of q and k, token-major in and head-major
+out for the flash kernels, and its transpose) and ``moe_rows`` (the
+expert layer's rows into the routed-row buffer and back, a tile of
+tokens' runs of buffer rows at a time).
 
 Every place that chooses between a Mosaic kernel and XLA math asks this
 module, and records what it chose:

@@ -528,3 +528,55 @@ def test_the_window_adds_no_relayout_around_the_kernels(v5e, monkeypatch):
                                      ("mellum_qkv", "mellum_out")))
                  for step in (windowed, full)]
         assert found[0] == found[1], (shape, found)
+
+
+# ---------------------------------------------------------------------------
+# the expert layer's shuffle: the Pallas row-gather pair at each sparse
+# cell's shapes
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("N,k,E,held,D,cap", [
+    (32768, 4, 64, 8, 2048, 32768),          # LFM2
+    (32768, 10, 512, 16, 2048, 20480),       # Qwen3-Next
+    (16384, 8, 256, 8, 7168, 16384),         # JoyAI
+    (32768, 8, 64, 8, 2304, 131072)],        # Mellum2
+    ids=["lfm2", "qwen3-next", "joyai", "mellum2"])
+def test_the_shuffle_s_kernels_compile_at_each_sparse_cell_s_size(
+        v5e, N, k, E, held, D, cap):
+    """The dispatch and the combine of one expert layer, forward and
+    backward, bf16: five Mosaic calls — the take, the gather-sum, and in
+    the reverse pass the weighted take, the weight's dot products and the
+    dispatch's gather-sum — within the VMEM each asks for, and no XLA
+    gather of a row or of a weight."""
+    from jax import lax
+    from paddle_tpu.distributed.fleet.meta_parallel import moe
+    from paddle_tpu.ops import moe_rows, pallas
+    from paddle_tpu.ops.pallas import moe_rows as kernels
+    S = _on(v5e[0])
+    plan = kernels.plan(N, k, D, cap, held, jnp.bfloat16, interpret=False)
+    assert plan is not None
+
+    @jax.jit
+    def layer(logits, z, out, dy):
+        w, idx = lax.top_k(logits, k)
+        p = moe._routing_plan(idx.astype(jnp.int32), 0, held, 1, cap)
+        tiles = moe_rows.runs(p["pos"], p["valid"], p["group"], held, plan)
+        kw = dict(cap=cap, groups=held, plan=plan)
+
+        def shuffle(z, out, w):
+            xs = moe_rows.dispatch(z, tiles, p["kept"], R=cap, **kw)
+            y = moe_rows.combine(out, w, tiles, p["kept"], **kw)
+            return xs, y
+        res, vjp = jax.vjp(shuffle, z, out, w)
+        return res, vjp((out, dy))
+
+    low = layer.lower(S((N, E), jnp.float32), S((N, D), jnp.bfloat16),
+                      S((cap, D), jnp.bfloat16), S((N, D), jnp.bfloat16))
+    assert "moe_rows.interpret" not in pallas.selections()
+    assert _mosaic_calls(low) == 5
+    text = low.compile().as_text()
+    calls = sorted(re.sub(r"^(jvp|transpose)_+|_+$", "", c).replace(
+        "jvp_", "") for c in _custom_calls(text)
+        if "moe_" in c)
+    assert calls == ["moe_gather_dots", "moe_gather_rows", "moe_gather_rows",
+                     "moe_take_rows", "moe_take_rows"], calls
+    assert " gather(" not in text
