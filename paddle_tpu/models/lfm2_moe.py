@@ -36,7 +36,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
@@ -149,19 +148,16 @@ def _rope(x, theta):
     return rope_rotate_half(x, rope_angles(x.shape[1], theta, x.shape[-1]))
 
 
-def _short_conv(p, x, eps):
+def _short_conv(p, x, eps, mesh, batch_axes):
+    from ..ops.causal_conv import short_conv
     with jax.named_scope("short_conv"):
         z = _rms(x, p["op_norm"], eps)
-        # the three sections on a leading axis: each comes out of the
-        # matmul as a row-major (B, T, D) array
-        b, c, u = jnp.einsum("btd,dse->sbte", z, p["conv_in_w"])
-        s = b * u
-        taps, T = p["conv_w"].shape[0], s.shape[1]
-        padded = jnp.pad(s, ((0, 0), (taps - 1, 0), (0, 0)))
-        y = sum(p["conv_w"][j] * lax.slice_in_dim(
-            padded, taps - 1 - j, taps - 1 - j + T, axis=1)
-            for j in range(taps))
-        return x + (c * y) @ p["conv_out_w"]
+        # the three sections on a leading axis, (3, B, T, D) row-major:
+        # what the convolution's kernels read, and for the weight's
+        # gradient a product per section in the parameter's own order
+        bcu = jnp.einsum("btd,dse->sbte", z, p["conv_in_w"])
+        y = short_conv(bcu, p["conv_w"], mesh=mesh, batch_axes=batch_axes)
+        return x + y @ p["conv_out_w"]
 
 
 def _gqa(p, x, cfg, mesh, batch_axes):
@@ -205,8 +201,8 @@ def _spmd_parts(cfg: Lfm2MoeConfig, mesh: Mesh):
         conv = cfg.layer_types[l] == "conv"
 
         def fn(p, x):
-            x = _short_conv(p, x, cfg.norm_eps) if conv else \
-                _gqa(p, x, cfg, mesh, batch_axes or ())
+            x = _short_conv(p, x, cfg.norm_eps, mesh, batch_axes or ()) \
+                if conv else _gqa(p, x, cfg, mesh, batch_axes or ())
             if l < cfg.num_dense_layers:
                 return _dense_ffn(p, x, cfg.norm_eps), None
             x, counts, overflow = _expert_ffn(p, x, cfg, mesh, batch_axes)

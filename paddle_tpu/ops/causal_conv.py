@@ -1,4 +1,9 @@
-"""The convolution in front of a linear-attention rule: a short depth-wise
+"""Short depth-wise causal convolutions between projections: the one in
+front of a linear-attention rule (``gated_causal_conv``) and LFM2's gated
+one (``short_conv``, at the end of this module: ``c * conv(b * u)`` over
+the three sections of its in-projection).  Two entries, two kernel pairs.
+
+The convolution in front of a linear-attention rule: a short depth-wise
 causal convolution over the packed q / k / v projection, SiLU, and the L2
 norms of the q and k heads.
 
@@ -41,7 +46,7 @@ from . import pallas
 from .pallas import causal_conv as _kernels
 from .pallas.flash_attention import _axes_entry, _traced_once
 
-__all__ = ["gated_causal_conv"]
+__all__ = ["gated_causal_conv", "short_conv"]
 
 
 def _causal_conv(s, w):
@@ -132,3 +137,72 @@ def gated_causal_conv(qkv, conv_w, *, n_qk: int, head: int, mesh=None,
     return pallas.shard_kernel(
         lambda x, w: _conv(x, w, n_qk, head, plan), mesh,
         (spec, PartitionSpec()), (spec,) * 3)(qkv, conv_w)
+
+
+# ---------------------------------------------------------------------------
+# LFM2's gated short convolution
+# ---------------------------------------------------------------------------
+def _short_reference(bcu, conv_w):
+    """XLA ops throughout: a pad, ``taps`` shifted slices, products in
+    the operands' dtype."""
+    b, c, u = bcu
+    return c * _causal_conv(b * u, conv_w)
+
+
+@_traced_once(2)
+def _short_kernel(bcu, conv_w, plan):
+    return _kernels.short_conv_fwd(bcu, conv_w.astype(jnp.float32),
+                                   plan=plan)
+
+
+_short = jax.custom_vjp(_short_kernel, nondiff_argnums=(2,))
+
+
+def _short_fwd(bcu, conv_w, plan):
+    return _short_kernel(bcu, conv_w, plan), (bcu, conv_w)
+
+
+@_traced_once(0)
+def _short_bwd(plan, inputs, dy):
+    """The residuals are the inputs: the backward kernel runs the
+    convolution again."""
+    bcu, conv_w = inputs
+    dbcu, dw = _kernels.short_conv_bwd(bcu, conv_w.astype(jnp.float32), dy,
+                                       plan=plan)
+    return dbcu, jnp.sum(dw, axis=0).astype(conv_w.dtype)
+
+
+_short.defvjp(_short_fwd, _short_bwd)
+
+
+def short_conv(bcu, conv_w, *, mesh=None, batch_axes=()):
+    """LFM2's gated short convolution between its two projections.
+
+    bcu: (3, B, T, D), the in-projection's product, the sections b, c, u
+    on the leading axis; conv_w: (taps, D), tap j weighing the token j
+    back.
+    -> ``c * conv(b * u)`` (B, T, D) in bcu's dtype, ``conv`` depth-wise
+    and causal within a row.  Differentiable in both.
+
+    On a TPU one forward and one backward Pallas kernel
+    (``ops/pallas/causal_conv.py``: ``%short_conv_fwd``,
+    ``%short_conv_bwd``) under a ``custom_vjp`` whose residuals are the
+    two inputs, float32 inside; they read ``bcu`` and write the result and
+    ``d(bcu)`` row-major, as the projections write and read them.
+    ``short_plan`` refuses — and ``_short_reference`` runs — a D that is
+    not whole 128-lane blocks, a T that is not whole 16-row tiles, more
+    than 9 taps and dtypes other than bfloat16 and float32.  Under a mesh
+    of more than one device ``batch_axes`` names the axes that shard B:
+    the kernels run per shard."""
+    _, B, T, D = bcu.shape
+    plan = _kernels.short_plan(B, T, D, conv_w.shape[0], bcu.dtype,
+                               interpret=not pallas.on_tpu()) \
+        if pallas.enabled() else None
+    pallas.note("short_conv", plan is not None)
+    if plan is None:
+        return _short_reference(bcu, conv_w)
+    rows = _axes_entry(mesh, batch_axes, B)
+    return pallas.shard_kernel(
+        lambda x, w: _short(x, w, plan), mesh,
+        (PartitionSpec(None, rows), PartitionSpec()),
+        PartitionSpec(rows))(bcu, conv_w)

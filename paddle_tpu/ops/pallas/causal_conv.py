@@ -1,16 +1,21 @@
-"""The delta-rule mixer's convolution on a TPU: the depth-wise causal
-convolution, its SiLU and the q / k L2 norms as one forward and one
-backward Pallas kernel, in the row-major layout the projection writes and
-the rule's kernels read (``ops/causal_conv.py`` has the mathematics, the
-plain-XLA path and the ``custom_vjp``).
+"""Two short depth-wise causal convolutions on a TPU, each a forward and a
+backward Pallas kernel in the row-major layout the projections write and
+read (``ops/causal_conv.py`` has the mathematics, the plain-XLA paths and
+the ``custom_vjp``s): the delta-rule mixer's, with its SiLU and the q / k
+L2 norms — one input, three outputs —, and LFM2's gated one — three
+sections in, one product out (the last part of this module).  The two
+share the tap sum over a chunk under its carried rows (``_taps``), its
+transpose with the taps' gradient (``_taps_t``, ``_tap_grads``) and the
+record of a plan, nothing else: which pair runs is the entry the model
+calls.
 
-Both kernels run on one grid, (batch row, column block, token tile), over
-``qkv (B, T, C)`` as it comes — channels on lanes, tokens on sublanes, so
-a tap's shift is a sublane shift and never crosses lanes.  A column block
-is ``block_c`` lanes of one kind — q, k or v, by its index alone: the
-first ``n_qk`` columns are q, the next ``n_qk`` k, the rest v — and a
-token tile ``block_t`` rows; inside, a loop over the block's heads and
-over chunks of ``rows`` rows of one head, a chunk's whole chain in
+The mixer's kernels run on one grid, (batch row, column block, token
+tile), over ``qkv (B, T, C)`` as it comes — channels on lanes, tokens on
+sublanes, so a tap's shift is a sublane shift and never crosses lanes.  A
+column block is ``block_c`` lanes of one kind — q, k or v, by its index
+alone: the first ``n_qk`` columns are q, the next ``n_qk`` k, the rest v —
+and a token tile ``block_t`` rows; inside, a loop over the block's heads
+and over chunks of ``rows`` rows of one head, a chunk's whole chain in
 float32:
 
     c_t = sum_j w_j s_{t-j},  s_{<0} = 0      the taps, j < taps
@@ -49,6 +54,24 @@ tile is one loop over its heads and, inside, over the chunks: a kernel
 body holds the chain three times, once a kind, whatever the block's
 width (a launch traces it).  What the layouts buy on the chip, and the ns
 a vreg of both kernels: PERF.md section 6, PR 40.
+
+LFM2's pair reads ``bcu (3, B, T, D)``, the in-projection's product as it
+lies — the sections b, c, u on the leading axis, each row-major — and
+computes, a chunk in float32,
+
+    s = b u,   y_t = sum_j w_j s_{t-j},   out = c y
+
+Both run on one grid, (batch row, channel block of 512 lanes, token
+tile); a block of ``bcu`` is the three sections' tiles at once (one
+strided copy), and the 16 rows before it come through a second block.
+
+- ``_short_conv_fwd``: inside the tile the loop carries ``s``; ``out (B,
+  T, D)`` row-major, as the out-projection reads it.
+- ``_short_conv_bwd``: under ``g = d out`` it runs the convolution again
+  and takes ``dc = g y``, ``e = g c``, ``ds = conv^T(e)``, ``db = ds u``,
+  ``du = ds b`` and ``dw_j = sum_t e_t s_{t-j}``; tiles and chunks in
+  reverse as the mixer's, e's first rows carried.  ``d(bcu)`` leaves as
+  ``bcu`` came, the three sections' tile a block.
 """
 from __future__ import annotations
 
@@ -63,13 +86,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .gated_delta_rule import _divisor
 
-__all__ = ["plan", "conv_fwd", "conv_bwd", "EPS"]
+__all__ = ["plan", "conv_fwd", "conv_bwd", "EPS", "short_plan",
+           "short_conv_fwd", "short_conv_bwd"]
 
 EPS = 1e-6                      # inside the L2 norm's rsqrt
 # rows of the halo block: a sublane tile of a 16-bit type, which an
 # 8-row carry of float32 fits
 _HALO = 16
 _CARRY = 8
+_LANES = 128
 # the most a grid step takes: tokens (of a 16-bit type), lanes; the most
 # rows a chunk of the inner loop.  Measured on the chip at (4, 8192, 8192):
 # PERF.md section 6, PR 40
@@ -80,7 +105,8 @@ _ROWS = 256
 
 class Plan(NamedTuple):
     block_t: int                # tokens a tile
-    block_c: int                # lanes a column block, whole heads of one kind
+    block_c: int                # lanes a column block: whole heads of one
+                                # kind (the mixer's), whole lanes (LFM2's)
     rows: int                   # rows a chunk of the loop inside a tile
     interpret: bool
 
@@ -131,6 +157,27 @@ def _taps(ext, w):
     for s, wj in zip(shifted[1:], w[1:]):
         c = c + s * wj
     return shifted, c
+
+
+def _taps_t(dc, after, w):
+    """The taps transposed: ``ds_t = sum_j w_j dc_{t+j}``.  dc: (rows,
+    head) float32, a chunk; after: dc of the 8 rows after it (nought past
+    a row's end) -> ds, the chunk over the rows after it shifted up."""
+    rows = dc.shape[0]
+    ext = jnp.concatenate([dc, after], 0)
+    ds = dc * w[0]
+    for j in range(1, len(w)):
+        ds = ds + pltpu.roll(ext, rows + _CARRY - j, 0)[:rows] * w[j]
+    return ds
+
+
+def _tap_grads(dw, dc, shifted):
+    """``dw_j += sum_t dc_t s_{t-j}`` over a chunk, eight partial sums a
+    lane (vreg adds; the caller sums the sublanes once a tile).  shifted:
+    :func:`_taps`' first result."""
+    rows, head = dc.shape
+    return tuple(acc + jnp.sum((dc * s).reshape(rows // 8, 8, head), 0)
+                 for acc, s in zip(dw, shifted))
 
 
 def _kind(c, nq: int):
@@ -215,19 +262,9 @@ def _gdn_conv_bwd(x_ref, halo_ref, w_ref, dq_ref, dk_ref, dv_ref, dx_ref,
                     da = (da - a * (_lane_sum(da * a) * (r * r))) \
                         * (r * scale)
                 dc = da * (sig + a * (1.0 - sig))
-                # the taps transposed: dc_{t+j}, the chunk over the rows
-                # after it shifted up
-                ext = jnp.concatenate([dc, after], 0)
-                ds = dc * w[0]
-                for j in range(1, taps):
-                    ds = ds + pltpu.roll(ext, rows + _CARRY - j,
-                                         0)[:rows] * w[j]
-                dx_ref[at, lanes] = ds.astype(dx_ref.dtype)
-                # dw_j, eight partial sums a lane: vreg adds, the sublanes
-                # summed once a tile
-                dw = tuple(
-                    acc + jnp.sum((dc * s).reshape(rows // 8, 8, head), 0)
-                    for acc, s in zip(dw, shifted))
+                dx_ref[at, lanes] = _taps_t(dc, after, w).astype(
+                    dx_ref.dtype)
+                dw = _tap_grads(dw, dc, shifted)
                 return dc[:_CARRY], dw
 
             zero = jnp.zeros((_CARRY, head), jnp.float32)
@@ -325,3 +362,169 @@ def conv_bwd(qkv, conv_w, dq, dk, dv, *, n_qk: int, head: int, plan: Plan):
         [jax.ShapeDtypeStruct((B, T, C), qkv.dtype),
          jax.ShapeDtypeStruct((B, taps, C), jnp.float32)],
         scratch=[pltpu.VMEM((_CARRY, plan.block_c), jnp.float32)])
+
+
+# ---------------------------------------------------------------------------
+# LFM2's gated short convolution: three sections in, one product out
+# ---------------------------------------------------------------------------
+def short_plan(B: int, T: int, D: int, taps: int, dtype, *,
+               interpret: bool) -> Optional[Plan]:
+    """The blocks of :func:`short_conv_fwd` / :func:`short_conv_bwd` for
+    ``bcu (3, B, T, D)``, or None where the shapes do not tile: D whole
+    lanes, T whole 16-row tiles (the halo block), the taps' history within
+    the 8-row carry, bfloat16 or float32."""
+    if D % _LANES or T % _HALO or not 1 <= taps <= _CARRY + 1 \
+            or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
+        return None
+    # the backward holds the three sections' tile, the cotangent's and
+    # the three gradients' twice (the pipeline's buffers): 14 MB at 1024 x
+    # 512 bfloat16 (512 tokens in float32) of the 16 MiB a kernel may use
+    most = _BLOCK_T * 2 // jnp.dtype(dtype).itemsize
+    block_t = _HALO * _divisor(T // _HALO, most // _HALO)
+    block_c = _LANES * _divisor(D // _LANES, _BLOCK_C // _LANES)
+    rows = _HALO * _divisor(block_t // _HALO, _ROWS // _HALO)
+    return Plan(block_t, block_c, rows, interpret)
+
+
+def _gate(x_ref, at, lanes):
+    """``s = b u`` of the rows ``at``, float32; x_ref: the sections'
+    (3, rows, lanes) block."""
+    return x_ref[0, at, lanes].astype(jnp.float32) \
+        * x_ref[2, at, lanes].astype(jnp.float32)
+
+
+def _short_conv_fwd(x_ref, halo_ref, w_ref, y_ref, *, rows: int):
+    block_t, block_c = y_ref.shape
+    taps = w_ref.shape[0]
+    first = pl.program_id(2) == 0
+    halo = pl.ds(_HALO - _CARRY, _CARRY)
+
+    def group(g, _):
+        lanes = pl.ds(pl.multiple_of(g * _LANES, _LANES), _LANES)
+        w = [w_ref[j:j + 1, lanes] for j in range(taps)]
+        before = _gate(halo_ref, halo, lanes)
+
+        def chunk(i, prev):
+            at = pl.ds(pl.multiple_of(i * rows, rows), rows)
+            s = _gate(x_ref, at, lanes)
+            _, y = _taps(jnp.concatenate([prev, s], 0), w)
+            y_ref[at, lanes] = (x_ref[1, at, lanes].astype(jnp.float32)
+                                * y).astype(y_ref.dtype)
+            return s[rows - _CARRY:]
+
+        lax.fori_loop(0, block_t // rows, chunk,
+                      jnp.where(first, 0.0, before))
+        return 0
+
+    lax.fori_loop(0, block_c // _LANES, group, 0)
+
+
+def _short_conv_bwd(x_ref, halo_ref, w_ref, g_ref, d_ref, dw_ref,
+                    after_scr, *, rows: int):
+    block_t, block_c = g_ref.shape
+    taps = w_ref.shape[0]
+    n = block_t // rows
+    t = pl.program_id(2)                # tiles in reverse: the row's last
+    first = t == pl.num_programs(2) - 1     # the row's first tokens
+    halo = pl.ds(_HALO - _CARRY, _CARRY)
+
+    @pl.when(t == 0)
+    def _start():
+        after_scr[...] = jnp.zeros_like(after_scr)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    def group(gi, _):
+        lanes = pl.ds(pl.multiple_of(gi * _LANES, _LANES), _LANES)
+        w = [w_ref[j:j + 1, lanes] for j in range(taps)]
+        before = jnp.where(first, 0.0, _gate(halo_ref, halo, lanes))
+
+        def chunk(i, carry):
+            after, dw = carry           # e's first rows of the chunk after
+            i = n - 1 - i
+            start = pl.multiple_of(i * rows, rows)
+            at = pl.ds(start, rows)
+            b = x_ref[0, at, lanes].astype(jnp.float32)
+            u = x_ref[2, at, lanes].astype(jnp.float32)
+            # the rows before the chunk, from its own tile (a 16-row read:
+            # a whole sublane tile of a 16-bit type)
+            prev = _gate(x_ref, pl.ds(pl.multiple_of(
+                jnp.maximum(start - _HALO, 0), _HALO), _HALO),
+                lanes)[_HALO - _CARRY:]
+            prev = jnp.where(i == 0, before, prev)
+            shifted, y = _taps(jnp.concatenate([prev, b * u], 0), w)
+            g = g_ref[at, lanes].astype(jnp.float32)
+            e = g * x_ref[1, at, lanes].astype(jnp.float32)    # dL/dy
+            ds = _taps_t(e, after, w)
+            d_ref[0, at, lanes] = (ds * u).astype(d_ref.dtype)
+            d_ref[1, at, lanes] = (g * y).astype(d_ref.dtype)
+            d_ref[2, at, lanes] = (ds * b).astype(d_ref.dtype)
+            dw = _tap_grads(dw, e, shifted)
+            return e[:_CARRY], dw
+
+        zero = jnp.zeros((_CARRY, _LANES), jnp.float32)
+        after, dw = lax.fori_loop(0, n, chunk,
+                                  (after_scr[:, lanes], (zero,) * taps))
+        after_scr[:, lanes] = after
+        for j in range(taps):
+            dw_ref[j:j + 1, lanes] += jnp.sum(dw[j], axis=0, keepdims=True)
+        return 0
+
+    lax.fori_loop(0, block_c // _LANES, group, 0)
+
+
+def _short_specs(plan: Plan, dims, taps: int, reverse: bool):
+    """The ``BlockSpec``s over the grid (b, channel block, token tile),
+    the tiles in reverse order where ``reverse``: the three sections' tile
+    of ``bcu`` (one block, a strided copy), the 16 rows before it (the
+    halo; the tile's own first rows, unused, at a row's start), the taps,
+    and a ``(B, T, D)`` array's tile."""
+    _, _, T, _ = dims
+    bt, bc = plan.block_t, plan.block_c
+    nT, per = T // bt, bt // _HALO
+    tok = (lambda t: nT - 1 - t) if reverse else (lambda t: t)
+    return ([pl.BlockSpec((3, None, bt, bc),
+                          lambda b, c, t: (0, b, tok(t), c)),
+             pl.BlockSpec((3, None, _HALO, bc), lambda b, c, t: (
+                 0, b, jnp.maximum(tok(t) * per - 1, 0), c)),
+             pl.BlockSpec((taps, bc), lambda b, c, t: (0, c))],
+            pl.BlockSpec((None, bt, bc), lambda b, c, t: (b, tok(t), c)))
+
+
+def short_conv_fwd(bcu, conv_w, *, plan: Plan):
+    """bcu: (3, B, T, D), the sections b, c, u; conv_w: (taps, D) float32.
+    -> ``c * conv(b * u)`` (B, T, D) in bcu's dtype."""
+    _, B, T, D = bcu.shape
+    ins, out = _short_specs(plan, bcu.shape, conv_w.shape[0], reverse=False)
+    return pl.pallas_call(
+        functools.partial(_short_conv_fwd, rows=plan.rows),
+        name="short_conv_fwd",
+        grid=(B, D // plan.block_c, T // plan.block_t),
+        in_specs=ins, out_specs=out,
+        out_shape=jax.ShapeDtypeStruct((B, T, D), bcu.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=plan.interpret,
+    )(bcu, bcu, conv_w)
+
+
+def short_conv_bwd(bcu, conv_w, dy, *, plan: Plan):
+    """The cotangents of :func:`short_conv_fwd`'s inputs under dy (B, T,
+    D): ``d(bcu)`` (3, B, T, D) in bcu's dtype — db, dc, du — and dconv_w
+    a batch row, (B, taps, D) float32 (the caller sums them)."""
+    _, B, T, D = bcu.shape
+    taps, bt, bc = conv_w.shape[0], plan.block_t, plan.block_c
+    (x, halo, w), g = _short_specs(plan, bcu.shape, taps, reverse=True)
+    return pl.pallas_call(
+        functools.partial(_short_conv_bwd, rows=plan.rows),
+        name="short_conv_bwd",
+        grid=(B, D // bc, T // bt),
+        in_specs=[x, halo, w, g],
+        out_specs=[x, pl.BlockSpec((None, taps, bc),
+                                   lambda b, c, t: (b, 0, c))],
+        out_shape=[jax.ShapeDtypeStruct(bcu.shape, bcu.dtype),
+                   jax.ShapeDtypeStruct((B, taps, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((_CARRY, bc), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=plan.interpret,
+    )(bcu, bcu, conv_w, dy)

@@ -26,7 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 import chip_smoke
-from hlo_text import HANDOVER_SHAPES, handover_copies
+from hlo_text import HANDOVER_SHAPES, handover_copies, unfused_instructions
 
 pytestmark = pytest.mark.slow
 
@@ -296,6 +296,67 @@ def test_the_mixer_s_convolution_at_the_qwen3_next_cell_s_size_compiles(
     # (outside a scope the compiler wraps the names: ``jvp_gdn_conv_fwd_``)
     assert sum("gdn_conv_fwd" in c for c in calls) == 1, calls
     assert sum("gdn_conv_bwd" in c for c in calls) == 1, calls
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_lfm2_s_short_convolution_at_the_cell_s_size_compiles(v5e, dtype):
+    """bcu (3, 4, 8192, 2048), three taps (bfloat16 is the cell's;
+    float32 takes half the tile): the forward kernel (three sections of
+    one array, two halo blocks) and the backward kernel (the carried
+    ``g c``, ``d(bcu)`` one array a section a grid step, the taps'
+    gradient resident over the token tiles) — two Mosaic calls, each
+    within the scoped VMEM."""
+    from paddle_tpu.ops import pallas
+    from paddle_tpu.ops.causal_conv import short_conv
+    S = _on(v5e[0])
+
+    @jax.jit
+    def fwd_bwd(bcu, w, dy):
+        out, vjp = jax.vjp(short_conv, bcu, w)
+        return out, vjp(dy)
+
+    before = pallas.selections().get("short_conv.mosaic", 0)
+    low = fwd_bwd.lower(S((3, 4, 8192, 2048), dtype), S((3, 2048), dtype),
+                        S((4, 8192, 2048), dtype))
+    assert pallas.selections()["short_conv.mosaic"] == before + 1
+    assert "short_conv.interpret" not in pallas.selections()
+    assert _mosaic_calls(low) == 2
+    calls = _custom_calls(low.compile().as_text())
+    assert sum("short_conv_fwd" in c for c in calls) == 1, calls
+    assert sum("short_conv_bwd" in c for c in calls) == 1, calls
+
+
+def test_the_lfm2_step_fits_the_chip_at_the_cell_s_size(
+        v5e, monkeypatch, capsys):
+    """The whole step of ``lfm2-24b-a2b.train-t8192`` (486.1 M
+    parameters, B = 4 x T = 8192) compiled for one v5e: the short
+    convolution's pair in each of the four conv layers (``short_conv_fwd``
+    in the forward and the recompute, ``short_conv_bwd``); under
+    ``short_conv`` no operand laid out with T on the lanes, no copy,
+    reshape or transpose of an operand or of the product; and what the
+    step holds stays under the chip's ``bytes_limit`` of 16.91 GB (12.200
+    GB with XLA's convolution, PR 44's step compiled the same way)."""
+    compiled, n_params = _cell_step(
+        v5e, monkeypatch, "lfm2_train", "lfm2-24b-a2b",
+        "lfm2-24b-a2b.train-t8192")
+    assert n_params == 486062464
+    text = compiled.as_text()
+    assert sorted(c for c in _custom_calls(text)
+                  if c.startswith("short_conv_")) \
+        == ["short_conv_bwd"] * 4 + ["short_conv_fwd"] * 8
+    moved = [(n, r) for n, r, _o, op in unfused_instructions(text)
+             if "short_conv" in op
+             and ("bf16[4,8192,2048]{1,2,0" in r
+                  or "bf16[3,4,8192,2048]{2,3,1,0" in r)]
+    assert not moved, moved
+    for shape in ("bf16[4,8192,2048]", "bf16[3,4,8192,2048]"):
+        assert handover_copies(text, shape, ("short_conv",)) == [], shape
+    held = _held(compiled, n_params)
+    with capsys.disabled():
+        print(f"\nlfm2 step, chip-free: {held / 1e9:.3f} GB held "
+              f"(the parent's, with XLA's convolution: 12.200 GB)")
+    assert 11e9 < held < 13.5e9, held
 
 
 def _custom_calls(text):
