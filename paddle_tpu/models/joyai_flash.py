@@ -20,8 +20,10 @@ MLA(N(x; op_norm))``, ``x' = h + FFN_l(N(h; ffn_norm))``.
   pair-swapped columns, ``_pair_swap``: the rotation shuffles a small
   weight, never the activations' lanes).  Causal ``softmax(q
   k^T / sqrt(qk_nope + qk_rope)) v``, then ``W_o``.  In training nothing
-  is absorbed and no latent is cached: the flash kernels see q and k of
-  192 and v of 128 (``ops/pallas/flash_attention.py``, the resident pair).
+  is absorbed and no latent is cached: every product is token-major,
+  (B, T, heads x width), and the flash entry's kernels read a head's
+  q_n, q_r, k_n and v and the one k_r where the products wrote them
+  (``ops/pallas/flash_attention.py``, ``flash_attention_latent``).
 - ``FFN_l``: dense SwiGLU of width ``intermediate_size`` in the first
   ``first_k_dense_replace`` layers; after them ``num_experts_per_tok`` of
   ``n_routed_experts`` routed SwiGLU experts (``topk_method: noaux_tc``
@@ -200,39 +202,45 @@ def _rotate(y, y_swapped, theta: float):
 
 
 def _mla(p, x, cfg, mesh, batch_axes):
-    from ..ops.pallas.flash_attention import flash_attention
+    from ..ops.pallas.flash_attention import flash_attention_latent
     B, T, _ = x.shape
     H, eps, theta = cfg.num_attention_heads, cfg.rms_norm_eps, cfg.rope_theta
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     r = cfg.kv_lora_rank
-    heads = "btc,chd->bthd"
-    # several products from one weight, here and below: each result is an
-    # array of its own (a slice of one result would be copied out), and
-    # the rotated parts come with their pair-swapped twins
+
+    def cols(w, lo, hi):
+        """Columns [lo, hi) of every head of a (in, H * width) weight,
+        as one (in, H * (hi - lo)) weight."""
+        return w.reshape(w.shape[0], H, -1)[..., lo:hi].reshape(
+            w.shape[0], -1)
+
+    def heads(y):
+        return y.reshape(B, T, H, -1)
+
+    # every product is token-major, (B, T, heads x width), the layout the
+    # flash entry's DMAs read a head's columns from; several products from
+    # one weight, here and below: each result is an array of its own (a
+    # slice of one result would be copied out), and the rotated parts come
+    # with their pair-swapped twins
     with jax.named_scope("mla_q"):
         z = rms_norm(x, p["op_norm"], eps)
         c_q = rms_norm(z @ p["q_a_w"], p["q_a_norm"], eps)
-        w = p["q_b_w"].reshape(-1, H, dn + dr)
-        q_r = _rotate(jnp.einsum(heads, c_q, w[..., dn:]),
-                      jnp.einsum(heads, c_q, _pair_swap(w[..., dn:])), theta)
-        q = jnp.concatenate(
-            [jnp.einsum(heads, c_q, w[..., :dn]), q_r], axis=-1)
+        q_n = c_q @ cols(p["q_b_w"], 0, dn)
+        w = cols(p["q_b_w"], dn, dn + dr)
+        q_r = _rotate(heads(c_q @ w), heads(c_q @ _pair_swap(w)),
+                      theta).reshape(B, T, H * dr)
     with jax.named_scope("mla_kv"):
         c_kv = rms_norm(z @ p["kv_a_w"][:, :r], p["kv_a_norm"], eps)
         w = p["kv_a_w"][:, r:]
-        k_r = _rotate((z @ w)[:, :, None, :],
-                      (z @ _pair_swap(w))[:, :, None, :], theta)
-        w = p["kv_b_w"].reshape(r, H, dn + dv)
-        v = jnp.einsum(heads, c_kv, w[..., dn:])
         # the one rotated key part serves every head
-        k = jnp.concatenate(
-            [jnp.einsum(heads, c_kv, w[..., :dn]),
-             jnp.broadcast_to(k_r, (B, T, H, dr))], axis=-1)
+        k_r = _rotate((z @ w)[:, :, None, :],
+                      (z @ _pair_swap(w))[:, :, None, :], theta)[:, :, 0]
+        k_n = c_kv @ cols(p["kv_b_w"], 0, dn)
+        v = c_kv @ cols(p["kv_b_w"], dn, dn + dv)
     # outside every scope of its own, like the other models' attention: a
     # scope around a pallas_call renames the Mosaic custom call
-    ctx = flash_attention(q, k, v, causal=True, mesh=mesh,
-                          batch_axes=batch_axes)
-    ctx = checkpoint_name(ctx.reshape(B, T, H * dv), "attn_ctx")
+    ctx = flash_attention_latent(q_n, q_r, k_n, k_r, v, causal=True,
+                                 mesh=mesh, batch_axes=batch_axes)
     with jax.named_scope("mla_out"):
         return x + ctx @ p["o_w"]
 

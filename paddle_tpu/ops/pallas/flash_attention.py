@@ -28,8 +28,11 @@ folded, T, Tk <= 4096     mid              _small_fwd_kernel /       none   XLA 
                                            _tiled_bwd_kernel                resident pair
 folded, longer, resident  stream and       _resident_fwd_kernel /    LFM2   kernels   kernels,
 budget fits the chip      stream_resident  _resident_bwd_kernel      Q3N              the band
-                                                                     JoyAI            alone:
-                                                                     Mellum2          _window
+                                                                     Mellum2          alone:
+                                                                                      _window
+split keys, as the        stream and       _latent_fwd_kernel /      JoyAI  (dv)      (none)
+resident pair above       stream_resident  _latent_bwd_kernel
+                          _latent
 folded, longer, it does   stream           _fwd_kernel_pipelined /   none   XLA math  XLA math
 not fit                                    _bwd_dq_kernel,
                                            _bwd_dkv_kernel
@@ -38,16 +41,31 @@ a length no multiple of   (XLA math, both directions; counted as     none       
 a TPU without FORCE
 ========================  ===============  ========================  =====  ========  ==========
 
-stacked is (3, B, T, H*d), folded (B*H, T, d).  GPT is the benchmark cell
+stacked is (3, B, T, H*d), folded (B*H, T, d), split keys the token-major
+operands of ``flash_attention_latent`` (below).  GPT is the benchmark cell
 ``gpt2-medium.train-t1024``, LFM2 is ``lfm2-24b-a2b.train-t8192`` (d =
 64), Q3N ``qwen3-next-80b-a3b.train-t8192`` (d = 256), JoyAI
 ``joyai-llm-flash.train-t8192``.  ``d`` is the head size of q and k,
 ``d_v`` that of v and the output: ``flash_attention`` takes a ``d_v`` of
-its own (latent attention: 192 over 128, the last column).  The resident
-pair carries it — V rows, the output, dO, the output accumulator and the
+its own (192 over 128, the last column: latent attention's concatenated
+heads; the split-key kernels budget by that plan).  The resident pair
+carries it — V rows, the output, dO, the output accumulator and the
 dV accumulator at ``d_v``, q, K, dq and dK at ``d``, the VMEM budget from
 both —, every other regime hands such a call to the XLA math, counted
 ``flash_attention.xla``; no cell depends on that.
+
+Latent attention (JoyAI) calls ``flash_attention_latent`` instead: a
+query head is [q_n | q_r], a key head [k_n | k_r] with one k_r shared by
+every head, and the operands come token-major, (B, T, H*d) as their
+projections write them — q_n, k_n and v a 128-lane column block a head,
+q_r two heads of 64 a block, k_r once a row.  Where ``_plan`` takes the
+resident pair for the concatenated heads and the sizes are those
+(``_latent_tiles``), the split-key kernels run the pair's tile math on s
+= q_n k_nᵀ + q_r k_rᵀ, each grid step's DMA fetching one head's columns
+(no fold, no concat, no repeat of k_r in HBM), and hand back what the
+pair hands back: out and lse, dq and dk [own | rotated] wide, dv,
+head-major.  Elsewhere the concatenated heads run under the same plan,
+as ``flash_attention`` runs them.
 
 ``window`` (a sliding window, causal only: query i sees key j where ``0
 <= i + Tk - T - j < window``) is an argument of the call.  A windowed
@@ -109,9 +127,11 @@ heads fill 128-lane column blocks.  The regimes:
 
 Under every kernel lies one copy of the tile math: ``_causal_mask``,
 ``_row_fwd`` / ``_row_bwd`` (a score row, whole or to its extent) and
-``_online_softmax_step`` / ``_saved_lse_bwd_tile`` (one key chunk).  A
-kernel body holds only what is its own: how it slices its refs and where
-it accumulates.
+``_online_softmax_step`` / ``_saved_lse_bwd_tile`` (one key chunk; the
+split-key kernels form their scores themselves and call the scores-in
+forms ``_online_softmax_scores`` / ``_saved_lse_ds``).  A kernel body
+holds only what is its own: how it slices its refs and where it
+accumulates.
 
 On a TPU the kernels are always compiled; ``PADDLE_PALLAS_FORCE=1`` takes
 them in interpret mode off-TPU (the kernel unit tests).  Under a mesh of
@@ -137,7 +157,8 @@ from jax.sharding import PartitionSpec
 
 from . import enabled, note, on_tpu, shard_kernel
 
-__all__ = ["flash_attention", "flash_attention_stacked"]
+__all__ = ["flash_attention", "flash_attention_latent",
+           "flash_attention_stacked"]
 
 NEG_INF = -1e30
 
@@ -496,7 +517,13 @@ def _online_softmax_step(q, k, v, mask, scale: float, m_scr, l_scr,
     Chunk 0 is live for every row (column 0 is), so m is finite from the
     first step on and a row wholly masked in a later chunk adds
     exp(NEG_INF - m) = 0."""
-    s = _dot(q, k, _NT) * scale                          # (bq, chunk)
+    _online_softmax_scores(_dot(q, k, _NT) * scale, v, mask, m_scr, l_scr,
+                           acc_scr)
+
+
+def _online_softmax_scores(s, v, mask, m_scr, l_scr, acc_scr):
+    """:func:`_online_softmax_step` from a tile's scaled scores ``s``
+    (bq, chunk), however they were formed."""
     if mask is not None:
         s = jnp.where(mask(s.shape), s, NEG_INF)
     m = m_scr[...]
@@ -526,22 +553,29 @@ def _saved_lse_bwd_tile(q, k, v, do, lse, delta, mask, scale: float, *,
     sums; the grid-streamed kernels scale every tile's product
     (``scale_each``), as they always have, so that their sums round as
     they did."""
-    s = _dot(q, k, _NT) * scale                          # (bq, chunk)
-    p = jnp.exp(s - lse)
-    if mask is not None:
-        p = jnp.where(mask(s.shape), p, 0.0)
-    dp = _dot(do, v, _NT)                                # (bq, chunk)
+    ds = _saved_lse_ds(_dot(q, k, _NT) * scale, v, do, lse, delta, mask,
+                       dv).astype(q.dtype)
     fold = (lambda x: scale * x) if scale_each else (lambda x: x)
-    if dv is not None:      # dV += P^T dO
-        ref, at = dv
-        ref[at] += _dot(p.astype(do.dtype), do, _TN)     # (chunk, d)
-    ds = (p * (dp - delta)).astype(q.dtype)
     if dq is not None:
         ref, at = dq
         ref[at] += fold(_dot(ds, k, _NN))
     if dk is not None:      # s = scale q k^T  =>  dK += scale dS^T q
         ref, at = dk
         ref[at] += fold(_dot(ds, q, _TN))                # (chunk, d)
+
+
+def _saved_lse_ds(s, v, do, lse, delta, mask, dv=None):
+    """:func:`_saved_lse_bwd_tile` from a tile's scaled scores ``s``, up
+    to dS in float32 (dV added where ``dv`` gives it a place); the
+    products with q and k are the caller's."""
+    p = jnp.exp(s - lse)
+    if mask is not None:
+        p = jnp.where(mask(s.shape), p, 0.0)
+    dp = _dot(do, v, _NT)                                # (bq, chunk)
+    if dv is not None:      # dV += P^T dO
+        ref, at = dv
+        ref[at] += _dot(p.astype(do.dtype), do, _TN)     # (chunk, d)
+    return p * (dp - delta)
 
 
 # ---------------------------------------------------------------------------
@@ -888,6 +922,200 @@ def _resident_flash_bwd(q, k, v, o, lse, do, scale: float, causal: bool,
     )(q, k, v, do, lse, delta)
 
 
+def _traced_once(*static: int):
+    """An inline ``jax.jit`` with those arguments static: a model's
+    unrolled layer loop calls a launcher once a layer with the same
+    shapes, and ``pallas_call`` traces its kernel body at every call —
+    48 times a GPT step, seconds of set-up.  Under the inline jit the
+    second call finds the first one's jaxpr, and nothing of it shows in
+    the program: no call, no component of the name stack."""
+    return functools.partial(jax.jit, static_argnums=static, inline=True)
+
+
+# ---------------------------------------------------------------------------
+# stream regime, resident form, split keys (latent attention): a head's q
+# and k are a 128-lane part of their own and a rotated part, and every head
+# shares one rotated key part.  The operands come token-major, (B, T, H*d)
+# as the projections write them, and each grid step's DMA takes one head's
+# column block; the scores are the sum of the two parts' contractions.
+# The results keep the layout of the pair above: out, lse, dq, dk and dv
+# head-major, dq and dk [own part | rotated part] wide.
+# ---------------------------------------------------------------------------
+def _latent_tiles(dn: int, dr: int, dv: int, heads: int) -> bool:
+    """Whether the split kernels take these head sizes: the own parts of
+    q and k and the v head whole 128-lane column blocks, the rotated part
+    64 wide, so that two heads fill one block of q_r."""
+    return dn % 128 == 0 and dv % 128 == 0 and dr == 64 and heads % 2 == 0
+
+
+def _head_part(block, h):
+    """Head ``h``'s 64-wide rotated part of q out of the 128-lane block
+    its DMA fetched, the two heads' parts side by side: an odd head's
+    half turned to the front (in float32: Mosaic rotates 32-bit lanes
+    alone)."""
+    wide = block.astype(jnp.float32)
+    part = jnp.where(h % 2 == 1, pltpu.roll(wide, 64, 1), wide)
+    return part[:, :64].astype(block.dtype)
+
+
+def _latent_specs(heads: int, block_q: int, Tk: int, dn: int, dr: int,
+                  dv: int):
+    """BlockSpecs over a (b, h, q block i) grid of the token-major
+    operands — q's parts and dO a block of rows per q block (q_r the
+    block of two heads that holds h's part), k's own part and v whole
+    rows per head, the shared rotated key part whole rows per batch
+    row — and of the head-major results."""
+    return dict(
+        qn=pl.BlockSpec((1, block_q, dn), lambda b, h, i: (b, i, h)),
+        qr=pl.BlockSpec((1, block_q, 128), lambda b, h, i: (b, i, h // 2)),
+        kn=pl.BlockSpec((1, Tk, dn), lambda b, h, i: (b, 0, h)),
+        kr=pl.BlockSpec((1, Tk, dr), lambda b, h, i: (b, 0, 0)),
+        v=pl.BlockSpec((1, Tk, dv), lambda b, h, i: (b, 0, h)),
+        do=pl.BlockSpec((1, block_q, dv), lambda b, h, i: (b, i, h)),
+        # head-major results, (B*H, ., .)
+        out=lambda width: pl.BlockSpec(
+            (1, block_q, width), lambda b, h, i: (b * heads + h, i, 0)),
+        rows=lambda width: pl.BlockSpec(
+            (1, Tk, width), lambda b, h, i: (b * heads + h, 0, 0)))
+
+
+def _latent_scores(qn, qr, kn, kr, scale: float):
+    return (_dot(qn, kn, _NT) + _dot(qr, kr, _NT)) * scale
+
+
+def _latent_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
+                       lse_ref, m_scr, l_scr, acc_scr, *, scale: float,
+                       causal: bool, block_q: int, chunk: int, nk: int,
+                       offset: int):
+    """:func:`_resident_fwd_kernel` over split keys."""
+    qi = pl.program_id(2)
+    live = _live_chunks(qi, block_q, chunk, offset, nk, causal)
+    _online_softmax_init(m_scr, l_scr, acc_scr)
+    qn = qn_ref[0]                                       # (bq, dn)
+    qr = _head_part(qr_ref[0], pl.program_id(1))         # (bq, 64)
+
+    def step(rows, mask):
+        _online_softmax_scores(
+            _latent_scores(qn, qr, kn_ref[0, rows, :], kr_ref[0, rows, :],
+                           scale),
+            v_ref[0, rows, :], mask, m_scr, l_scr, acc_scr)
+
+    _for_live_chunks(step, qi, live, causal, block_q, chunk, offset)
+    _online_softmax_finish(o_ref, lse_ref, m_scr, l_scr, acc_scr)
+
+
+def _latent_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, o_ref,
+                       lse_ref, dq_ref, dk_ref, dv_ref, dqn_scr, dqr_scr,
+                       dkn_scr, dkr_scr, dv_scr, *, scale: float,
+                       causal: bool, block_q: int, chunk: int, nq: int,
+                       nk: int, offset: int):
+    """:func:`_resident_bwd_kernel` over split keys: dq = [dS k_n | dS
+    k_r] and dk = [dSᵀ q_n | dSᵀ q_r], each part accumulated apart and
+    written into its lanes of the one result; delta = rowsum(dO * O) is
+    formed here from dO's token-major block and out's head-major one."""
+    qi = pl.program_id(2)
+    live = _live_chunks(qi, block_q, chunk, offset, nk, causal)
+
+    _zero_on_first(qi, dkn_scr, dkr_scr, dv_scr)
+
+    dqn_scr[...] = jnp.zeros_like(dqn_scr)
+    dqr_scr[...] = jnp.zeros_like(dqr_scr)
+    qn = qn_ref[0]                                       # (bq, dn)
+    qr = _head_part(qr_ref[0], pl.program_id(1))         # (bq, 64)
+    do = do_ref[0]
+    lse = lse_ref[0]                                     # (bq, 1)
+    delta = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+                    axis=-1, keepdims=True)
+
+    def step(rows, mask):
+        kn, kr = kn_ref[0, rows, :], kr_ref[0, rows, :]
+        ds = _saved_lse_ds(_latent_scores(qn, qr, kn, kr, scale),
+                           v_ref[0, rows, :], do, lse, delta, mask,
+                           (dv_scr, (rows, slice(None)))).astype(qn.dtype)
+        dqn_scr[...] += _dot(ds, kn, _NN)
+        dqr_scr[...] += _dot(ds, kr, _NN)
+        dkn_scr[rows, :] += _dot(ds, qn, _TN)
+        dkr_scr[rows, :] += _dot(ds, qr, _TN)
+
+    _for_live_chunks(step, qi, live, causal, block_q, chunk, offset)
+    dn = qn.shape[-1]
+    dq_ref[0, :, :dn] = (scale * dqn_scr[...]).astype(dq_ref.dtype)
+    dq_ref[0, :, dn:] = (scale * dqr_scr[...]).astype(dq_ref.dtype)
+
+    @pl.when(qi == nq - 1)
+    def _finalize():
+        dk_ref[0, :, :dn] = (scale * dkn_scr[...]).astype(dk_ref.dtype)
+        dk_ref[0, :, dn:] = (scale * dkr_scr[...]).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+@_traced_once(5, 6, 7)
+def _latent_flash_fwd(q_n, q_r, k_n, k_r, v, scale: float, causal: bool,
+                      plan: _Plan):
+    """q_n (B, T, H*dn), q_r (B, T, H*dr), k_n (B, Tk, H*dn), k_r (B, Tk,
+    dr), v (B, Tk, H*dv) -> (out (B*H, T, dv), lse (B*H, T, 1) f32)."""
+    B, T, _ = q_n.shape
+    Tk, dr = k_r.shape[1:]
+    H = q_r.shape[-1] // dr
+    dn, dv = q_n.shape[-1] // H, v.shape[-1] // H
+    block_q, chunk, _ = plan.fwd
+    specs = _latent_specs(H, block_q, Tk, dn, dr, dv)
+    return pl.pallas_call(
+        functools.partial(_latent_fwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, chunk=chunk, nk=Tk // chunk,
+                          offset=Tk - T),
+        grid=(B, H, T // block_q),
+        in_specs=[specs[n] for n in ("qn", "qr", "kn", "kr", "v")],
+        out_specs=[specs["out"](dv), specs["out"](1)],
+        out_shape=[jax.ShapeDtypeStruct((B * H, T, dv), q_n.dtype),
+                   jax.ShapeDtypeStruct((B * H, T, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=plan.vmem_limit),
+        interpret=plan.interpret,
+    )(q_n, q_r, k_n, k_r, v)
+
+
+@_traced_once(8, 9, 10)
+def _latent_flash_bwd(q_n, q_r, k_n, k_r, v, out, lse, do, scale: float,
+                      causal: bool, plan: _Plan):
+    """-> (dq, dk (B*H, ., dn + dr), dv (B*H, Tk, dv)), head-major, from
+    the operands of :func:`_latent_flash_fwd`, its results ``out`` and
+    ``lse`` and ``do`` token-major, (B, T, H*dv)."""
+    B, T, _ = q_n.shape
+    Tk, dr = k_r.shape[1:]
+    H = q_r.shape[-1] // dr
+    dn, dv = q_n.shape[-1] // H, v.shape[-1] // H
+    block_q, chunk, _ = plan.bwd
+    nq = T // block_q
+    specs = _latent_specs(H, block_q, Tk, dn, dr, dv)
+    return pl.pallas_call(
+        functools.partial(_latent_bwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, chunk=chunk, nq=nq,
+                          nk=Tk // chunk, offset=Tk - T),
+        grid=(B, H, nq),
+        in_specs=[*(specs[n] for n in ("qn", "qr", "kn", "kr", "v", "do")),
+                  specs["out"](dv), specs["out"](1)],
+        out_specs=[specs["out"](dn + dr), specs["rows"](dn + dr),
+                   specs["rows"](dv)],
+        out_shape=[jax.ShapeDtypeStruct((B * H, T, dn + dr), q_n.dtype),
+                   jax.ShapeDtypeStruct((B * H, Tk, dn + dr), k_n.dtype),
+                   jax.ShapeDtypeStruct((B * H, Tk, dv), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_q, dn), jnp.float32),
+                        pltpu.VMEM((block_q, dr), jnp.float32),
+                        pltpu.VMEM((Tk, dn), jnp.float32),
+                        pltpu.VMEM((Tk, dr), jnp.float32),
+                        pltpu.VMEM((Tk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=plan.vmem_limit),
+        interpret=plan.interpret,
+    )(q_n, q_r, k_n, k_r, v, do, out, lse)
+
+
 # ---------------------------------------------------------------------------
 # small and mid regimes, folded layout: full K/V rows resident in VMEM, G
 # batch-heads per grid step.  At the flagship regime (T=512, d=64,
@@ -1153,16 +1381,6 @@ def _section(s: int, G: int, rows: int, whole: bool = False):
         else (lambda b, hp, i: (s, b, i, hp)))
 
 
-def _traced_once(*static: int):
-    """An inline ``jax.jit`` with those arguments static: a model's
-    unrolled layer loop calls a launcher once a layer with the same
-    shapes, and ``pallas_call`` traces its kernel body at every call —
-    48 times a GPT step, seconds of set-up.  Under the inline jit the
-    second call finds the first one's jaxpr, and nothing of it shows in
-    the program: no call, no component of the name stack."""
-    return functools.partial(jax.jit, static_argnums=static, inline=True)
-
-
 @_traced_once(1, 2, 3, 4)
 def _qkv_fwd(qkv, num_heads: int, scale: float, causal: bool, plan: _Plan):
     """qkv: (3, B, T, H*d) -> ctx (B, T, H*d): whole rows and G batch
@@ -1362,6 +1580,48 @@ def _attend(q, k, v, scale: float, causal: bool, plan: _Plan):
     return _unfold(out, q.shape[0])
 
 
+def _by_tokens(x, b: int):
+    """(B*H, T, d) -> (B, T, H*d)."""
+    return _unfold(x, b).reshape(b, x.shape[1], -1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_latent(q_n, q_r, k_n, k_r, v, scale, causal, plan):
+    """Split-key attention of the resident pair, token-major in and out.
+    Its backward needs the forward's own ``out`` and ``lse``: both are
+    kept as the kernel wrote them, head-major, under the checkpoint
+    names of :func:`_flash_stream`'s; the output projection reads the
+    token-major view of ``out``."""
+    return _flash_latent_vjp_fwd(q_n, q_r, k_n, k_r, v, scale, causal,
+                                 plan)[0]
+
+
+def _flash_latent_vjp_fwd(q_n, q_r, k_n, k_r, v, scale, causal, plan):
+    out, lse = _latent_flash_fwd(q_n, q_r, k_n, k_r, v, scale, causal, plan)
+    # lse compact, tied to out: as in _flash_stream_vjp_fwd
+    out, lse = lax.optimization_barrier((out, lse[..., 0]))
+    out = checkpoint_name(out, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
+    return _by_tokens(out, q_n.shape[0]), (q_n, q_r, k_n, k_r, v, out, lse)
+
+
+def _flash_latent_vjp_bwd(scale, causal, plan, res, g):
+    q_n, q_r, k_n, k_r, v, out, lse = res
+    B, (Tk, dr) = q_n.shape[0], k_r.shape[1:]
+    dq, dk, dv = _latent_flash_bwd(q_n, q_r, k_n, k_r, v, out,
+                                   lse[..., None], g, scale, causal, plan)
+    dn = dq.shape[-1] - dr
+    # every head's rotated key part is the one k_r: its gradient is the
+    # sum of theirs
+    dk_r = dk[..., dn:].reshape(B, -1, Tk, dr).sum(
+        axis=1, dtype=jnp.float32).astype(k_r.dtype)
+    return (_by_tokens(dq[..., :dn], B), _by_tokens(dq[..., dn:], B),
+            _by_tokens(dk[..., :dn], B), dk_r, _by_tokens(dv, B))
+
+
+_flash_latent.defvjp(_flash_latent_vjp_fwd, _flash_latent_vjp_bwd)
+
+
 def _axes_entry(mesh, axes, dim: int):
     """PartitionSpec entry for one array dim: those of ``axes`` the mesh
     has with size > 1, when together they divide ``dim`` (else None —
@@ -1463,3 +1723,53 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
     h_ax = _axes_entry(mesh, head_axes, H)
     spec = PartitionSpec(b_ax, None, h_ax, None)
     return shard_kernel(local, mesh, (spec, spec, spec), spec)(q, k, v)
+
+
+def flash_attention_latent(q_n, q_r, k_n, k_r, v, *, causal: bool = False,
+                           mesh=None, batch_axes=()):
+    """Latent attention's heads in the layout their projections write.
+
+    q_n (B, S, H*dn) and q_r (B, S, H*dr) — a query head is [own part |
+    rotated part] —, k_n (B, Sk, H*dn), k_r (B, Sk, dr): the one rotated
+    key part every head shares, not repeated; v (B, Sk, H*dv).  All
+    token-major, a head's columns side by side on the last axis ->
+    (B, S, H*dv), ready for the output projection; the gradients come
+    back in the operands' forms (k_r's summed over the heads).  The
+    scores are q_n k_nᵀ + q_r k_rᵀ, scaled by 1/sqrt(dn + dr): attention
+    over the concatenated heads.
+
+    Where ``_plan`` takes the resident pair for the concatenated heads
+    and their sizes tile (``_latent_tiles``), the split-key kernels run
+    — counted ``flash_attention.stream_resident_latent`` — and each grid
+    step's DMA reads one head's column blocks, so no head is folded or
+    concatenated in HBM.  Elsewhere the concatenated heads run under the
+    same plan, as :func:`flash_attention` runs them.  ``mesh`` /
+    ``batch_axes``: as for :func:`flash_attention`; the heads stay whole
+    on every shard.
+    """
+    B, T, _ = q_n.shape
+    Tk, dr = k_r.shape[1:]
+    H = q_r.shape[-1] // dr
+    dn, dv = q_n.shape[-1] // H, v.shape[-1] // H
+    s = float(1.0 / np.sqrt(dn + dr))
+
+    def local(q_n, q_r, k_n, k_r, v):
+        b = q_n.shape[0]
+        plan = _plan("folded", b, T, Tk, H, dn + dr, q_n.dtype.itemsize,
+                     causal, dv)
+        if plan.name == "stream_resident" and _latent_tiles(dn, dr, dv, H):
+            note("flash_attention.stream", True)
+            note("flash_attention.stream_resident_latent", True)
+            return _flash_latent(q_n, q_r, k_n, k_r, v, s, causal, plan)
+        q = jnp.concatenate([q_n.reshape(b, T, H, dn),
+                             q_r.reshape(b, T, H, dr)], axis=-1)
+        k = jnp.concatenate([k_n.reshape(b, Tk, H, dn), jnp.broadcast_to(
+            k_r[:, :, None], (b, Tk, H, dr))], axis=-1)
+        return _attend(q, k, v.reshape(b, Tk, H, dv), s, causal,
+                       plan).reshape(b, T, H * dv)
+
+    if not _kernels_apply(T, Tk, causal):
+        return local(q_n, q_r, k_n, k_r, v)    # XLA math: GSPMD partitions
+    spec = PartitionSpec(_axes_entry(mesh, batch_axes, B), None, None)
+    return shard_kernel(local, mesh, (spec,) * 5, spec)(q_n, q_r, k_n, k_r,
+                                                        v)

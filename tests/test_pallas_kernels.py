@@ -1027,6 +1027,115 @@ def test_resident_pair_at_unequal_head_sizes_vs_xla(bq, ck, causal, tq, tk,
             atol=tol, rtol=tol, err_msg=name)
 
 
+def _latent_operands(rs, B, tq, tk, H, dn, dr, dv, dt):
+    """Token-major split-key operands and an output cotangent."""
+    def x(*shape):
+        return jnp.asarray(rs.randn(*shape), dt)
+    return (x(B, tq, H * dn), x(B, tq, H * dr), x(B, tk, H * dn),
+            x(B, tk, dr), x(B, tk, H * dv)), x(B, tq, H * dv)
+
+
+def _latent_reference(q_n, q_r, k_n, k_r, v, scale, causal):
+    """The XLA math on the concatenated heads: q = [q_n | q_r], k = [k_n |
+    k_r repeated over the heads] -> (B, T, H*dv)."""
+    B, T, _ = q_n.shape
+    Tk, dr = k_r.shape[1:]
+    H = q_r.shape[-1] // dr
+    q = jnp.concatenate([q_n.reshape(B, T, H, -1),
+                         q_r.reshape(B, T, H, dr)], axis=-1)
+    k = jnp.concatenate([k_n.reshape(B, Tk, H, -1), jnp.broadcast_to(
+        k_r[:, :, None], (B, Tk, H, dr))], axis=-1)
+    out = fa._xla_attention(fa._fold(q), fa._fold(k),
+                            fa._fold(v.reshape(B, Tk, H, -1)), scale, causal)
+    return fa._unfold(out, B).reshape(B, T, -1)
+
+
+@pytest.mark.parametrize("bq,ck,causal,tq,tk,heads,dtype", [
+    (128, 128, True, 512, 512, 2, "float32"),
+    (256, 128, True, 512, 512, 2, "float32"),
+    (128, 256, True, 256, 512, 2, "float32"),
+    (128, 128, False, 256, 384, 2, "float32"),
+    (128, 128, True, 384, 384, 2, "bfloat16"),
+    (128, 128, True, 256, 256, 4, "bfloat16"),
+], ids=["blocks-128", "q-block-256", "chunk-256-decode-offset",
+        "not-causal", "bfloat16", "bfloat16-four-heads"])
+def test_split_key_pair_vs_xla(bq, ck, causal, tq, tk, heads, dtype):
+    """The split-key entry of the resident pair (latent attention, q = [q_n
+    | q_r] per head, k = [k_n | the one k_r], the operands token-major),
+    interpreted, at the shapes of the test above: out and all five
+    gradients against the XLA math on the concatenated heads and its
+    ``jax.vjp``.  Two heads, so that the two halves of q_r's 128-lane
+    block (heads of 64) are each a head's; four, so that heads 2 and 3
+    read the second block."""
+    rs = np.random.RandomState(7)
+    dt = jnp.dtype(dtype)
+    ops, g = _latent_operands(rs, 1, tq, tk, heads, 128, 64, 128, dt)
+    scale = 1.0 / np.sqrt(128 + 64)
+    plan = _stream_plan("resident", bq, ck)
+    out, vjp = jax.vjp(
+        lambda *a: fa._flash_latent(*a, scale, causal, plan), *ops)
+    grads = vjp(g)
+    ref, ref_vjp = jax.vjp(
+        lambda *a: _latent_reference(*a, scale, causal),
+        *(x.astype(jnp.float32) for x in ops))
+    tol = 3e-5 if dt == jnp.float32 else 4e-2
+    for name, got, want in zip(
+            ("out", "dq_n", "dq_r", "dk_n", "dk_r", "dv"), (out, *grads),
+            (ref, *ref_vjp(g.astype(jnp.float32)))):
+        assert got.dtype == dt and got.shape == want.shape, name
+        np.testing.assert_allclose(
+            np.asarray(got.astype(jnp.float32)), np.asarray(want),
+            atol=tol, rtol=tol, err_msg=name)
+
+
+def test_split_keys_through_the_public_entry(force_pallas, monkeypatch):
+    """``flash_attention_latent``: where the resident pair is planned (the
+    thresholds lowered so that 256 rows select it) the split-key kernels
+    run and count as such, values and gradients agree with the XLA math.
+    Elsewhere the concatenated heads run as ``flash_attention`` runs
+    them: XLA math at a length the whole-row regimes own (they take no
+    q/k head of 192 over a v head of 128); an own part that fills no
+    whole lane block (64 + 64 over 128) takes the small kernels there
+    and the resident pair at 256 rows once the thresholds are lowered."""
+    from paddle_tpu.ops import pallas
+    rs = np.random.RandomState(8)
+    ops, g = _latent_operands(rs, 1, 256, 256, 2, 128, 64, 128,
+                              jnp.float32)
+    narrow, _ = _latent_operands(rs, 1, 256, 256, 2, 64, 64, 128,
+                                 jnp.float32)
+
+    def took(*args):
+        before = pallas.selections()
+        out, vjp = jax.vjp(
+            functools.partial(fa.flash_attention_latent, causal=True),
+            *args)
+        return out, vjp, {n for n, c in pallas.selections().items()
+                          if c != before.get(n, 0)}
+
+    want, ref_vjp = jax.vjp(
+        lambda *a: _latent_reference(*a, 192 ** -0.5, True), *ops)
+    narrow_want = _latent_reference(*narrow, 128 ** -0.5, True)
+    out, _, names = took(*ops)
+    assert names == {"flash_attention.xla"}
+    np.testing.assert_allclose(out, want, atol=2e-5)
+    out, _, names = took(*narrow)
+    assert names == {"flash_attention.small.interpret"}
+    np.testing.assert_allclose(out, narrow_want, atol=2e-5)
+    monkeypatch.setattr(fa, "SMALL_T_MAX", 0)
+    monkeypatch.setattr(fa, "MID_T_MAX", 0)
+    out, vjp, names = took(*ops)
+    assert names == {"flash_attention.stream.interpret",
+                     "flash_attention.stream_resident_latent.interpret"}
+    assert out.shape == (1, 256, 256)
+    np.testing.assert_allclose(out, want, atol=2e-5)
+    for got, exp in zip(vjp(g), ref_vjp(g)):
+        np.testing.assert_allclose(got, exp, atol=5e-5)
+    out, _, names = took(*narrow)
+    assert names == {"flash_attention.stream.interpret",
+                     "flash_attention.stream_resident.interpret"}
+    np.testing.assert_allclose(out, narrow_want, atol=2e-5)
+
+
 def test_unequal_head_sizes_through_the_public_entry(force_pallas,
                                                      monkeypatch):
     """``flash_attention`` with a v head narrower than q's: the stream

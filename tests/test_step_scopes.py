@@ -749,6 +749,7 @@ def joyai_real_width_hlo(v5e):
     for mod in (pallas, fa):
         mp.setattr(mod, "on_tpu", lambda: True)
     mp.setattr(fa, "_vmem_capacity", lambda: 128 << 20)
+    before = pallas.selections()
     try:
         cfg = JoyAIFlashConfig(vocab_size=16160, num_hidden_layers=2,
                                num_experts_held=8, moe_rows_factor=4.0)
@@ -757,6 +758,13 @@ def joyai_real_width_hlo(v5e):
                            remat_policy="ctx").compile().as_text()
     finally:
         mp.undo()
+        JOYAI_SELECTIONS.update(
+            (n, c - before.get(n, 0))
+            for n, c in pallas.selections().items() if c != before.get(n, 0))
+
+
+# what the JoyAI fixture's trace selected, kernel by kernel
+JOYAI_SELECTIONS = {}
 
 
 def test_every_joyai_mosaic_call_is_one_the_benchmark_finds(
@@ -819,6 +827,52 @@ def test_every_joyai_mosaic_call_is_one_the_benchmark_finds(
     # and this cell's attention patterns find nothing in another's step
     for c in mosaic:
         assert "bf16[64,8192,256]" not in c and "bf16[128,8192,64]" not in c
+
+
+@pytest.mark.parametrize("shape", [
+    "bf16[2,32,8192,192]", "bf16[2,8192,32,192]"])
+def test_latent_attention_folds_no_192_wide_head(joyai_real_width_hlo,
+                                                 shape):
+    """q and k reach the flash pair as the projections wrote them: no
+    ``copy``, ``transpose`` or ``reshape`` of the step, whatever its op
+    path, moves a (B, H, T, 192) or (B, T, H, 192) array — the parent's
+    step folded q and k and their concatenations, four of them a block
+    (``jvp(...)/transpose``, under no scope)."""
+    assert [(name, op) for name, result, opcode, op
+            in unfused_instructions(joyai_real_width_hlo)
+            if opcode in ("copy", "transpose", "reshape")
+            and result.startswith(shape + "{")] == []
+
+
+def test_the_joyai_flash_calls_are_the_split_key_entry_s(
+        joyai_real_width_hlo):
+    """Three forwards and three fused backwards (two blocks and the
+    module), each reading q_n, q_r, k_n, the one k_r and v token-major —
+    (B, T, 32 x 128), (B, T, 32 x 64), (B, T, 64) — the backward also dO
+    token-major and the forward's head-major out and lse; the trace took
+    the split-key kernels and nothing else for attention."""
+    tokens = ["bf16[2,8192,4096]", "bf16[2,8192,2048]", "bf16[2,8192,4096]",
+              "bf16[2,8192,64]", "bf16[2,8192,4096]"]
+    calls = {"forward": [], "backward": []}
+    for c in _mosaic_calls(joyai_real_width_hlo):
+        operands = re.search(
+            r"operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}", c)
+        shapes = re.findall(r"\w+\[[\d,]*\]",
+                            operands.group(1) if operands else "")
+        if c.startswith(("%jvp__", "%jvp_mtp_")) and \
+                "bf16[64,8192,128]" in c.split(" custom-call(")[0]:
+            calls["forward"].append(shapes)
+        elif c.startswith("%checkpoint"):
+            calls["backward"].append(shapes)
+    assert calls["forward"] == [tokens] * 3, calls["forward"]
+    assert calls["backward"] == [tokens + [
+        "bf16[2,8192,4096]", "bf16[64,8192,128]", "f32[64,8192,1]"]] * 3, \
+        calls["backward"]
+    flash = {n: c for n, c in JOYAI_SELECTIONS.items()
+             if n.startswith("flash_attention")}
+    assert set(flash) == {"flash_attention.stream.mosaic",
+                          "flash_attention.stream_resident_latent.mosaic"}, \
+        flash
 
 
 @pytest.mark.parametrize("shape", HANDOVER_SHAPES)

@@ -478,6 +478,42 @@ def test_flash_attention_at_unequal_head_sizes_compiles(v5e, monkeypatch):
     low.compile()
 
 
+def test_the_split_key_entry_compiles_at_the_cell_s_shapes(v5e,
+                                                          monkeypatch):
+    """The JoyAI cell's attention as its model hands it over: q_n, k_n and
+    v (2, 8192, 32 x 128), q_r (2, 8192, 32 x 64) and the one k_r (2,
+    8192, 64), token-major, bf16 causal, forward and backward — the
+    split-key kernels, two Mosaic calls, within the VMEM the plan asks
+    for, with the results the benchmark finds the pair by."""
+    import sys
+    from paddle_tpu.ops import pallas
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_latent
+    fa = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+    monkeypatch.setattr(fa, "_vmem_capacity", lambda: 128 << 20)
+    S = _on(v5e[0])
+
+    @jax.jit
+    def fwd_bwd(q_n, q_r, k_n, k_r, v, g):
+        out, vjp = jax.vjp(functools.partial(flash_attention_latent,
+                                             causal=True),
+                           q_n, q_r, k_n, k_r, v)
+        return out, vjp(g)
+
+    before = pallas.selections().get(
+        "flash_attention.stream_resident_latent.mosaic", 0)
+    wide = S((2, 8192, 4096), jnp.bfloat16)
+    low = fwd_bwd.lower(wide, S((2, 8192, 2048), jnp.bfloat16), wide,
+                        S((2, 8192, 64), jnp.bfloat16), wide, wide)
+    assert pallas.selections()[
+        "flash_attention.stream_resident_latent.mosaic"] > before
+    assert "flash_attention.xla" not in pallas.selections()
+    assert _mosaic_calls(low) == 2
+    text = low.compile().as_text()
+    assert "(bf16[64,8192,128]{2,1,0:T(8,128)(2,1)}, f32[64,8192,1]" in text
+    assert "(bf16[64,8192,192]{2,1,0:T(8,128)(2,1)}, bf16[64,8192,192]" \
+        "{2,1,0:T(8,128)(2,1)}, bf16[64,8192,128]" in text
+
+
 def test_the_joyai_step_fits_the_chip_at_the_cell_s_size(v5e, monkeypatch):
     """The whole step of ``joyai-llm-flash.train-t8192`` — the
     configuration file's model (491.7 M parameters: five layers and the
