@@ -44,7 +44,7 @@ from jax.sharding import PartitionSpec
 
 from . import pallas
 from .pallas import causal_conv as _kernels
-from .pallas.flash_attention import _axes_entry, _traced_once
+from .pallas import axes_entry, traced_once
 
 __all__ = ["gated_causal_conv", "short_conv"]
 
@@ -79,7 +79,7 @@ def _reference(qkv, conv_w, n_qk: int, head: int):
             c[..., 2 * n_qk:])
 
 
-@_traced_once(2, 3, 4)
+@traced_once(2, 3, 4)
 def _conv_kernel(qkv, conv_w, n_qk: int, head: int, plan):
     """(An inline jit, like the backward: a model's layers call with the
     same shapes, and the second finds the first one's jaxpr.)"""
@@ -94,7 +94,7 @@ def _conv_fwd(qkv, conv_w, n_qk, head, plan):
     return _conv_kernel(qkv, conv_w, n_qk, head, plan), (qkv, conv_w)
 
 
-@_traced_once(0, 1, 2)
+@traced_once(0, 1, 2)
 def _conv_bwd(n_qk, head, plan, inputs, d_out):
     """What the forward leaves behind is its inputs: the backward kernel
     runs the convolution again."""
@@ -133,7 +133,7 @@ def gated_causal_conv(qkv, conv_w, *, n_qk: int, head: int, mesh=None,
     pallas.note("causal_conv", plan is not None)
     if plan is None:
         return _reference(qkv, conv_w, n_qk, head)
-    spec = PartitionSpec(_axes_entry(mesh, batch_axes, B))
+    spec = PartitionSpec(axes_entry(mesh, batch_axes, B))
     return pallas.shard_kernel(
         lambda x, w: _conv(x, w, n_qk, head, plan), mesh,
         (spec, PartitionSpec()), (spec,) * 3)(qkv, conv_w)
@@ -149,7 +149,7 @@ def _short_reference(bcu, conv_w):
     return c * _causal_conv(b * u, conv_w)
 
 
-@_traced_once(2)
+@traced_once(2)
 def _short_kernel(bcu, conv_w, plan):
     return _kernels.short_conv_fwd(bcu, conv_w.astype(jnp.float32),
                                    plan=plan)
@@ -162,7 +162,7 @@ def _short_fwd(bcu, conv_w, plan):
     return _short_kernel(bcu, conv_w, plan), (bcu, conv_w)
 
 
-@_traced_once(0)
+@traced_once(0)
 def _short_bwd(plan, inputs, dy):
     """The residuals are the inputs: the backward kernel runs the
     convolution again."""
@@ -201,7 +201,7 @@ def short_conv(bcu, conv_w, *, mesh=None, batch_axes=()):
     pallas.note("short_conv", plan is not None)
     if plan is None:
         return _short_reference(bcu, conv_w)
-    rows = _axes_entry(mesh, batch_axes, B)
+    rows = axes_entry(mesh, batch_axes, B)
     return pallas.shard_kernel(
         lambda x, w: _short(x, w, plan), mesh,
         (PartitionSpec(None, rows), PartitionSpec()),

@@ -76,7 +76,7 @@ from jax.sharding import PartitionSpec
 
 from . import pallas
 from .pallas import gated_delta_rule as _kernels
-from .pallas.flash_attention import _axes_entry, _traced_once
+from .pallas import axes_entry, traced_once
 
 __all__ = ["gated_delta_rule", "gated_delta_rule_reference",
            "RESIDUAL_NAMES"]
@@ -199,7 +199,7 @@ def _operands(q, k, v, gates, plan, with_inverse=False):
     return (*operands, last[None]), inv
 
 
-@_traced_once(5, 6)
+@traced_once(5, 6)
 def _rule_kernels(q, k, v, g, beta, chunk: int, plan):
     """The rule in the kernels, a batch row at a time, q, k, v and the
     output token-major: the gates (XLA ops), ``prep``, then
@@ -237,7 +237,7 @@ def _row_vjp(q, k, v, g, beta, do, chunk: int, plan):
             *gates_vjp((dG[0], d_beta[0], d_last[0])))
 
 
-@_traced_once(0, 1)
+@traced_once(0, 1)
 def _rule_bwd(chunk, plan, inputs, do):
     """A row at a time.  What the forward leaves behind is the rule's
     inputs."""
@@ -295,7 +295,7 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, key_heads=None,
             q.reshape(B, -1, key_heads, dk), k.reshape(B, -1, key_heads, dk),
             v.reshape(B, -1, H, dv), g, beta)).reshape(B, -1, H * dv)
     else:
-        b_ax = _axes_entry(mesh, batch_axes, B)
+        b_ax = axes_entry(mesh, batch_axes, B)
         spec = PartitionSpec(b_ax)
         o = pallas.shard_kernel(
             lambda *x: _rule(*x, chunk, plan), mesh, (spec,) * 5,

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .pallas import moe_rows as _kernels
-from .pallas.flash_attention import _traced_once
+from .pallas import traced_once
 
 __all__ = ["dispatch", "combine", "runs"]
 
@@ -58,7 +58,7 @@ def runs(pos, valid, group, groups: int, plan):
             jnp.where(valid, s.reshape(N, k), -1))
 
 
-@_traced_once(4, 5, 6, 7)
+@traced_once(4, 5, 6, 7)
 def _take(x, tiles, kept, w, R: int, cap: int, groups: int, plan):
     starts, rows, s = tiles
     return _kernels.take_rows(x, starts, rows, s.T, kept,
@@ -66,12 +66,12 @@ def _take(x, tiles, kept, w, R: int, cap: int, groups: int, plan):
                               groups=groups, plan=plan)
 
 
-@_traced_once(3, 4)
+@traced_once(3, 4)
 def _gather(buf, tiles, w, groups: int, plan):
     return _kernels.gather_rows(buf, *tiles, w, groups=groups, plan=plan)
 
 
-@_traced_once(3, 4)
+@traced_once(3, 4)
 def _weight_grad(buf, tiles, dy, groups: int, plan):
     return _kernels.gather_rows(buf, *tiles, dy=dy, groups=groups, plan=plan)
 

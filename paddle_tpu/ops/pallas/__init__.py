@@ -28,17 +28,28 @@ module, and records what it chose:
 - each choice bumps ``pallas.<kernel>.<impl>`` (``mosaic`` /
   ``interpret`` / ``xla``) at trace time, so a run can assert which
   implementation its programs hold (``selections()``).
+
+It also holds what every kernel family shares: the tile matmul
+(``dot`` with ``NN`` / ``NT`` / ``TN``), ``divisor`` for block sizes,
+``traced_once`` for the launchers and ``axes_entry`` for the sharded
+entries.  A kernel module imports these from here, never another
+kernel module's private names.
 """
 import contextlib
+import functools
 import os
 import threading
 
+import numpy as np
+
 import jax
+import jax.numpy as jnp
 
 from ...profiler import metrics as _metrics
 
 __all__ = ["flash_attention", "on_tpu", "enabled", "note", "selections",
-           "shard_kernel", "kernel_mesh", "current_kernel_mesh"]
+           "shard_kernel", "kernel_mesh", "current_kernel_mesh", "NN", "NT",
+           "TN", "dot", "divisor", "traced_once", "axes_entry"]
 
 
 def on_tpu() -> bool:
@@ -114,6 +125,44 @@ def current_kernel_mesh():
     """``(mesh, batch_axes, head_axes)`` of the enclosing
     :func:`kernel_mesh`, or None."""
     return getattr(_mesh_scope, "spec", None)
+
+
+# the tile matmul's dimension numbers
+NT = (((1,), (1,)), ((), ()))           # a @ b.T
+NN = (((1,), (0,)), ((), ()))           # a @ b
+TN = (((0,), (0,)), ((), ()))           # a.T @ b
+
+
+def dot(a, b, dims):
+    # operands stay in input dtype: bf16 x bf16 -> f32 runs the MXU at
+    # full rate
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def divisor(n: int, most: int) -> int:
+    return max(d for d in range(1, most + 1) if n % d == 0)
+
+
+def traced_once(*static: int):
+    """An inline ``jax.jit`` with those arguments static: a model's
+    unrolled layer loop calls a launcher once a layer with the same
+    shapes, and ``pallas_call`` traces its kernel body at every call —
+    48 times a GPT step, seconds of set-up.  Under the inline jit the
+    second call finds the first one's jaxpr, and nothing of it shows in
+    the program: no call, no component of the name stack."""
+    return functools.partial(jax.jit, static_argnums=static, inline=True)
+
+
+def axes_entry(mesh, axes, dim: int):
+    """PartitionSpec entry for one array dim: those of ``axes`` the mesh
+    has with size > 1, when together they divide ``dim`` (else None —
+    the dim stays whole on every shard)."""
+    keep = tuple(a for a in axes if mesh.shape.get(a, 1) > 1) \
+        if mesh is not None else ()
+    if not keep or dim % int(np.prod([mesh.shape[a] for a in keep])):
+        return None
+    return keep if len(keep) > 1 else keep[0]
 
 
 from .flash_attention import flash_attention  # noqa: E402,F401

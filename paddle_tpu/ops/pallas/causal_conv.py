@@ -84,7 +84,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .gated_delta_rule import _divisor
+from . import divisor
 
 __all__ = ["plan", "conv_fwd", "conv_bwd", "EPS", "short_plan",
            "short_conv_fwd", "short_conv_bwd"]
@@ -128,10 +128,10 @@ def plan(B: int, T: int, C: int, taps: int, head: int, dtype, *,
     # pipeline's buffers): 10 MB of the 16 MiB a kernel may use, whatever
     # the itemsize
     most = _BLOCK_T * 2 // jnp.dtype(dtype).itemsize
-    block_t = _HALO * _divisor(T // _HALO, most // _HALO)
+    block_t = _HALO * divisor(T // _HALO, most // _HALO)
     block_c = max(c for c in range(head, max(_BLOCK_C, head) + 1, head)
                   if n_qk % c == 0 and n_v % c == 0)
-    rows = _HALO * _divisor(block_t // _HALO, _ROWS // _HALO)
+    rows = _HALO * divisor(block_t // _HALO, _ROWS // _HALO)
     return Plan(block_t, block_c, rows, interpret)
 
 
@@ -380,9 +380,9 @@ def short_plan(B: int, T: int, D: int, taps: int, dtype, *,
     # the three gradients' twice (the pipeline's buffers): 14 MB at 1024 x
     # 512 bfloat16 (512 tokens in float32) of the 16 MiB a kernel may use
     most = _BLOCK_T * 2 // jnp.dtype(dtype).itemsize
-    block_t = _HALO * _divisor(T // _HALO, most // _HALO)
-    block_c = _LANES * _divisor(D // _LANES, _BLOCK_C // _LANES)
-    rows = _HALO * _divisor(block_t // _HALO, _ROWS // _HALO)
+    block_t = _HALO * divisor(T // _HALO, most // _HALO)
+    block_c = _LANES * divisor(D // _LANES, _BLOCK_C // _LANES)
+    rows = _HALO * divisor(block_t // _HALO, _ROWS // _HALO)
     return Plan(block_t, block_c, rows, interpret)
 
 

@@ -155,7 +155,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec
 
-from . import enabled, note, on_tpu, shard_kernel
+from . import (NN, NT, TN, axes_entry, dot, enabled, note, on_tpu,
+               shard_kernel, traced_once)
 
 __all__ = ["flash_attention", "flash_attention_latent",
            "flash_attention_stacked"]
@@ -410,20 +411,8 @@ def _plan(layout: str, B: int, T: int, Tk: int, heads: int, d: int,
 # _qkv_mid_bwd_kernel, _resident_*), so that those trace to the programs
 # that were measured; the two chunk routines take the scratch refs they
 # update, so that the scratch is read and written where it was among the
-# matmuls.
+# matmuls.  The matmul itself, ``dot``, is the package's.
 # ---------------------------------------------------------------------------
-_NT = (((1,), (1,)), ((), ()))          # a @ b.T
-_NN = (((1,), (0,)), ((), ()))          # a @ b
-_TN = (((0,), (0,)), ((), ()))          # a.T @ b
-
-
-def _dot(a, b, dims):
-    # operands stay in input dtype: bf16 x bf16 -> f32 runs the MXU at
-    # full rate
-    return jax.lax.dot_general(a, b, dims,
-                               preferred_element_type=jnp.float32)
-
-
 def _causal_mask(shape, qi, block_q: int, offset: int, j=None,
                  chunk: int = 0, window: Optional[int] = None):
     """True where a score tile's query row sees its key column: row r of
@@ -467,11 +456,11 @@ def _row_fwd(q, k, v, mask, scale: float):
     """Attention of (bq, d) queries over whole (Tk, d) K/V rows -> the
     (bq, d) output in f32.  ``mask`` as for :func:`_mask_row` (applied
     once the scores exist).  scale folds into the f32 scores."""
-    s = _mask_row(_dot(q, k, _NT) * scale, mask)         # (bq, Tk)
+    s = _mask_row(dot(q, k, NT) * scale, mask)           # (bq, Tk)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    return _dot(p.astype(v.dtype), v, _NN) / l
+    return dot(p.astype(v.dtype), v, NN) / l
 
 
 def _row_bwd(q, k, v, do, mask, scale: float):
@@ -480,19 +469,19 @@ def _row_bwd(q, k, v, do, mask, scale: float):
     vs. the 7 a two-kernel backward spends) -> (dq, dk, dv).  dq is
     final for these rows (every key was seen) and comes back in the
     operand dtype; dk and dv are this q block's share, in f32."""
-    s = _mask_row(_dot(q, k, _NT) * scale, mask)         # (bq, Tk)
+    s = _mask_row(dot(q, k, NT) * scale, mask)           # (bq, Tk)
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.exp(s - m)
     l = jnp.sum(e, axis=-1, keepdims=True)
     p = e / l                                            # softmax, f32
-    dp = _dot(do, v, _NT)                                # (bq, Tk)
+    dp = dot(do, v, NT)                                  # (bq, Tk)
     # delta_i = sum_j p_ij dp_ij  (== rowsum(dO * O), derived in-kernel
     # so O need not be a residual)
     delta = jnp.sum(p * dp, axis=-1, keepdims=True)
-    dv = _dot(p.astype(do.dtype), do, _TN)               # (Tk, d)
+    dv = dot(p.astype(do.dtype), do, TN)                 # (Tk, d)
     ds = (p * (dp - delta)).astype(q.dtype)
-    dq = (scale * _dot(ds, k, _NN)).astype(q.dtype)
-    dk = scale * _dot(ds, q, _TN)                        # (Tk, d)
+    dq = (scale * dot(ds, k, NN)).astype(q.dtype)
+    dk = scale * dot(ds, q, TN)                          # (Tk, d)
     return dq, dk, dv
 
 
@@ -517,7 +506,7 @@ def _online_softmax_step(q, k, v, mask, scale: float, m_scr, l_scr,
     Chunk 0 is live for every row (column 0 is), so m is finite from the
     first step on and a row wholly masked in a later chunk adds
     exp(NEG_INF - m) = 0."""
-    _online_softmax_scores(_dot(q, k, _NT) * scale, v, mask, m_scr, l_scr,
+    _online_softmax_scores(dot(q, k, NT) * scale, v, mask, m_scr, l_scr,
                            acc_scr)
 
 
@@ -531,7 +520,7 @@ def _online_softmax_scores(s, v, mask, m_scr, l_scr, acc_scr):
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m - m_new)
     l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + _dot(p.astype(v.dtype), v, _NN)
+    acc_scr[...] = acc_scr[...] * corr + dot(p.astype(v.dtype), v, NN)
     m_scr[...] = m_new
 
 
@@ -553,15 +542,15 @@ def _saved_lse_bwd_tile(q, k, v, do, lse, delta, mask, scale: float, *,
     sums; the grid-streamed kernels scale every tile's product
     (``scale_each``), as they always have, so that their sums round as
     they did."""
-    ds = _saved_lse_ds(_dot(q, k, _NT) * scale, v, do, lse, delta, mask,
+    ds = _saved_lse_ds(dot(q, k, NT) * scale, v, do, lse, delta, mask,
                        dv).astype(q.dtype)
     fold = (lambda x: scale * x) if scale_each else (lambda x: x)
     if dq is not None:
         ref, at = dq
-        ref[at] += fold(_dot(ds, k, _NN))
+        ref[at] += fold(dot(ds, k, NN))
     if dk is not None:      # s = scale q k^T  =>  dK += scale dS^T q
         ref, at = dk
-        ref[at] += fold(_dot(ds, q, _TN))                # (chunk, d)
+        ref[at] += fold(dot(ds, q, TN))                  # (chunk, d)
 
 
 def _saved_lse_ds(s, v, do, lse, delta, mask, dv=None):
@@ -571,10 +560,10 @@ def _saved_lse_ds(s, v, do, lse, delta, mask, dv=None):
     p = jnp.exp(s - lse)
     if mask is not None:
         p = jnp.where(mask(s.shape), p, 0.0)
-    dp = _dot(do, v, _NT)                                # (bq, chunk)
+    dp = dot(do, v, NT)                                  # (bq, chunk)
     if dv is not None:      # dV += P^T dO
         ref, at = dv
-        ref[at] += _dot(p.astype(do.dtype), do, _TN)     # (chunk, d)
+        ref[at] += dot(p.astype(do.dtype), do, TN)       # (chunk, d)
     return p * (dp - delta)
 
 
@@ -922,16 +911,6 @@ def _resident_flash_bwd(q, k, v, o, lse, do, scale: float, causal: bool,
     )(q, k, v, do, lse, delta)
 
 
-def _traced_once(*static: int):
-    """An inline ``jax.jit`` with those arguments static: a model's
-    unrolled layer loop calls a launcher once a layer with the same
-    shapes, and ``pallas_call`` traces its kernel body at every call —
-    48 times a GPT step, seconds of set-up.  Under the inline jit the
-    second call finds the first one's jaxpr, and nothing of it shows in
-    the program: no call, no component of the name stack."""
-    return functools.partial(jax.jit, static_argnums=static, inline=True)
-
-
 # ---------------------------------------------------------------------------
 # stream regime, resident form, split keys (latent attention): a head's q
 # and k are a 128-lane part of their own and a rotated part, and every head
@@ -980,7 +959,7 @@ def _latent_specs(heads: int, block_q: int, Tk: int, dn: int, dr: int,
 
 
 def _latent_scores(qn, qr, kn, kr, scale: float):
-    return (_dot(qn, kn, _NT) + _dot(qr, kr, _NT)) * scale
+    return (dot(qn, kn, NT) + dot(qr, kr, NT)) * scale
 
 
 def _latent_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
@@ -1032,10 +1011,10 @@ def _latent_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, o_ref,
         ds = _saved_lse_ds(_latent_scores(qn, qr, kn, kr, scale),
                            v_ref[0, rows, :], do, lse, delta, mask,
                            (dv_scr, (rows, slice(None)))).astype(qn.dtype)
-        dqn_scr[...] += _dot(ds, kn, _NN)
-        dqr_scr[...] += _dot(ds, kr, _NN)
-        dkn_scr[rows, :] += _dot(ds, qn, _TN)
-        dkr_scr[rows, :] += _dot(ds, qr, _TN)
+        dqn_scr[...] += dot(ds, kn, NN)
+        dqr_scr[...] += dot(ds, kr, NN)
+        dkn_scr[rows, :] += dot(ds, qn, TN)
+        dkr_scr[rows, :] += dot(ds, qr, TN)
 
     _for_live_chunks(step, qi, live, causal, block_q, chunk, offset)
     dn = qn.shape[-1]
@@ -1049,7 +1028,7 @@ def _latent_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, o_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-@_traced_once(5, 6, 7)
+@traced_once(5, 6, 7)
 def _latent_flash_fwd(q_n, q_r, k_n, k_r, v, scale: float, causal: bool,
                       plan: _Plan):
     """q_n (B, T, H*dn), q_r (B, T, H*dr), k_n (B, Tk, H*dn), k_r (B, Tk,
@@ -1079,7 +1058,7 @@ def _latent_flash_fwd(q_n, q_r, k_n, k_r, v, scale: float, causal: bool,
     )(q_n, q_r, k_n, k_r, v)
 
 
-@_traced_once(8, 9, 10)
+@traced_once(8, 9, 10)
 def _latent_flash_bwd(q_n, q_r, k_n, k_r, v, out, lse, do, scale: float,
                       causal: bool, plan: _Plan):
     """-> (dq, dk (B*H, ., dn + dr), dv (B*H, Tk, dv)), head-major, from
@@ -1381,7 +1360,7 @@ def _section(s: int, G: int, rows: int, whole: bool = False):
         else (lambda b, hp, i: (s, b, i, hp)))
 
 
-@_traced_once(1, 2, 3, 4)
+@traced_once(1, 2, 3, 4)
 def _qkv_fwd(qkv, num_heads: int, scale: float, causal: bool, plan: _Plan):
     """qkv: (3, B, T, H*d) -> ctx (B, T, H*d): whole rows and G batch
     rows a step (packed_small), or q blocks with K/V rows resident."""
@@ -1406,7 +1385,7 @@ def _qkv_fwd(qkv, num_heads: int, scale: float, causal: bool, plan: _Plan):
     )(qkv, qkv, qkv)
 
 
-@_traced_once(2, 3, 4, 5)
+@traced_once(2, 3, 4, 5)
 def _qkv_bwd(qkv, do, num_heads: int, scale: float, causal: bool,
              plan: _Plan):
     """-> dqkv (3, B, T, H*d) for qkv as in :func:`_qkv_fwd`: one fused
@@ -1622,17 +1601,6 @@ def _flash_latent_vjp_bwd(scale, causal, plan, res, g):
 _flash_latent.defvjp(_flash_latent_vjp_fwd, _flash_latent_vjp_bwd)
 
 
-def _axes_entry(mesh, axes, dim: int):
-    """PartitionSpec entry for one array dim: those of ``axes`` the mesh
-    has with size > 1, when together they divide ``dim`` (else None —
-    the dim stays whole on every shard)."""
-    keep = tuple(a for a in axes if mesh.shape.get(a, 1) > 1) \
-        if mesh is not None else ()
-    if not keep or dim % int(np.prod([mesh.shape[a] for a in keep])):
-        return None
-    return keep if len(keep) > 1 else keep[0]
-
-
 def flash_attention_stacked(qkv, num_heads: int, *, causal: bool = False,
                             scale=None, mesh=None, batch_axes=(),
                             head_axes=()):
@@ -1669,8 +1637,8 @@ def flash_attention_stacked(qkv, num_heads: int, *, causal: bool = False,
 
     if not _kernels_apply(T, T, causal):
         return local(qkv)              # XLA math: GSPMD partitions it
-    b_ax = _axes_entry(mesh, batch_axes, B)
-    h_ax = _axes_entry(mesh, head_axes, num_heads)
+    b_ax = axes_entry(mesh, batch_axes, B)
+    h_ax = axes_entry(mesh, head_axes, num_heads)
     return shard_kernel(
         local, mesh, PartitionSpec(None, b_ax, None, h_ax),
         PartitionSpec(b_ax, None, h_ax))(qkv)
@@ -1719,8 +1687,8 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
 
     if not _kernels_apply(T, Tk, causal):
         return local(q, k, v)          # XLA math: GSPMD partitions it
-    b_ax = _axes_entry(mesh, batch_axes, B)
-    h_ax = _axes_entry(mesh, head_axes, H)
+    b_ax = axes_entry(mesh, batch_axes, B)
+    h_ax = axes_entry(mesh, head_axes, H)
     spec = PartitionSpec(b_ax, None, h_ax, None)
     return shard_kernel(local, mesh, (spec, spec, spec), spec)(q, k, v)
 
@@ -1770,6 +1738,6 @@ def flash_attention_latent(q_n, q_r, k_n, k_r, v, *, causal: bool = False,
 
     if not _kernels_apply(T, Tk, causal):
         return local(q_n, q_r, k_n, k_r, v)    # XLA math: GSPMD partitions
-    spec = PartitionSpec(_axes_entry(mesh, batch_axes, B), None, None)
+    spec = PartitionSpec(axes_entry(mesh, batch_axes, B), None, None)
     return shard_kernel(local, mesh, (spec,) * 5, spec)(q_n, q_r, k_n, k_r,
                                                         v)

@@ -104,7 +104,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _NN, _NT, _TN, _dot
+from . import NN, NT, TN, divisor, dot
 
 __all__ = ["plan", "prep", "prep_vjp", "chunk_scan", "chunk_scan_vjp"]
 
@@ -130,10 +130,6 @@ class Plan(NamedTuple):
     interpret: bool
 
 
-def _divisor(n: int, most: int) -> int:
-    return max(d for d in range(1, most + 1) if n % d == 0)
-
-
 def _prep_heads(H: int, group: int, most: int) -> int:
     """The prep's value heads a grid step: whole key heads (each is read
     once), and a sublane tile of the (H, C) decays or all of them."""
@@ -154,10 +150,10 @@ def plan(n: int, H: int, C: int, dk: int, dv: int, interpret: bool,
             or C not in (_INVERSE_BASE << e for e in range(4)):
         return None
     group = H // (H_k or H)
-    loop = [(_divisor(H, h), _divisor(n, c)) for h, c in (_FWD_BLOCK,
-                                                          _BWD_BLOCK)]
+    loop = [(divisor(H, h), divisor(n, c)) for h, c in (_FWD_BLOCK,
+                                                        _BWD_BLOCK)]
     prep = (_prep_heads(H, group, _PREP_BLOCK[0]),
-            _divisor(n, _PREP_BLOCK[1]))
+            divisor(n, _PREP_BLOCK[1]))
     pack = max(p for p in range(1, group + 1)
                if group % p == 0 and (p * C <= 128 or p == 1))
     return Plan(*loop, prep, group, pack, interpret)
@@ -177,10 +173,10 @@ def _delta_rule_fwd(wk_ref, wv_ref, attn_ref, qd_ref, kd_ref, last_ref,
         for h in range(heads):
             S = S_scr[h]
             Sb = S.astype(dtype)
-            ub = (wv_ref[j, h] - _dot(wk_ref[j, h], Sb, _NN)).astype(dtype)
-            o = _dot(qd_ref[j, h], Sb, _NN) + _dot(attn_ref[j, h], ub, _NN)
+            ub = (wv_ref[j, h] - dot(wk_ref[j, h], Sb, NN)).astype(dtype)
+            o = dot(qd_ref[j, h], Sb, NN) + dot(attn_ref[j, h], ub, NN)
             o_ref[rows, h * dv:(h + 1) * dv] = o.astype(o_ref.dtype)
-            S_scr[h] = last_ref[j, h] * S + _dot(kd_ref[j, h], ub, _TN)
+            S_scr[h] = last_ref[j, h] * S + dot(kd_ref[j, h], ub, TN)
         return carry
 
     lax.fori_loop(0, chunks, chunk, 0)
@@ -199,9 +195,9 @@ def _delta_rule_states(wk_ref, wv_ref, kd_ref, last_ref, s_ref, u_ref,
             S = S_scr[h]
             s_ref[j, h] = S
             ub = (wv_ref[j, h]
-                  - _dot(wk_ref[j, h], S.astype(dtype), _NN)).astype(dtype)
+                  - dot(wk_ref[j, h], S.astype(dtype), NN)).astype(dtype)
             u_ref[j, h] = ub
-            S_scr[h] = last_ref[j, h] * S + _dot(kd_ref[j, h], ub, _TN)
+            S_scr[h] = last_ref[j, h] * S + dot(kd_ref[j, h], ub, TN)
         return carry
 
     lax.fori_loop(0, chunks, chunk, 0)
@@ -227,16 +223,16 @@ def _delta_rule_bwd(do_ref, wk_ref, attn_ref, qd_ref, kd_ref, last_ref,
             Sb = S.astype(dtype)
             ub = u_ref[j, h]
             do = do_ref[rows, h * dv:(h + 1) * dv]
-            du = _dot(attn_ref[j, h], do, _TN) + _dot(kd_ref[j, h], dSb, _NN)
+            du = dot(attn_ref[j, h], do, TN) + dot(kd_ref[j, h], dSb, NN)
             dub = du.astype(dtype)
             dwv_ref[j, h] = du.astype(dwv_ref.dtype)
-            dwk_ref[j, h] = (-_dot(dub, Sb, _NT)).astype(dwk_ref.dtype)
-            dattn_ref[j, h] = _dot(do, ub, _NT).astype(dattn_ref.dtype)
-            dqd_ref[j, h] = _dot(do, Sb, _NT).astype(dqd_ref.dtype)
-            dkd_ref[j, h] = _dot(ub, dSb, _NT).astype(dkd_ref.dtype)
+            dwk_ref[j, h] = (-dot(dub, Sb, NT)).astype(dwk_ref.dtype)
+            dattn_ref[j, h] = dot(do, ub, NT).astype(dattn_ref.dtype)
+            dqd_ref[j, h] = dot(do, Sb, NT).astype(dqd_ref.dtype)
+            dkd_ref[j, h] = dot(ub, dSb, NT).astype(dkd_ref.dtype)
             dlast_ref[j, h] = jnp.sum(dS * S, axis=0, keepdims=True)
             dS_scr[h] = last_ref[j, h] * dS \
-                + _dot(qd_ref[j, h], do, _TN) - _dot(wk_ref[j, h], dub, _TN)
+                + dot(qd_ref[j, h], do, TN) - dot(wk_ref[j, h], dub, TN)
         return carry
 
     lax.fori_loop(0, chunks, chunk, 0)
@@ -246,7 +242,7 @@ def _delta_rule_bwd(do_ref, wk_ref, attn_ref, qd_ref, kd_ref, last_ref,
 # the prep: what a chunk's loop operands are made from, no state in it
 # ---------------------------------------------------------------------------
 
-def _mm32(a, b, dims=_NN):
+def _mm32(a, b, dims=NN):
     """A float32 product that keeps float32's mantissa."""
     return lax.dot_general(a, b, dims, precision=lax.Precision.HIGHEST,
                            preferred_element_type=jnp.float32)
@@ -345,7 +341,7 @@ def _unit_lower_inverses(As, C: int, masks):
     for r in range(b - 1):
         # exact: each part against zeros and ones, the parts stacked so
         # that the zeros and ones reach the MXU once
-        column = _dot(parts, spread[r], _NN)
+        column = dot(parts, spread[r], NN)
         U = U - (column[:n * b] + column[n * b:2 * n * b]
                  + column[2 * n * b:]) * _rows_of(U, r, b)
     Ts = [U[u * b:(u + 1) * b] for u in range(n)]
@@ -418,7 +414,7 @@ def _delta_rule_prep(q_ref, k_ref, v_ref, g_ref, b_ref, wk_ref, wv_ref,
             k = k_ref[rows, kh * dk:(kh + 1) * dk]
             keys.append((q, k))
             kk = jnp.concatenate([k] * pack, axis=0)
-            KK, QK = _dot(k, kk, _NT), _dot(q, kk, _NT)       # (C, W)
+            KK, QK = dot(k, kk, NT), dot(q, kk, NT)           # (C, W)
             for h in range(kh * group, (kh + 1) * group, pack):
                 mine = [_chunk_gates(g_ref[c, h + e:h + e + 1, :],
                                      b_ref[c, h + e:h + e + 1, :], i, j)
@@ -441,9 +437,9 @@ def _delta_rule_prep(q_ref, k_ref, v_ref, g_ref, b_ref, wk_ref, wv_ref,
                 ref[c, h] = inv
             inv = inv.astype(dtype)
             v = v_ref[rows, h * dv:(h + 1) * dv]
-            wv_ref[c, h] = _dot(inv, (v * beta).astype(dtype), _NN)
-            wk_ref[c, h] = _dot(inv, (k * (beta * eG)).astype(dtype),
-                                _NN).astype(dtype)
+            wv_ref[c, h] = dot(inv, (v * beta).astype(dtype), NN)
+            wk_ref[c, h] = dot(inv, (k * (beta * eG)).astype(dtype),
+                               NN).astype(dtype)
             attn_ref[c, h] = attns[h // pack][:, at]
             qd_ref[c, h] = (q * eG).astype(dtype)
             kd_ref[c, h] = (k * e_last).astype(dtype)
@@ -472,7 +468,7 @@ def _delta_rule_prep_bwd(q_ref, k_ref, v_ref, g_ref, b_ref, inv_ref,
         for kh in range(heads // group):
             q = q_ref[rows, kh * dk:(kh + 1) * dk]
             k = k_ref[rows, kh * dk:(kh + 1) * dk]
-            KK, QK = _dot(k, k, _NT), _dot(q, k, _NT)
+            KK, QK = dot(k, k, NT), dot(q, k, NT)
             dq = jnp.zeros((C, dk), f32)
             dk_ = jnp.zeros((C, dk), f32)
             for h in range(kh * group, (kh + 1) * group):
@@ -488,14 +484,14 @@ def _delta_rule_prep_bwd(q_ref, k_ref, v_ref, g_ref, b_ref, inv_ref,
                 d_qd = dqd_ref[c, h].astype(f32)
                 d_kd = dkd_ref[c, h].astype(f32)
                 # w_v = inv (v beta), w_k = inv (k beta e^G)
-                d_inv = _dot(d_wv, (v * beta).astype(dtype), _NT) \
-                    + _dot(d_wk, (k * (beta * eG)).astype(dtype), _NT)
-                d_vb = _dot(invb, d_wv, _TN)
-                d_kb = _dot(invb, d_wk, _TN)
+                d_inv = dot(d_wv, (v * beta).astype(dtype), NT) \
+                    + dot(d_wk, (k * (beta * eG)).astype(dtype), NT)
+                d_vb = dot(invb, d_wv, TN)
+                d_kb = dot(invb, d_wk, TN)
                 # inv = (I + A)^-1: dA = -inv^T d_inv inv^T, below the
                 # diagonal
                 dA = jnp.where(
-                    i > j, -_mm32(_mm32(inv, d_inv, _TN), inv, _NT), 0.0)
+                    i > j, -_mm32(_mm32(inv, d_inv, TN), inv, NT), 0.0)
                 dAb = dA * beta
                 # A = beta KK decay, attn = QK decay; E = d_decay decay is
                 # the gradient of the exponent G_i - G_j
@@ -514,9 +510,9 @@ def _delta_rule_prep_bwd(q_ref, k_ref, v_ref, g_ref, b_ref, inv_ref,
                 db_ref[c, h:h + 1, :] = _row(d_beta, eye)
                 dv_ref[rows, h * dv:(h + 1) * dv] = \
                     (d_vb * beta).astype(dv_ref.dtype)
-                dq += _dot(dQK, k, _NN) + d_qd * eG
-                dk_ += _dot(dKK, k, _NN) + _dot(dKK, k, _TN) \
-                    + _dot(dQK, q, _TN) + d_kb * (beta * eG) + d_kd * e_last
+                dq += dot(dQK, k, NN) + d_qd * eG
+                dk_ += dot(dKK, k, NN) + dot(dKK, k, TN) \
+                    + dot(dQK, q, TN) + d_kb * (beta * eG) + d_kd * e_last
             dq_ref[rows, kh * dk:(kh + 1) * dk] = dq.astype(dq_ref.dtype)
             dk_ref[rows, kh * dk:(kh + 1) * dk] = dk_.astype(dk_ref.dtype)
         return carry

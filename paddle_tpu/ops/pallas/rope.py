@@ -42,7 +42,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .gated_delta_rule import _divisor
+from . import divisor
 
 __all__ = ["plan", "rope_fwd", "rope_bwd"]
 
@@ -75,9 +75,9 @@ def plan(T: int, H: int, K: int, hd: int, dtype, *,
     # and two float32 tables, twice (the pipeline's buffers), 10 MB of
     # the 16 MiB a kernel may use
     most = _BLOCK_T * 2 // jnp.dtype(dtype).itemsize
-    block_t = _TILE * _divisor(T // _TILE, most // _TILE)
-    heads = _divisor(math.gcd(H, K), max(_LANES // hd, 1))
-    rows = _TILE * _divisor(block_t // _TILE, _ROWS // _TILE)
+    block_t = _TILE * divisor(T // _TILE, most // _TILE)
+    heads = divisor(math.gcd(H, K), max(_LANES // hd, 1))
+    rows = _TILE * divisor(block_t // _TILE, _ROWS // _TILE)
     return Plan(block_t, heads, rows, interpret)
 
 

@@ -30,7 +30,7 @@ from jax.sharding import PartitionSpec
 
 from . import pallas
 from .pallas import rope as _kernels
-from .pallas.flash_attention import _axes_entry, _traced_once
+from .pallas import axes_entry, traced_once
 
 __all__ = ["rope_rotate_half", "rope_to_heads"]
 
@@ -65,7 +65,7 @@ def _reference(q, k, ang, scale: float):
     return one(q), one(k)
 
 
-@_traced_once(4)
+@traced_once(4)
 def _turn_kernel(q, k, cos, sin, plan):
     """(An inline jit, like the backward: a model's layers of one kind
     call with the same shapes, and the second finds the first one's
@@ -80,7 +80,7 @@ def _turn_fwd(q, k, cos, sin, plan):
     return _turn_kernel(q, k, cos, sin, plan), (cos, sin)
 
 
-@_traced_once(0)
+@traced_once(0)
 def _turn_bwd(plan, tables, d_out):
     """The rotation's transpose, R(-theta): the tables are all it needs;
     they are constants and take no cotangent."""
@@ -117,7 +117,7 @@ def rope_to_heads(q, k, ang, scale: float = 1.0, *, mesh=None,
     cos, sin = _tables(ang, scale)
     # the sign of concatenate([-x2, x1]) folded into the sine: exact
     sin[:, :hd // 2] = -sin[:, :hd // 2]
-    spec = PartitionSpec(_axes_entry(mesh, batch_axes, B))
+    spec = PartitionSpec(axes_entry(mesh, batch_axes, B))
     return pallas.shard_kernel(
         lambda q, k, c, s: _turn(q, k, c, s, plan), mesh,
         (spec, spec, PartitionSpec(), PartitionSpec()), (spec, spec))(

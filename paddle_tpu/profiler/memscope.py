@@ -529,8 +529,8 @@ def _goodput_doc_path() -> Optional[str]:
 
 
 class GoodputMeter:
-    """Wall-clock decomposition of one fit (or bench leg): productive
-    step time vs badput buckets.
+    """Wall-clock decomposition of one fit: productive step time vs
+    badput buckets.
 
     The caller feeds measured intervals — :meth:`step_ns` for the step
     body, :meth:`add_ns` for badput (``data_wait`` / ``checkpoint`` /

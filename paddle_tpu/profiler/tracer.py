@@ -59,7 +59,6 @@ from . import metrics as _metrics
 __all__ = ["active", "enable", "disable", "is_enabled", "clear", "events",
            "drain", "record", "now_ns", "chrome_trace_dict",
            "export_chrome_tracing", "summarize", "op_table",
-           "op_phase", "phase_shares", "OP_PHASES",
            "EPOCH_OFFSET_NS", "LAUNCH_ID", "record_launch",
            "launch_events", "launch_spans", "launch_report"]
 
@@ -528,116 +527,11 @@ def summarize(evs: Optional[List[_Event]] = None) -> Dict[str, dict]:
 
 
 # ---------------------------------------------------------------------------
-# op-level aggregation: the table bench.py's per-phase MFU breakdown and
-# tools/profile_resnet.py both read (one summary path, no ad-hoc timing)
+# op-level aggregation: which ops dispatched, and their host time
 # ---------------------------------------------------------------------------
-
-# op-name prefix -> phase class, first match wins.  "conv" covers the
-# fused conv-block ops too (fused_conv_bn_relu spans conv+bn+act in one
-# op — it IS the conv phase after fusion).
-OP_PHASES = (
-    ("conv", ("conv", "fused_conv", "fused_bn_")),
-    ("optimizer", ("optimizer", "sgd", "momentum", "adam", "lamb",
-                   "fused_update")),
-    ("norm", ("batch_norm", "layer_norm", "instance_norm", "group_norm",
-              "rms_norm", "sync_batch_norm")),
-    ("matmul", ("linear", "matmul", "mm", "bmm", "addmm", "einsum")),
-    ("pool", ("max_pool", "avg_pool", "adaptive_", "max_unpool")),
-    ("loss", ("cross_entropy", "softmax_with_cross_entropy", "mse",
-              "nll", "bce", "kl_div")),
-)
-
-
-def eager_phase_profile(model, opt, x, y, p0, steps: int = 2):
-    """The one measurement recipe behind ``bench.py``'s resnet phase
-    breakdown and ``tools/profile_resnet.py``: run ``steps``
-    instrumented EAGER train steps (per-op dispatch is the only place
-    per-op attribution exists; the jitted step is one opaque call) with
-    the optimizer's wall time folded in as its own synthetic bucket.
-
-    The eager per-op jit caches are warmed OUTSIDE the traced window —
-    a prior jitted ``train_batch`` leaves them cold, and a cold window
-    attributes one-time trace/compile (~40x a cache hit) instead of
-    dispatch time.  Returns ``(op_table, phase_shares, wall_s)``;
-    tracer enablement is restored on exit.
-    """
-    import time as _time
-
-    import jax as _jax
-
-    model._train_batch_eager([x], [y], update=False)
-    opt.step()
-    opt.clear_grad()
-    _jax.block_until_ready(p0._data)
-    was = active
-    enable()
-    clear()
-    opt_ns = 0
-    t_all = _time.perf_counter()
-    try:
-        for _ in range(steps):
-            model._train_batch_eager([x], [y], update=False)
-            t0 = _time.perf_counter_ns()
-            opt.step()
-            opt.clear_grad()
-            _jax.block_until_ready(p0._data)
-            opt_ns += _time.perf_counter_ns() - t0
-        wall = _time.perf_counter() - t_all
-        table = op_table()
-        return table, phase_shares(table, extra_ns={"optimizer": opt_ns}), \
-            wall
-    finally:
-        clear()
-        if not was:
-            disable()
-
-
-def op_phase(op_name: str) -> str:
-    """Phase class of one dispatched op name ('conv', 'norm',
-    'matmul', 'pool', 'optimizer', 'loss', or 'elementwise')."""
-    for phase, prefixes in OP_PHASES:
-        for p in prefixes:
-            if op_name.startswith(p):
-                return phase
-    return "elementwise"
-
-
 def op_table(evs: Optional[List[_Event]] = None) -> Dict[str, dict]:
     """``summarize()`` restricted to dispatched ops (``op::`` spans),
-    keyed by bare op name, each row carrying its phase class."""
-    out = {}
-    for name, s in summarize(evs).items():
-        if not name.startswith("op::"):
-            continue
-        op = name[len("op::"):]
-        row = dict(s)
-        row["phase"] = op_phase(op)
-        out[op] = row
-    return out
+    keyed by bare op name."""
+    return {name[len("op::"):]: row for name, row in summarize(evs).items()
+            if name.startswith("op::")}
 
-
-def phase_shares(table: Optional[Dict[str, dict]] = None,
-                 extra_ns: Optional[Dict[str, int]] = None
-                 ) -> Dict[str, dict]:
-    """Fraction of total dispatched-op host time per phase class.
-
-    ``extra_ns`` adds phases measured outside the dispatch layer (e.g.
-    an ``optimizer`` wall-time bucket when the optimizer runs through
-    one fused jit call rather than per-op dispatch).  Returns
-    ``{phase: {"time_frac", "total_ns", "calls"}}`` sorted by share;
-    purely-synthetic buckets (extra_ns with no dispatched ops) carry
-    ``calls=None`` — a dispatch count would be a lie for them.
-    """
-    table = op_table() if table is None else table
-    agg: Dict[str, dict] = {}
-    for op, row in table.items():
-        a = agg.setdefault(row["phase"], {"total_ns": 0, "calls": 0})
-        a["total_ns"] += row["total_ns"]
-        a["calls"] += row["calls"]
-    for phase, ns in (extra_ns or {}).items():
-        a = agg.setdefault(phase, {"total_ns": 0, "calls": None})
-        a["total_ns"] += int(ns)
-    total = sum(a["total_ns"] for a in agg.values()) or 1
-    for a in agg.values():
-        a["time_frac"] = a["total_ns"] / total
-    return dict(sorted(agg.items(), key=lambda kv: -kv[1]["total_ns"]))

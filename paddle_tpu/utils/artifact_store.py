@@ -398,7 +398,7 @@ def aot_compile(lowered, label: str = "", extra=()):
 
 
 def stats() -> dict:
-    """Hit/miss/store/corrupt counters (for bench JSON and CI gates)."""
+    """Hit/miss/store/corrupt counters (for CI gates and tests)."""
     from ..profiler import metrics as _metrics
     out = {}
     for k in ("hit", "miss", "store", "corrupt", "evicted", "bypass"):

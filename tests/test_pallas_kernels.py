@@ -7,10 +7,12 @@ the forward (lse-emitting) kernel and both backward kernels
 the XLA reference math (reference parity net: the same numpy-oracle
 posture as OpTest, ``tests/unittests/op_test.py:277``).
 """
+import ast
 import functools
 import importlib
 import math
 import os
+import pathlib
 
 import numpy as np
 import pytest
@@ -448,7 +450,7 @@ def _nt_dot_elements(jaxpr, found):
     by output shape."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "dot_general" and \
-                eqn.params["dimension_numbers"] == fa._NT:
+                eqn.params["dimension_numbers"] == fa.NT:
             shape = eqn.outvars[0].aval.shape
             found[shape] = found.get(shape, 0) + 1
         for sub in jax.core.jaxprs_in_params(eqn.params):
@@ -502,7 +504,7 @@ def test_packed_mid_multiplies_only_the_live_score_columns(force_pallas,
 def test_layers_share_one_trace_of_the_stacked_kernels(force_pallas):
     """A model's unrolled layer loop calls the stacked entry once a layer
     with the same shapes: every layer's forward and backward call carry
-    the kernel jaxpr the first layer traced (``_traced_once``), so set-up
+    the kernel jaxpr the first layer traced (``traced_once``), so set-up
     pays for two kernel bodies and not for two a layer."""
     def layers(x):
         for _ in range(3):
@@ -1222,3 +1224,27 @@ def test_the_cells_plans(force_pallas, monkeypatch, shape, d_v, want):
     plan = fa._plan(*shape, 2, True, *d_v)
     assert plan == fa._Plan(want[0], jax.default_backend() != "tpu",
                             *want[1:])
+
+
+def test_no_module_imports_a_kernel_modules_private_names():
+    """The helpers every kernel family shares (``dot``, ``divisor``,
+    ``traced_once``, ``axes_entry``, ...) live in ``ops.pallas`` itself:
+    no module of the package imports a name that starts with ``_`` from
+    a module under ``ops/pallas/``.  Importing a kernel module under a
+    private alias (``from .pallas import rope as _kernels``) is fine."""
+    root = pathlib.Path(fa.__file__).parents[3]
+    found = []
+    for path in sorted((root / "paddle_tpu").rglob("*.py")):
+        package = path.parent.relative_to(root).parts
+        for node in ast.walk(ast.parse(path.read_text(), str(path))):
+            if not isinstance(node, ast.ImportFrom):
+                continue
+            module = (node.module or "").split(".") if node.module else []
+            if node.level:
+                module = [*package[:len(package) - node.level + 1], *module]
+            if module[:3] != ["paddle_tpu", "ops", "pallas"]:
+                continue
+            found += [f"{path.relative_to(root)}:{node.lineno} "
+                      f"{'.'.join(module)}.{a.name}"
+                      for a in node.names if a.name.startswith("_")]
+    assert not found, found
